@@ -429,7 +429,7 @@ def bench_kernels() -> None:
     from repro.kernels.crps.ref import crps_fused_ref
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.normal(size=(8, 128, 16)), jnp.float32)
-    t = jnp.asarray(rng.normal(size=(128, 128, 16)), jnp.float32)
+    t = jnp.asarray(rng.normal(size=(16, 128, 128)), jnp.float32)
     us_k = _timeit(lambda: legendre_contract(x, t), n=3)
     ref = jax.jit(legendre_contract_ref)
     us_r = _timeit(lambda: ref(x, t), n=3)
@@ -447,7 +447,7 @@ def bench_sec5_kernels() -> None:
     """Section 5 / App. B.5, C: op-level kernel-substrate A/B.
 
     Times the two hot contractions of the FCN3 step -- the SHT (forward
-    and inverse) and the raw DISCO contraction -- through the reference
+    and inverse) and the DISCO convolution -- through the reference
     XLA path vs the Pallas dispatch (interpret mode on CPU, compiled on
     TPU/GPU; the ``mode`` field in the derived column says which ran),
     and reports the static-memory win of the banded psi split vs the
@@ -490,17 +490,19 @@ def bench_sec5_kernels() -> None:
          f"ref_us={us_r:.1f};mode={mode};blocks={leg_blocks};"
          f"speedup={us_r / us_p:.2f}x")
 
-    # DISCO on a real encoder plan (equiangular -> Gaussian downsampling).
+    # DISCO conv (contraction + channel mix) on a real encoder plan
+    # (equiangular -> Gaussian downsampling).
     gi = grids.make_grid(64, 128, "equiangular")
     go = grids.make_grid(32, 64, "gauss")
     plan = dlib.make_disco_plan(gi, go)
     full = plan.buffers(jnp.float32)
     band = plan.banded_buffers(jnp.float32)
     xd = jax.random.normal(jax.random.PRNGKey(1), (8, 64, 128))
-    dis_ref = jax.jit(lambda x: kdispatch.disco_conv(x, full, plan.stride,
-                                                     plan.affine))
-    dis_pal = jax.jit(lambda x: kdispatch.disco_conv(x, band, plan.stride,
-                                                     plan.affine, kc))
+    wd = dlib.init_disco_conv(jax.random.PRNGKey(2), 8, 8, plan.n_basis)
+    dis_ref = jax.jit(lambda x: dlib.apply_disco_conv(
+        wd, x, full, plan.stride, affine=plan.affine))
+    dis_pal = jax.jit(lambda x: dlib.apply_disco_conv(
+        wd, x, band, plan.stride, affine=plan.affine, kernels=kc))
     us_r, us_p = _ab_timeit([lambda: dis_ref(xd), lambda: dis_pal(xd)], n=5)
     _row("sec5_kernels_disco", us_p,
          f"ref_us={us_r:.1f};mode={mode};blocks={dis_blocks};"
@@ -548,13 +550,13 @@ def bench_sec5_kernels_tuned() -> None:
     # families A/B the same work): the smoke-latent SHT slab, the
     # encoder-plan DISCO band and the kernel_crps_interp score slab.
     t = sht.SHT.create(grids.make_grid(32, 64, "gauss"))
-    h, l, m = t.buffers()["wpct"].shape
+    m, h, l = t.buffers()["wpct"].shape
     plan = dlib.make_disco_plan(grids.make_grid(64, 128, "equiangular"),
                                 grids.make_grid(32, 64, "gauss"))
     k, h_out, s, d = plan.banded_buffers(jnp.float32)["psi_band"].shape
     ops_shapes = {
         "legendre": (16, h, l, m),
-        "disco": (8, h_out, s, 128, k, d, plan.stride),
+        "disco": (1, 8, 64, h_out, s, 128, k, d, 8, plan.stride),
         "crps": (16, 65536),
     }
 
@@ -624,7 +626,7 @@ def bench_bundle(members: int = 2, steps: int = 4) -> None:
     import tempfile
     from repro.core.sphere import disco as discolib
     from repro.core.sphere import legendre as leg
-    from repro.serving.bundle import boot_scheduler, pack, set_xla_cache_dir
+    from repro.serving.bundle import boot_scheduler, pack
     from repro.serving.cache import ExecutableCache
     from repro.serving.scheduler import (ForecastScheduler, ModelPool,
                                          RequestSpec)
@@ -641,9 +643,10 @@ def bench_bundle(members: int = 2, steps: int = 4) -> None:
     try:
         bundle_path = pack([spec], out=os.path.join(tmp, "bundle"))
 
-        # cold boot: nothing warm anywhere -- the full pipeline runs
+        # cold boot: nothing warm anywhere -- the full pipeline runs,
+        # with the persistent compilation cache off so XLA compiles
         clear_geometry_caches()
-        set_xla_cache_dir(os.path.join(tmp, "cold-xla"))
+        jax.config.update("jax_enable_compilation_cache", False)
         t0 = time.perf_counter()
         cold_sched = ForecastScheduler(pool=ModelPool(),
                                        cache=ExecutableCache(),
@@ -656,6 +659,7 @@ def bench_bundle(members: int = 2, steps: int = 4) -> None:
                  f"setup_s={res.timing['setup_s']:.2f}")
         finally:
             cold_sched.close()
+            jax.config.update("jax_enable_compilation_cache", True)
 
         # bundle boot: same cleared caches, everything from the bundle
         clear_geometry_caches()
@@ -875,6 +879,8 @@ def main(argv=None) -> None:
                 if args.only is None or args.only in n}
     if not selected:
         raise SystemExit(f"no benchmark matches --only {args.only!r}")
+    from repro import compile_cache
+    compile_cache.configure()
     print("name,us_per_call,derived")
     for fn in selected.values():
         fn(args)

@@ -25,6 +25,7 @@ tables, interpolation plans) is carried in a ``buffers`` pytree produced by
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -221,6 +222,8 @@ class FCN3:
         calibration runs a short fixed-point iteration: calibrate, step the
         state forward, recalibrate on that state.  Processor blocks are
         near-identity at init via LayerScale and need no calibration.
+        Each round runs the jitted encoder and step (``self.step``), so
+        the full-width model never executes op by op.
         """
         cfg = self.cfg
         params = self.init(key)
@@ -234,10 +237,11 @@ class FCN3:
 
         na = cfg.n_levels * cfg.atmos_embed
         nl = cfg.n_levels * cfg.n_atmos
+        encode = jax.jit(self._encode)
         x = state
         for _ in range(rounds):
             # 1) encoders -> unit-std latent / conditioning embeddings.
-            z, c = self._encode(params, bufs, x, cond_in)
+            z, c = encode(params, bufs, x, cond_in)
             params["enc_atmos"] = _scale(
                 params["enc_atmos"], 1.0 / (float(jnp.std(z[..., :na, :, :])) or 1.0))
             params["enc_surface"] = _scale(
@@ -245,7 +249,7 @@ class FCN3:
             params["enc_cond"] = _scale(
                 params["enc_cond"], 1.0 / (float(jnp.std(c)) or 1.0))
             # 2) decoder -> one full step preserves the state's std.
-            out = self.apply(params, bufs, x, cond_in)
+            out = self.step(params, bufs, x, cond_in)
             params["dec_atmos"] = _scale(
                 params["dec_atmos"],
                 target / (float(jnp.std(out[..., :nl, :, :])) or 1.0))
@@ -253,7 +257,7 @@ class FCN3:
                 params["dec_surface"],
                 target / (float(jnp.std(out[..., nl:, :, :])) or 1.0))
             # 3) advance the calibration state to the model's own output.
-            x = self.apply(params, bufs, x, cond_in)
+            x = self.step(params, bufs, x, cond_in)
         return params
 
     # ------------------------------------------------------------------
@@ -288,26 +292,40 @@ class FCN3:
 
     def _decode(self, params: dict, buffers: dict, latent: jax.Array
                 ) -> jax.Array:
+        """Bilinear upsample + grouped DISCO decoders, one pressure level
+        at a time: the upsampled latent is the largest activation of the
+        step (641 channels at 721x1440, 2.7 GB per member in float32),
+        so it is never materialized for all levels at once."""
         cfg = self.cfg
         nl = cfg.n_levels
-        up = self.upsample(latent)  # (..., C_latent, H, W)
-        atmos_lat = up[..., : nl * cfg.atmos_embed, :, :]
-        surf_lat = up[..., nl * cfg.atmos_embed:, :, :]
+        kc = cfg.kernels
+        atmos_lat = latent[..., : nl * cfg.atmos_embed, :, :]
+        surf_lat = latent[..., nl * cfg.atmos_embed:, :, :]
         b = atmos_lat.shape[:-3]
         hw = atmos_lat.shape[-2:]
-        atmos_lat = atmos_lat.reshape(b + (nl, cfg.atmos_embed) + hw)
-        kc = cfg.kernels
-        ua = discolib.apply_disco_conv(params["dec_atmos"], atmos_lat,
-                                       buffers["dec"], 1, groups=cfg.n_atmos,
-                                       affine=self.dec_plan.affine,
-                                       kernels=kc)
-        ua = ua.reshape(b + (nl * cfg.n_atmos,) + hw)
-        us = discolib.apply_disco_conv(params["dec_surface"], surf_lat,
+        levels = jnp.moveaxis(
+            atmos_lat.reshape(b + (nl, cfg.atmos_embed) + hw), -4, 0)
+
+        def decode_level(lat):
+            return discolib.apply_disco_conv(
+                params["dec_atmos"], self.upsample(lat), buffers["dec"], 1,
+                groups=cfg.n_atmos, affine=self.dec_plan.affine, kernels=kc)
+
+        ua = jnp.moveaxis(jax.lax.map(decode_level, levels), 0, -4)
+        ua = ua.reshape(b + (nl * cfg.n_atmos,) + ua.shape[-2:])
+        us = discolib.apply_disco_conv(params["dec_surface"],
+                                       self.upsample(surf_lat),
                                        buffers["dec"], 1,
                                        groups=cfg.n_surface,
                                        affine=self.dec_plan.affine,
                                        kernels=kc)
         return jnp.concatenate([ua, us], axis=-3)
+
+    @functools.cached_property
+    def step(self):
+        """``apply`` under ``jax.jit``: one compiled program per input
+        shape, shared by every caller of this model instance."""
+        return jax.jit(self.apply)
 
     def apply(self, params: dict, buffers: dict, state: jax.Array,
               cond_in: jax.Array) -> jax.Array:

@@ -425,6 +425,28 @@ def _gather_band(x: jax.Array, lat_idx, affine, h_out: int) -> jax.Array:
     return jnp.stack(cols, axis=-2)                 # (..., H_out, S, W)
 
 
+def fft_correlate(xg: jax.Array, psi: jax.Array, stride: int) -> jax.Array:
+    """Circular correlation of gathered bands with full-circle filters.
+
+    xg: (..., H_out, S, W_in); psi: (K, H_out, S, W_in) ->
+    (..., K, H_out, W_in // stride) with
+    out[..., k, h, w] = sum_{s, dw} psi[k, h, s, dw] * xg[..., h, s,
+                                                       (w*stride + dw) % W_in].
+    """
+    w_in = xg.shape[-1]
+    xf = fourier.rfft(xg.astype(jnp.float32), axis=-1)
+    pf = jnp.conj(fourier.rfft(psi.astype(jnp.float32), axis=-1))  # (K,H,S,F)
+    # correlation: out_hat = x_hat * conj(psi_hat), summed over the band
+    # S.  An explicit sum, not a dot: S is 5-13 rings, and as a dot
+    # operand a TPU tiles it to 128 lanes (25x the bytes at 721x1440).
+    prod = sum(xf[..., None, :, s, :] * pf[:, :, s, :]
+               for s in range(psi.shape[2]))
+    out = fourier.irfft(prod, n=w_in, axis=-1)
+    if stride > 1:
+        out = out[..., ::stride]
+    return out
+
+
 def disco_conv(x: jax.Array, psi: jax.Array, lat_idx: jax.Array,
                stride: int, affine: tuple[int, int] | None = None
                ) -> jax.Array:
@@ -434,17 +456,74 @@ def disco_conv(x: jax.Array, psi: jax.Array, lat_idx: jax.Array,
     out[..., k, h, w] = sum_{s, dw} psi[k, h, s, dw] * x[..., lat_idx[h, s],
                                                           (w*stride+dw) % W_in].
     """
-    w_in = x.shape[-1]
-    h_out = psi.shape[1]
-    xg = _gather_band(x, lat_idx, affine, h_out)    # (..., H_out, S, W_in)
-    xf = fourier.rfft(xg.astype(jnp.float32), axis=-1)
-    pf = fourier.rfft(psi, axis=-1)                 # (K, H_out, S, F)
-    # correlation: out_hat = x_hat * conj(psi_hat); contract the band S.
-    prod = jnp.einsum("...hsf,khsf->...khf", xf, jnp.conj(pf))
-    out = fourier.irfft(prod, n=w_in, axis=-1)
-    if stride > 1:
-        out = out[..., ::stride]
-    return out
+    xg = _gather_band(x, lat_idx, affine, psi.shape[1])  # (..., H_out, S, W)
+    return fft_correlate(xg, psi, stride)
+
+
+def mix_basis(z: jax.Array, w: jax.Array, groups: int = 1) -> jax.Array:
+    """Learnable channel mix of basis responses (paper eq. 23).
+
+    z: (..., C_in, K, H, W); w: (C_out, C_in // groups, K) ->
+    (..., C_out, H, W).
+    """
+    c_out, cpg, k = w.shape
+    if groups == 1:
+        return jnp.einsum("...ikhw,oik->...ohw", z, w)
+    zg = z.reshape(z.shape[:-4] + (groups, cpg, k) + z.shape[-2:])
+    wg = w.reshape(groups, c_out // groups, cpg, k)
+    y = jnp.einsum("...gikhw,goik->...gohw", zg, wg)
+    return y.reshape(y.shape[:-4] + (c_out,) + y.shape[-2:])
+
+
+def dense_mix(w: jax.Array, groups: int = 1) -> jax.Array:
+    """(C_out, C_in // groups, K) grouped weights -> (K, C_out, C_in)
+    block-diagonal dense per-basis mixing matrices."""
+    c_out, cpg, k = w.shape
+    wg = w.reshape(groups, c_out // groups, cpg, k)
+    dense = jnp.einsum("goik,gh->kgohi", wg, jnp.eye(groups, dtype=w.dtype))
+    return dense.reshape(k, c_out, groups * cpg)
+
+
+#: gathered-band elements (..., C_in, H_out, S, W_in) above which the
+#: reference path contracts in pieces -- per leading index, then per
+#: input-channel chunk -- so its FFT intermediates stay a few GB at
+#: 721x1440 instead of tens
+_REF_BAND_ELEMS = 2**28
+
+
+def _reference_conv(w: jax.Array, x: jax.Array, psi: jax.Array,
+                    lat_idx: jax.Array, stride: int, groups: int,
+                    affine: tuple[int, int] | None) -> jax.Array:
+    """FFT-path DISCO conv without bias, memory-bounded at full width."""
+    band = x.size // x.shape[-2] * psi.shape[1] * psi.shape[2]
+    if band <= _REF_BAND_ELEMS:
+        z = disco_conv(x, psi, lat_idx, stride, affine)
+        return mix_basis(z, w, groups)       # z: (..., C_in, K, H, W)
+    if x.ndim > 3:
+        xl = x.reshape((-1,) + x.shape[-3:])
+        y = jax.lax.map(lambda xi: _reference_conv(
+            w, xi, psi, lat_idx, stride, groups, affine), xl)
+        return y.reshape(x.shape[:-3] + y.shape[-3:])
+    if groups != 1:
+        z = disco_conv(x, psi, lat_idx, stride, affine)
+        return mix_basis(z, w, groups)
+    # groups == 1: sum the mix over input-channel chunks
+    c_out, c_in, _ = w.shape
+    n = -(-band // _REF_BAND_ELEMS)
+    chunk = -(-c_in // n)
+    pad = n * chunk - c_in
+    xs = jnp.pad(x, ((0, pad), (0, 0), (0, 0))).reshape(
+        (n, chunk) + x.shape[-2:])
+    ws = jnp.pad(w, ((0, 0), (0, pad), (0, 0))).reshape(
+        c_out, n, chunk, -1).transpose(1, 0, 2, 3)
+
+    def body(acc, xw):
+        z = disco_conv(xw[0], psi, lat_idx, stride, affine)
+        return acc + mix_basis(z, xw[1]), None
+
+    y0 = jnp.zeros((c_out, psi.shape[1], x.shape[-1] // stride),
+                   jnp.result_type(x, w, jnp.float32))
+    return jax.lax.scan(body, y0, (xs, ws))[0]
 
 
 def init_disco_conv(key: jax.Array, c_out: int, c_in: int, n_basis: int,
@@ -477,29 +556,20 @@ def apply_disco_conv(params: dict, x: jax.Array, buffers: dict,
                      kernels: KernelConfig | None = None) -> jax.Array:
     """x: (..., C_in, H_in, W_in) -> (..., C_out, H_out, W_out).
 
-    The raw contraction dispatches on the buffer layout: banded buffers
+    The contraction dispatches on the buffer layout: banded buffers
     (built by ``DiscoPlan.buffers`` under pallas dispatch) route through
-    the Pallas band kernel with the FFT fallback on wrap rows; full-psi
-    buffers take the reference FFT correlation.  ``kernels`` only
-    supplies the interpret flag for the Pallas call.
+    the Pallas band kernel, which also applies the channel mix, with the
+    FFT fallback on wrap rows; full-psi buffers take the reference FFT
+    correlation.  ``kernels`` supplies the interpret flag and tile shape
+    for the Pallas call.
     """
     if "psi_band" in buffers:
         from repro.kernels import dispatch as kdispatch
-        z = kdispatch.disco_conv_banded_buffers(x, buffers, stride, affine,
-                                                kernels)
+        y = kdispatch.disco_conv_mixed(x, params["weight"], buffers, stride,
+                                       groups, affine, kernels)
     else:
-        z = disco_conv(x, buffers["psi"], buffers["lat_idx"], stride, affine)
-    # z: (..., C_in, K, H_out, W_out)
-    w = params["weight"]  # (C_out, C_in/groups, K)
-    c_out, cpg, k = w.shape
-    c_in = x.shape[-3]
-    if groups == 1:
-        y = jnp.einsum("...ikhw,oik->...ohw", z, w)
-    else:
-        zg = z.reshape(z.shape[:-4] + (groups, cpg, k) + z.shape[-2:])
-        wg = w.reshape(groups, c_out // groups, cpg, k)
-        y = jnp.einsum("...gikhw,goik->...gohw", zg, wg)
-        y = y.reshape(y.shape[:-4] + (c_out,) + y.shape[-2:])
+        y = _reference_conv(params["weight"], x, buffers["psi"],
+                            buffers["lat_idx"], stride, groups, affine)
     if "bias" in params:
         y = y + params["bias"][..., :, None, None]
     return y

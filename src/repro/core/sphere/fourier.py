@@ -13,9 +13,13 @@ matrices (~2-8 MB) are shared constants.  The O(W^2) vs O(W log W) flop
 increase is paid on the MXU where FCN3 is nowhere near compute-bound
 (see EXPERIMENTS.md SPerf iteration 2).
 
-Mode selection: ``REPRO_DFT_MODE`` environment variable ("fft" default --
-fastest on CPU; "matmul" -- set by repro.launch.dryrun for SPMD builds) or
-the ``set_mode`` function.
+Mode selection: ``REPRO_DFT_MODE`` environment variable or the
+``set_mode`` function; unset, the backend decides -- "matmul" on a TPU,
+"fft" elsewhere (fastest on CPU).  On a TPU the FFT path also costs
+memory: XLA lowers the inverse real FFT of the latent global blocks
+through Hermitian-extended complex buffers, ~3 GB of extra HBM per
+full-width member (compile rehearsal of the 721x1440 chunk program),
+where the GEMMs need only their inputs and outputs.
 """
 
 from __future__ import annotations
@@ -27,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-_MODE = os.environ.get("REPRO_DFT_MODE", "fft")
+_MODE = os.environ.get("REPRO_DFT_MODE")
 
 
 def set_mode(mode: str) -> None:
@@ -37,7 +41,9 @@ def set_mode(mode: str) -> None:
 
 
 def get_mode() -> str:
-    return _MODE
+    if _MODE is not None:
+        return _MODE
+    return "matmul" if jax.default_backend() == "tpu" else "fft"
 
 
 @functools.lru_cache(maxsize=16)
@@ -69,7 +75,7 @@ def _irdft_mats(n: int) -> tuple[np.ndarray, np.ndarray]:
 def rfft(x: jax.Array, axis: int = -1) -> jax.Array:
     """Real FFT along the last axis (axis must be -1)."""
     assert axis in (-1, x.ndim - 1)
-    if _MODE == "fft":
+    if get_mode() == "fft":
         # lax.fft accepts only f32/f64; under a bf16 compute policy the
         # longitudinal transform is computed in fp32 (its result is
         # complex64 either way).
@@ -84,7 +90,7 @@ def rfft(x: jax.Array, axis: int = -1) -> jax.Array:
 def irfft(c: jax.Array, n: int, axis: int = -1) -> jax.Array:
     """Inverse real FFT along the last axis; c must have n//2+1 entries."""
     assert axis in (-1, c.ndim - 1)
-    if _MODE == "fft":
+    if get_mode() == "fft":
         return jnp.fft.irfft(c, n=n, axis=-1)
     assert c.shape[-1] == n // 2 + 1, (c.shape, n)
     a, b = _irdft_mats(n)

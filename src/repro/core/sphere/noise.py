@@ -102,9 +102,10 @@ class SphericalDiffusion:
         return out
 
     def buffers(self) -> dict[str, jax.Array]:
-        b = dict(self.sht.buffers())
-        b["sigma_l"] = jnp.asarray(self._sigma_l(), jnp.float32)
-        return b
+        """What the process needs on device: the inverse Legendre table
+        ("pct") and the per-degree spectral std ("sigma_l")."""
+        return {"pct": self.sht.table("pct"),
+                "sigma_l": jnp.asarray(self._sigma_l(), jnp.float32)}
 
     def _sample_coeffs(self, key: jax.Array, batch_shape: tuple[int, ...],
                        sigma_l: jax.Array) -> jax.Array:
@@ -131,7 +132,7 @@ class SphericalDiffusion:
     def to_grid(self, z_hat: jax.Array, buffers: dict | None = None) -> jax.Array:
         """Coefficients -> (*batch, n_proc, H, W) real fields."""
         b = buffers if buffers is not None else self.buffers()
-        return shtlib.sht_inverse(z_hat, b["pct"], self.sht.grid.nlon)
+        return self.sht.inverse(z_hat, b)
 
 
 def _mirror_pairs(x: jax.Array, src: jax.Array, n: int, axis: int

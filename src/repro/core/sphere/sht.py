@@ -23,7 +23,7 @@ sharded and replaced by ``ShapeDtypeStruct`` in compile-only dry-runs.
 from __future__ import annotations
 
 import dataclasses
-import math
+import threading
 
 import jax
 import jax.numpy as jnp
@@ -39,16 +39,16 @@ def sht_forward(x: jax.Array, wpct: jax.Array) -> jax.Array:
 
     Args:
       x: input signal.
-      wpct: (H, L, M) quadrature-weighted Legendre table
-        ``w_h * Pbar_l^m(cos theta_h)``.
+      wpct: (M, H, L) quadrature-weighted Legendre table
+        ``w_h * Pbar_l^m(cos theta_h)``, order-major.
     """
-    h, l, m = wpct.shape
+    m = wpct.shape[0]
     w = x.shape[-1]
     xf = fourier.rfft(x.astype(jnp.float32), axis=-1)[..., :m]
     xf = xf * (2.0 * jnp.pi / w)
-    # Legendre contraction over latitude: (..., H, M) x (H, L, M) -> (..., L, M)
-    re = jnp.einsum("...hm,hlm->...lm", jnp.real(xf), wpct)
-    im = jnp.einsum("...hm,hlm->...lm", jnp.imag(xf), wpct)
+    # Legendre contraction over latitude: (..., H, M) x (M, H, L) -> (..., L, M)
+    re = jnp.einsum("...hm,mhl->...lm", jnp.real(xf), wpct)
+    im = jnp.einsum("...hm,mhl->...lm", jnp.imag(xf), wpct)
     return jax.lax.complex(re, im)
 
 
@@ -57,13 +57,24 @@ def sht_inverse(c: jax.Array, pct: jax.Array, nlon: int) -> jax.Array:
 
     Args:
       c: spherical harmonic coefficients (orders m >= 0).
-      pct: (H, L, M) unweighted Legendre table ``Pbar_l^m(cos theta_h)``.
+      pct: (M, L, H) unweighted Legendre table ``Pbar_l^m(cos theta_h)``,
+        order-major.
       nlon: number of output longitudes.
+
+    Contracted at float32 ("highest"): in this layout and precision a
+    TPU reads the table where it lies, with no transposed or bf16 copy
+    (a rollout's loop would hoist such a copy of the 1.5 GB IO-grid
+    table and hold it for all of the loop).
     """
-    h, l, m = pct.shape
-    sr = jnp.einsum("...lm,hlm->...hm", jnp.real(c), pct)
-    si = jnp.einsum("...lm,hlm->...hm", jnp.imag(c), pct)
-    spec = jax.lax.complex(sr, si)
+    hi = jax.lax.Precision.HIGHEST
+    sr = jnp.einsum("...lm,mlh->...hm", jnp.real(c), pct, precision=hi)
+    si = jnp.einsum("...lm,mlh->...hm", jnp.imag(c), pct, precision=hi)
+    return synthesize(jax.lax.complex(sr, si), nlon)
+
+
+def synthesize(spec: jax.Array, nlon: int) -> jax.Array:
+    """(..., H, M) Legendre-synthesized orders -> (..., H, nlon) field."""
+    m = spec.shape[-1]
     pad = nlon // 2 + 1 - m
     if pad < 0:
         raise ValueError(f"mmax={m} too large for nlon={nlon}")
@@ -71,6 +82,10 @@ def sht_inverse(c: jax.Array, pct: jax.Array, nlon: int) -> jax.Array:
         spec = jnp.pad(spec, [(0, 0)] * (spec.ndim - 1) + [(0, pad)])
     # irfft contributes 1/nlon and the Hermitian double-count of m>0 modes.
     return fourier.irfft(spec, n=nlon, axis=-1) * nlon
+
+
+_TABLES: dict[tuple, jax.Array] = {}
+_TABLES_LOCK = threading.Lock()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -89,33 +104,54 @@ class SHT:
         mmax = int(mmax if mmax is not None else min(lmax, grid.nlon // 2 + 1))
         return cls(grid=grid, lmax=lmax, mmax=mmax, dtype=dtype)
 
-    def _tables(self) -> tuple[np.ndarray, np.ndarray]:
-        pbar = leg.cached_legendre_table(self.lmax, self.mmax, self.grid.colat)
-        wpct = pbar * self.grid.quad_weights[:, None, None]
-        return wpct, pbar
+    def table(self, name: str) -> jax.Array:
+        """One Legendre table as a device array, in the order-major
+        layout both the reference einsums and the Pallas Legendre kernel
+        contract: "wpct" (M, H, L) for the forward transform, "pct"
+        (M, L, H) for the inverse.
+
+        One device copy per (grid, lmax, mmax, dtype, table) is shared
+        by every ``SHT`` of that shape -- at 721x1440 each table is
+        1.5 GB, and the model's noise process, the data source and the
+        losses all transform on the IO grid -- and built only when some
+        caller needs it."""
+        g = self.grid
+        key = (g.nlat, g.nlon, g.kind, self.lmax, self.mmax,
+               jnp.dtype(self.dtype).name, name)
+        with _TABLES_LOCK:
+            hit = _TABLES.get(key)
+            if hit is None:
+                pbar = leg.cached_legendre_table(self.lmax, self.mmax,
+                                                 g.colat)   # (H, L, M)
+                if name == "wpct":
+                    host = (pbar * g.quad_weights[:, None, None]
+                            ).transpose(2, 0, 1)
+                elif name == "pct":
+                    host = pbar.transpose(2, 1, 0)
+                else:
+                    raise KeyError(f"no Legendre table {name!r}")
+                hit = jnp.asarray(host, self.dtype)
+                _TABLES[key] = hit
+            return hit
 
     def buffers(self) -> dict[str, jax.Array]:
         """Legendre tables as arrays (pass through the model as buffers)."""
-        wpct, pbar = self._tables()
-        return {
-            "wpct": jnp.asarray(wpct, self.dtype),
-            "pct": jnp.asarray(pbar, self.dtype),
-        }
+        return {"wpct": self.table("wpct"), "pct": self.table("pct")}
 
     def buffer_specs(self) -> dict[str, jax.ShapeDtypeStruct]:
-        shape = (self.grid.nlat, self.lmax, self.mmax)
+        h, l, m = self.grid.nlat, self.lmax, self.mmax
         return {
-            "wpct": jax.ShapeDtypeStruct(shape, self.dtype),
-            "pct": jax.ShapeDtypeStruct(shape, self.dtype),
+            "wpct": jax.ShapeDtypeStruct((m, h, l), self.dtype),
+            "pct": jax.ShapeDtypeStruct((m, l, h), self.dtype),
         }
 
     def forward(self, x: jax.Array, buffers: dict | None = None) -> jax.Array:
-        b = buffers if buffers is not None else self.buffers()
-        return sht_forward(x, b["wpct"])
+        wpct = buffers["wpct"] if buffers is not None else self.table("wpct")
+        return sht_forward(x, wpct)
 
     def inverse(self, c: jax.Array, buffers: dict | None = None) -> jax.Array:
-        b = buffers if buffers is not None else self.buffers()
-        return sht_inverse(c, b["pct"], self.grid.nlon)
+        pct = buffers["pct"] if buffers is not None else self.table("pct")
+        return sht_inverse(c, pct, self.grid.nlon)
 
 
 def resample(x: jax.Array, sht_in: SHT, sht_out: SHT) -> jax.Array:

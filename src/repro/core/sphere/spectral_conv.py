@@ -46,7 +46,8 @@ def apply_spectral_conv(params: dict, x: jax.Array, sht_buffers: dict,
     Args:
       params: from ``init_spectral_filter``.
       x: input signal, channels-second-to-last-but-two layout (..., C, H, W).
-      sht_buffers: {"wpct": (H,L,M), "pct": (H,L,M)} Legendre tables.
+      sht_buffers: {"wpct": (M,H,L), "pct": (M,L,H)} order-major Legendre
+        tables (``SHT.buffers``).
       nlon: output longitude count (== W).
       lmax_keep: optional hard spectral truncation (anti-aliasing).
       kernels: substrate selection for the two SHTs (the hot Legendre
@@ -54,11 +55,10 @@ def apply_spectral_conv(params: dict, x: jax.Array, sht_buffers: dict,
     """
     if kernels is not None and kernels.resolve("sht")[0] == "pallas":
         from repro.kernels import dispatch as kdispatch
-        interpret = kernels.resolve("sht")[1]
-        fwd = lambda x_: kdispatch.sht_forward_pallas(  # noqa: E731
-            x_, sht_buffers["wpct"], interpret)
-        inv = lambda c_: kdispatch.sht_inverse_pallas(  # noqa: E731
-            c_, sht_buffers["pct"], nlon, interpret)
+        fwd = lambda x_: kdispatch.sht_forward(  # noqa: E731
+            x_, sht_buffers["wpct"], kernels)
+        inv = lambda c_: kdispatch.sht_inverse(  # noqa: E731
+            c_, sht_buffers["pct"], nlon, kernels)
     else:
         fwd = lambda x_: shtlib.sht_forward(x_, sht_buffers["wpct"])  # noqa: E731
         inv = lambda c_: shtlib.sht_inverse(c_, sht_buffers["pct"], nlon)  # noqa: E731
@@ -70,9 +70,18 @@ def apply_spectral_conv(params: dict, x: jax.Array, sht_buffers: dict,
     if "w" in params:  # depthwise, real gain
         y = c * params["w"][..., :, None]
     else:
-        # Complex spectral weights always combine in fp32: lax.complex has
-        # no bf16 variant, and the coefficients c are complex64 anyway.
-        w = jax.lax.complex(params["w_re"].astype(jnp.float32),
-                            params["w_im"].astype(jnp.float32))  # (Co,Ci,L)
-        y = jnp.einsum("oil,...ilm->...olm", w, c)
+        # Complex spectral weights always combine in fp32 (the
+        # coefficients c are complex64).  Stacking c's real and imaginary
+        # parts makes the complex product two real contractions in which
+        # each (Co, Ci, L) weight appears once, so a TPU holds one
+        # rounded copy per weight -- hoisted out of the rollout loop, at
+        # 721x1440 each is 0.3 GB -- where XLA's own complex lowering
+        # holds three per block.
+        m = c.shape[-1]
+        c2 = jnp.concatenate([jnp.real(c), jnp.imag(c)], axis=-1)
+        p = jnp.einsum("oil,...ilm->...olm",
+                       params["w_re"].astype(jnp.float32), c2)
+        q = jnp.einsum("oil,...ilm->...olm",
+                       params["w_im"].astype(jnp.float32), c2)
+        y = jax.lax.complex(p[..., :m] - q[..., m:], p[..., m:] + q[..., :m])
     return inv(y)

@@ -13,7 +13,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import axis_size
 
 from repro.core import crps as crpslib
 
@@ -30,7 +29,7 @@ def dist_crps(ens_local: jax.Array, obs_local: jax.Array,
       normalized (sum over *all* ranks and points == 1).
     Returns the scalar spatially averaged CRPS (identical on all ranks).
     """
-    n_e = axis_size(ens_axis)
+    n_e = jax.lax.axis_size(ens_axis)
     # 1) gather ensemble, scatter space: (Eloc,...,S) -> (E, ..., S/nE)
     ens = jax.lax.all_to_all(ens_local, ens_axis, split_axis=ens_local.ndim - 1,
                              concat_axis=0, tiled=True)

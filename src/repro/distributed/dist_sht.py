@@ -24,7 +24,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from repro.distributed.compat import axis_size
 
 
 def _a2a(x, axis_name, split_axis, concat_axis):
@@ -37,11 +36,11 @@ def dist_sht_forward(x: jax.Array, wpct_local: jax.Array, mmax: int,
     """Rank-local body of the forward SHT.
 
     x: (..., C, Hloc, Wloc) local block of the input signal.
-    wpct_local: (H, L, Mloc_over_lat? ...) -- the *full-latitude* Legendre
-      table sliced to this rank's longitudinal mode block: (H, L, Mloc).
+    wpct_local: the *full-latitude* order-major Legendre table sliced to
+      this rank's longitudinal mode block: (Mloc, H, L).
     Returns (..., C, Lloc, Mloc) local coefficient block.
     """
-    w_total = x.shape[-1] * axis_size(lon_axis)
+    w_total = x.shape[-1] * jax.lax.axis_size(lon_axis)
     # 1) gather longitudes, scatter channels (pencil 1)
     xt = _a2a(x, lon_axis, x.ndim - 3, x.ndim - 1)     # (.., Cw, Hloc, W)
     # 2) local FFT + mode truncation
@@ -52,8 +51,8 @@ def dist_sht_forward(x: jax.Array, wpct_local: jax.Array, mmax: int,
     # 4) gather latitudes, scatter channels (pencil 2)
     xf = _a2a(xf, lat_axis, xf.ndim - 3, xf.ndim - 2)  # (.., Ch, H, Mloc)
     # 5) local Legendre-Gauss contraction
-    re = jnp.einsum("...hm,hlm->...lm", jnp.real(xf), wpct_local)
-    im = jnp.einsum("...hm,hlm->...lm", jnp.imag(xf), wpct_local)
+    re = jnp.einsum("...hm,mhl->...lm", jnp.real(xf), wpct_local)
+    im = jnp.einsum("...hm,mhl->...lm", jnp.imag(xf), wpct_local)
     c = jax.lax.complex(re, im)
     # 6) scatter degrees, gather channels back
     return _a2a(c, lat_axis, c.ndim - 2, c.ndim - 3)   # (.., C, Lloc, Mloc)
@@ -63,16 +62,19 @@ def dist_sht_inverse(c: jax.Array, pct_local: jax.Array, nlon: int,
                      lat_axis: str, lon_axis: str) -> jax.Array:
     """Rank-local body of the inverse SHT.
 
-    c: (..., C, Lloc, Mloc); pct_local: (H, L, Mloc).
+    c: (..., C, Lloc, Mloc); pct_local: (Mloc, L, H), order-major.
     Returns (..., C, Hloc, Wloc).
     """
     mmax_local = c.shape[-1]
-    n_lon_ranks = axis_size(lon_axis)
+    n_lon_ranks = jax.lax.axis_size(lon_axis)
     # 1) gather degrees, scatter channels
     ct = _a2a(c, lat_axis, c.ndim - 3, c.ndim - 2)     # (.., Ch, L, Mloc)
     # 2) local inverse Legendre
-    sr = jnp.einsum("...lm,hlm->...hm", jnp.real(ct), pct_local)
-    si = jnp.einsum("...lm,hlm->...hm", jnp.imag(ct), pct_local)
+    hi = jax.lax.Precision.HIGHEST
+    sr = jnp.einsum("...lm,mlh->...hm", jnp.real(ct), pct_local,
+                    precision=hi)
+    si = jnp.einsum("...lm,mlh->...hm", jnp.imag(ct), pct_local,
+                    precision=hi)
     s = jax.lax.complex(sr, si)
     # 3) scatter latitudes, gather channels
     s = _a2a(s, lat_axis, s.ndim - 2, s.ndim - 3)      # (.., C, Hloc, Mloc)

@@ -20,10 +20,7 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P  # noqa: E402
-try:
-    from jax import shard_map  # noqa: E402  # jax >= 0.5
-except ImportError:
-    from jax.experimental.shard_map import shard_map  # noqa: E402
+from jax import shard_map  # noqa: E402
 
 from repro.core import crps as crpslib  # noqa: E402
 from repro.core.sphere import disco as dlib  # noqa: E402
@@ -47,7 +44,7 @@ def check_dist_sht(mesh: Mesh) -> None:
         functools.partial(dist_sht.dist_sht_forward, mmax=t.mmax,
                           lat_axis="lat", lon_axis="lon"),
         mesh=mesh,
-        in_specs=(P(None, None, "lat", "lon"), P(None, None, "lon")),
+        in_specs=(P(None, None, "lat", "lon"), P("lon", None, None)),
         out_specs=P(None, None, "lat", "lon"),
     )
     c_dist = jax.jit(fwd)(x, bufs["wpct"])
@@ -59,7 +56,7 @@ def check_dist_sht(mesh: Mesh) -> None:
         functools.partial(dist_sht.dist_sht_inverse, nlon=64,
                           lat_axis="lat", lon_axis="lon"),
         mesh=mesh,
-        in_specs=(P(None, None, "lat", "lon"), P(None, None, "lon")),
+        in_specs=(P(None, None, "lat", "lon"), P("lon", None, None)),
         out_specs=P(None, None, "lat", "lon"),
     )
     x_dist = jax.jit(inv)(c_ref, bufs["pct"])

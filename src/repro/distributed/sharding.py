@@ -193,8 +193,8 @@ def fcn3_param_specs(params_struct: Any, data_axis=DP, model_axis=MP,
 def fcn3_buffer_specs(buffers_struct: Any, model_axis=MP) -> Any:
     """Geometry buffers: shard along latitude-like dims.
 
-    psi: (K, H_out, S, W) -> H_out over model; Legendre tables (H, L, M) ->
-    H over model (forward) -- GSPMD inserts the reduce for the contraction.
+    psi: (K, H_out, S, W) -> H_out over model; the order-major Legendre
+    tables stay replicated.
     """
     def spec_for(path, leaf) -> P:
         name = _path_str(path).split("/")[-1]

@@ -97,6 +97,11 @@ SCORE_NAMES = ("crps", "ens_rmse", "spread", "ssr", "rank_hist",
                "spectrum", "spectrum_truth")
 
 
+#: one member's latent activation (bytes) above which the engine steps
+#: ensemble members sequentially (see ``ForecastEngine._members_in_sequence``)
+SEQUENTIAL_MEMBER_BYTES = 2**28
+
+
 def in_scan_rank_histogram(ens: jax.Array, target: jax.Array,
                            area_weights: jax.Array) -> jax.Array:
     """(C, E+1) area-weighted rank histogram for the scan body.
@@ -131,8 +136,11 @@ class EngineConfig:
     member_axes:    mesh axes for the leading ensemble dim (paper G.1),
                     e.g. ("model",); several axes all shard dim 0
                     (engine states carry no batch dim, unlike the
-                    trainer's (E, B) convention).  None lets GSPMD
-                    choose.
+                    trainer's (E, B) convention).  The model step then
+                    runs under ``shard_map`` over those axes, each
+                    device stepping its own members (Pallas kernels
+                    cannot be partitioned automatically).  None applies
+                    no member sharding.
     donate:         donate state/noise carries to each chunk call.
     static_buffers: close over the geometry buffers instead of passing
                     them as jit arguments.  Baked buffers constant-fold
@@ -312,6 +320,9 @@ class ForecastEngine:
         self.cfg = cfg
         self.diagnostics = diagnostics
         self.noise_buffers = model.noise.buffers()
+        if cfg.spectra:
+            # spectra run a forward SHT on the IO grid the noise shares
+            self.noise_buffers["wpct"] = model.in_sht.table("wpct")
         self.area_weights = jnp.asarray(model.grid_in.area_weights_2d(),
                                         jnp.float32)
         # IC perturbation sampler: EngineConfig.perturb is the single
@@ -359,6 +370,47 @@ class ForecastEngine:
         return self.cfg.perturb
 
     # ------------------------------------------------------------------
+    @property
+    def _members_in_sequence(self) -> bool:
+        """Step the members one after another instead of side by side
+        (``vmap``) when one member's latent activation passes
+        ``SEQUENTIAL_MEMBER_BYTES``: at 721x1440 the step's transient
+        activations then scale with one member, not E, which is what
+        lets an ensemble fit one chip.  The sequence is unrolled, not a
+        loop: out of a loop over members XLA hoists the rounded copies
+        of loop-invariant weights and tables and holds all of them for
+        the whole loop (2 GB at full width, compile rehearsal)."""
+        mc = self.model.cfg
+        latent = (mc.c_latent * mc.latent_nlat * mc.latent_nlon
+                  * self.cfg.jdtype.itemsize)
+        return latent > SEQUENTIAL_MEMBER_BYTES
+
+    def _step_members(self, params, buffers, s: jax.Array,
+                      cond: jax.Array) -> jax.Array:
+        """One model step of every member: (E, C, H, W) -> (E, C, H, W).
+
+        With ``member_axes`` the step runs under ``shard_map`` over those
+        mesh axes -- each device steps its own members with replicated
+        params and geometry, because a Pallas kernel cannot be
+        partitioned automatically (the TPU compiler refuses it)."""
+        m = self.model
+
+        def step(params, buffers, s, cond):
+            if self._members_in_sequence:
+                return jnp.stack([m.apply(params, buffers, s[i], cond[i])
+                                  for i in range(s.shape[0])])
+            return jax.vmap(
+                lambda se, ce: m.apply(params, buffers, se, ce))(s, cond)
+
+        if self.cfg.member_axes is None:
+            return step(params, buffers, s, cond)
+        from jax.sharding import PartitionSpec
+        ax = PartitionSpec(tuple(self.cfg.member_axes))
+        # check_vma=False: Pallas out_shapes carry no varying-axes info
+        return jax.shard_map(
+            step, in_specs=(PartitionSpec(), PartitionSpec(), ax, ax),
+            out_specs=ax, check_vma=False)(params, buffers, s, cond)
+
     def _constrain(self, x: jax.Array) -> jax.Array:
         if self.cfg.member_axes is None:
             return x
@@ -507,9 +559,8 @@ class ForecastEngine:
             # The spectral path promotes to fp32 through the FFT; pin the
             # carry back to the compute dtype so the scan carry
             # shape/dtype is invariant (no-op in fp32).
-            s = self._constrain(jax.vmap(
-                lambda se, ce: m.apply(params, buffers, se, ce)
-            )(s, cond).astype(dt))
+            s = self._constrain(
+                self._step_members(params, buffers, s, cond).astype(dt))
             z_hat = m.noise.step(jax.random.fold_in(key, x["n"]),
                                  z_hat, nbufs)
             sf = s.astype(jnp.float32)

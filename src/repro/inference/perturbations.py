@@ -155,9 +155,9 @@ class InitialConditionPerturbation:
 
     @property
     def buffers(self) -> dict:
-        """Legendre tables, built lazily: callers that already hold tables
-        for the same SHT (the engine's noise buffers) pass theirs via
-        ``sht_buffers`` and this copy is never materialized."""
+        """Legendre tables, built lazily and shared with every other
+        user of the same SHT (the engine's noise buffers carry the same
+        inverse table)."""
         if self._buffers is None:
             self._buffers = self.sht.buffers()
         return self._buffers
@@ -199,7 +199,7 @@ class InitialConditionPerturbation:
         b = sht_buffers if sht_buffers is not None else self.buffers
         c = noiselib.sample_spectral_coeffs(
             key, (n, n_channels), self.sigma_l, self.sht.lmax, self.sht.mmax)
-        fields = shtlib.sht_inverse(c, b["pct"], self.sht.grid.nlon)
+        fields = self.sht.inverse(c, b)
         return fields * self._channel_scale(n_channels)[:, None, None]
 
     def _rescale(self, p: jax.Array) -> jax.Array:

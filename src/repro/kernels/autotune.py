@@ -9,9 +9,11 @@ budget, so this module adds the missing measurement loop:
   power-of-two tile values, filtered down to VMEM-feasible shapes whose
   padding waste stays bounded (padding exactness itself holds for *any*
   positive tile -- every kernel zero-pads and slices exactly -- so
-  feasibility is purely a performance/VMEM filter).  The default tile is
-  always a candidate: a sweep can never pick something slower than
-  today's hardcoded values.
+  feasibility is purely a performance/VMEM filter).  Every candidate
+  obeys Mosaic's (8, 128) minor-dims tiling rule (``legal_tile``), so
+  a sweep never proposes a tile the TPU compiler refuses.  The default
+  tile is always a candidate: a sweep can never pick something slower
+  than the defaults.
 * **Sweep** -- ``sweep_op`` times every candidate with warmup +
   ``block_until_ready`` (best-of-``iters``), picks the winner
   (ties prefer the default, then the lexicographically smallest dims)
@@ -46,11 +48,12 @@ import os
 import time
 
 from repro.kernels.config import (BLOCK_DEFAULTS, BLOCK_OPS, BlockConfig,
-                                  KernelConfig, default_interpret)
+                                  KernelConfig, default_interpret,
+                                  legal_tile)
 
 #: bump when the candidate lattice or entry schema changes incompatibly;
 #: part of every entry token, so old caches read as stale, not wrong
-LATTICE_VERSION = "1"
+LATTICE_VERSION = "2"
 
 #: VMEM budget one kernel instance may plan for (half of the ~16 MB/core
 #: so double buffering still fits)
@@ -65,7 +68,7 @@ WASTE_BOUND = 2.0
 #: ``sweep_op`` and the ``shapes`` list in every cache entry)
 OP_SHAPE_FIELDS = {
     "legendre": ("b", "k", "n", "m"),
-    "disco": ("b", "h", "s", "w_in", "k", "d", "stride"),
+    "disco": ("n", "r", "h_in", "h", "s", "w_in", "k", "d", "q", "stride"),
     "crps": ("e", "n"),
     "ssd": ("bc", "l", "h", "p", "g", "n"),
 }
@@ -73,11 +76,11 @@ OP_SHAPE_FIELDS = {
 #: candidate values per block dim (cross product, then feasibility)
 _LATTICE = {
     "legendre": {"b_blk": (8, 16, 32, 64, 128, 256),
-                 "k_blk": (8, 16, 32, 64, 128, 256),
-                 "n_blk": (8, 16, 32, 64, 128, 256),
+                 "k_blk": (128, 256, 512),
+                 "n_blk": (128, 256, 512),
                  "m_blk": (1, 2, 4, 8, 16)},
-    "disco": {"b_blk": (1, 2, 4, 8, 16, 32),
-              "h_blk": (1, 2, 4, 8, 16, 32)},
+    "disco": {"c_blk": (128, 256, 512),
+              "w_blk": (128, 256)},
     "crps": {"n_blk": (128, 256, 512, 1024, 2048, 4096, 8192)},
     "ssd": {"bc_blk": (1, 2, 4, 8)},
 }
@@ -85,7 +88,7 @@ _LATTICE = {
 #: which shape field each block dim tiles (for waste estimation)
 _DIM_EXTENT = {
     "legendre": {"b_blk": "b", "k_blk": "k", "n_blk": "n", "m_blk": "m"},
-    "disco": {"b_blk": "b", "h_blk": "h"},
+    "disco": {"c_blk": "r", "w_blk": "w_out"},
     "crps": {"n_blk": "n"},
     "ssd": {"bc_blk": "bc"},
 }
@@ -117,13 +120,11 @@ def vmem_bytes(op: str, dims: dict, shapes) -> int:
             dims["m_blk"]
         return 4 * (b * k * m + k * n * m + 2 * b * n * m)
     if op == "disco":
-        b, h = dims["b_blk"], dims["h_blk"]
-        w_out = s["w_in"] // s["stride"]
-        x_blk = b * h * s["s"] * (s["w_in"] + s["d"])
-        psi_blk = s["k"] * h * s["s"] * s["d"]
-        win = b * h * s["s"] * s["d"] * w_out
-        out = b * s["k"] * h * w_out
-        return 4 * (x_blk + psi_blk + win + out)
+        from repro.kernels.disco import disco
+        stride = s["stride"]
+        g = disco.tile_geometry(s["r"], s["d"], s["w_in"] // stride, stride,
+                                BlockConfig.make("disco", **dims))
+        return disco.vmem_bytes(g, s["k"], s["q"], stride)
     if op == "crps":
         return 4 * (s["e"] + 4) * dims["n_blk"]
     if op == "ssd":
@@ -134,9 +135,16 @@ def vmem_bytes(op: str, dims: dict, shapes) -> int:
     raise ValueError(f"unknown op {op!r}")
 
 
+def _extents(op: str, shapes) -> dict:
+    s = _shape_dict(op, shapes)
+    if op == "disco":
+        s["w_out"] = s["w_in"] // s["stride"]
+    return s
+
+
 def padding_waste(op: str, dims: dict, shapes) -> float:
     """Product over tiled dims of padded_extent / extent (>= 1.0)."""
-    s = _shape_dict(op, shapes)
+    s = _extents(op, shapes)
     w = 1.0
     for name, value in dims.items():
         extent = s[_DIM_EXTENT[op][name]]
@@ -146,10 +154,12 @@ def padding_waste(op: str, dims: dict, shapes) -> float:
 
 def feasible(op: str, dims: dict, shapes,
              vmem_budget: int = VMEM_BUDGET_BYTES) -> bool:
-    """VMEM fit + bounded padding waste for every tiled dim."""
+    """Legal tile + VMEM fit + bounded padding waste for every tiled dim."""
+    if not legal_tile(op, dims):
+        return False
     if vmem_bytes(op, dims, shapes) > vmem_budget:
         return False
-    s = _shape_dict(op, shapes)
+    s = _extents(op, shapes)
     for name, value in dims.items():
         extent = s[_DIM_EXTENT[op][name]]
         if _pad_up(extent, value) > WASTE_BOUND * max(extent, 1):
@@ -205,15 +215,19 @@ def _op_call(op: str, shapes, dtype: str, interpret: bool,
     if op == "legendre":
         from repro.kernels.legendre.legendre import legendre_contract
         x = arr(s["b"], s["k"], s["m"])
-        t = arr(s["k"], s["n"], s["m"])
+        t = arr(s["m"], s["k"], s["n"])
         return lambda: legendre_contract(x, t, interpret=interpret,
                                          blocks=blocks)
     if op == "disco":
         from repro.kernels.disco.disco import disco_band_contract
-        x = arr(s["b"], s["h"], s["s"], s["w_in"])
+        x = arr(s["n"], s["r"], s["h_in"], s["w_in"])
         psi = arr(s["k"], s["h"], s["s"], s["d"])
+        mix = arr(s["k"], s["q"], s["r"])
         stride = s["stride"]
-        return lambda: disco_band_contract(x, psi, stride=stride,
+        affine = (max(1, s["h_in"] // s["h"]), -(s["s"] // 2))
+        return lambda: disco_band_contract(x, psi, mix, stride=stride,
+                                           affine=affine,
+                                           off0=-(s["d"] // 2),
                                            interpret=interpret,
                                            blocks=blocks)
     if op == "crps":
@@ -499,19 +513,20 @@ def model_op_shapes(model, members: int = 2) -> dict:
     """Concrete tuning shapes for a live ``FCN3``'s hot ops.
 
     legendre: the latent-grid SHT slab batched over ``members`` member
-    channels (the spectral-convolution hot spot); disco: the encoder
-    plan's banded contraction; crps: the pointwise score over the full
+    channels (the spectral-convolution hot spot); disco: the local
+    blocks' latent-plan contraction with its channel mix; crps: the pointwise score over the full
     state.  One shape per op family -- ``TuningCache.best_for`` serves
     the largest tuned slab, so tune at the dominant one.
     """
     import jax.numpy as jnp
     cfg = model.cfg
-    h, l, m = model.latent_sht.buffers()["wpct"].shape
+    m, h, l = model.latent_sht.buffer_specs()["wpct"].shape
     shapes = {"legendre": (members * cfg.c_latent, h, l, m)}
-    band = model.enc_plan.banded_buffers(jnp.float32)
+    band = model.latent_plan.banded_buffers(jnp.float32)
     k, h_out, s, d = band["psi_band"].shape
-    shapes["disco"] = (members * cfg.c_latent, h_out, s,
-                       model.grid_in.nlon, k, d, model.enc_plan.stride)
+    c_in = cfg.c_latent + cfg.cond_embed
+    shapes["disco"] = (members, c_in, model.grid_latent.nlat, h_out, s,
+                       model.grid_latent.nlon, k, d, cfg.c_latent, 1)
     shapes["crps"] = (members, cfg.n_state * cfg.nlat * cfg.nlon)
     return shapes
 
@@ -527,10 +542,11 @@ def op_flops_bytes(op: str, shapes) -> tuple[float, float]:
                      + s["b"] * s["n"] * s["m"])
     elif op == "disco":
         w_out = s["w_in"] // s["stride"]
-        flops = 2.0 * s["b"] * s["k"] * s["h"] * s["s"] * s["d"] * w_out
-        mem = 4.0 * (s["b"] * s["h"] * s["s"] * s["w_in"]
+        pix = s["n"] * s["h"] * w_out
+        flops = 2.0 * pix * s["k"] * s["r"] * (s["s"] * s["d"] + s["q"])
+        mem = 4.0 * (s["n"] * s["r"] * s["h_in"] * s["w_in"]
                      + s["k"] * s["h"] * s["s"] * s["d"]
-                     + s["b"] * s["k"] * s["h"] * w_out)
+                     + s["k"] * s["q"] * s["r"] + pix * s["q"])
     elif op == "crps":
         flops = 3.0 * s["e"] * s["e"] * s["n"]
         mem = 4.0 * (s["e"] * s["n"] + 2 * s["n"])

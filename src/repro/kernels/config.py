@@ -37,15 +37,37 @@ _OPS = ("sht", "disco")
 #: covers both SHT directions (the contraction is the same kernel).
 BLOCK_OPS = ("legendre", "disco", "crps", "ssd")
 
-#: today's hardcoded tile shapes, now the authoritative defaults: an
-#: empty/absent ``BlockConfig`` resolves to exactly these values, so the
-#: untuned dispatch stays bit-identical (same pallas_call, same grid).
+#: the authoritative default tile shapes: an empty/absent
+#: ``BlockConfig`` resolves to exactly these values.
 BLOCK_DEFAULTS = {
     "legendre": {"b_blk": 128, "k_blk": 128, "m_blk": 8, "n_blk": 128},
-    "disco": {"b_blk": 8, "h_blk": 8},
+    "disco": {"c_blk": 128, "w_blk": 128},
     "crps": {"n_blk": 1024},
     "ssd": {"bc_blk": 1},
 }
+
+#: Mosaic's tiling rule for the two minor dims of a block: the
+#: second-minor (sublane) dim must be a multiple of 8 and the minor
+#: (lane) dim a multiple of 128 (or span the whole array dim, which the
+#: zero-padding wrappers never rely on).  Per op, the multiple each tile
+#: dim must honour because of the operand blocks it lands in; dims absent
+#: here are leading (batch/grid) dims with no constraint.
+TILE_MULTIPLES = {
+    # x (m, b, k), table (m, k, n), out (m, b, n)
+    "legendre": {"b_blk": 8, "k_blk": 128, "n_blk": 128},
+    # x (P, c, W) sublane c; mix (K, Q, c) lane c; output tiles of w_blk
+    # lanes.  A channel axis no wider than c_blk is one whole-axis tile.
+    "disco": {"c_blk": 128, "w_blk": 128},
+    # ens (E, n), obs/out (1, n)
+    "crps": {"n_blk": 128},
+    "ssd": {},
+}
+
+
+def legal_tile(op: str, dims: dict) -> bool:
+    """True iff every tile dim of ``dims`` obeys ``TILE_MULTIPLES[op]``."""
+    rule = TILE_MULTIPLES[op]
+    return all(v % rule.get(name, 1) == 0 for name, v in dims.items())
 
 
 def compiled_backend() -> bool:
@@ -140,7 +162,9 @@ class KernelConfig:
       ``interpret=True`` is the *only* way to get the Pallas kernels
       (interpret mode exists for parity testing, not speed): a plain
       ``sht="pallas"`` on CPU degrades to the reference path rather
-      than silently running the interpreter in production.
+      than silently running the interpreter.  On a compiled backend
+      ``interpret=True`` is an error: a chip never runs the
+      interpreter, and a "pallas" op there always compiles.
 
     blocks: tile-shape overrides, a tuple of ``BlockConfig`` (at most
       one per op family, sorted by op).  Empty means the hardcoded
@@ -204,14 +228,17 @@ class KernelConfig:
             raise ValueError(f"unknown kernel op {op!r}; expected {_OPS}")
         mode = getattr(self, op)
         compiled = compiled_backend()
-        interpret = (self.interpret if self.interpret is not None
-                     else not compiled)
+        if compiled and self.interpret:
+            raise ValueError(
+                f"KernelConfig.interpret=True on the compiled "
+                f"{jax.default_backend()!r} backend: Pallas kernels "
+                f"compile there, they never run interpreted")
         if mode == "auto":
             mode = "pallas" if compiled else "reference"
         if mode == "pallas" and not compiled and self.interpret is not True:
             # CPU interpret mode only on explicit request
             mode = "reference"
-        return mode, interpret
+        return mode, not compiled
 
     def effective(self) -> dict[str, str]:
         """Resolved dispatch summary (for stats endpoints / benchmarks)."""

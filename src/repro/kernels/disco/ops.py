@@ -1,19 +1,15 @@
-"""Jitted public wrappers for the Pallas DISCO band kernel.
+"""Host-side helpers for the Pallas DISCO band kernel.
 
-``disco_conv_banded`` mirrors ``repro.core.sphere.disco.disco_conv`` (the
-exact FFT path) for plans whose longitudinal support fits a narrow band --
-i.e. all latitude rows away from the poles.  ``banded_psi_from_plan``
-extracts the (K, H, S, D) band (and checks it is exact) from a DiscoPlan.
+``banded_psi_from_plan`` extracts the (K, H, S, D) band (and checks it is
+exact) from a DiscoPlan.  The jitted model path is
+``repro.kernels.dispatch.disco_conv_mixed``.
 """
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
 from repro.core.sphere.disco import DiscoPlan
-from repro.kernels.disco.disco import disco_band_contract
 
 
 def banded_psi_from_plan(plan: DiscoPlan, d_max: int | None = None
@@ -53,29 +49,3 @@ def banded_psi_from_plan(plan: DiscoPlan, d_max: int | None = None
     outside[idx] = False
     exact = not np.any(psi[:, :, :, outside])
     return band.astype(np.float32), int(off0), exact
-
-
-def disco_conv_banded(x: jax.Array, psi_band: jax.Array, lat_idx: jax.Array,
-                      off0: int, stride: int = 1,
-                      interpret: bool | None = None) -> jax.Array:
-    """Banded DISCO conv matching ``disco_conv`` (FFT path) semantics.
-
-    x: (..., H_in, W_in); psi_band: (K, H_out, S, D); lat_idx: (H_out, S);
-    off0: longitudinal offset of the first band tap (may be negative).
-    ``interpret=None`` auto-detects from the backend.
-    Returns (..., K, H_out, W_out).
-    """
-    batch = x.shape[:-2]
-    w_in = x.shape[-1]
-    xb = x.reshape((-1,) + x.shape[-2:])
-    # roll so the first band tap sits at offset 0
-    xb = jnp.roll(xb, -off0, axis=-1) if off0 else xb
-    xg = jnp.take(xb, lat_idx, axis=-2)  # (B, H_out, S, W_in)
-    out = disco_band_contract(xg, psi_band, stride=stride,
-                              interpret=interpret)
-    if off0:
-        # the roll shifted the *input* by -off0; output index w corresponds
-        # to input window starting at w*stride + off0, matching the FFT path.
-        pass
-    k, h_out = psi_band.shape[0], psi_band.shape[1]
-    return out.reshape(batch + (k, h_out, w_in // stride))

@@ -1,19 +1,27 @@
-"""Pure-jnp oracle for the banded DISCO contraction."""
+"""Pure-jnp oracle for the banded DISCO contraction + channel mix."""
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from repro.kernels.disco.disco import band_rows
 
 
-def disco_band_contract_ref(x_gathered: jax.Array, psi_band: jax.Array,
-                            stride: int = 1) -> jax.Array:
-    """out[b,k,h,w] = sum_{s,d} psi[k,h,s,d] * x[b,h,s,(w*stride+d) % W]."""
-    b, h, s, w_in = x_gathered.shape
-    k, _, _, d = psi_band.shape
+def disco_band_contract_ref(x: jax.Array, psi_band: jax.Array,
+                            mix: jax.Array, stride: int = 1,
+                            affine: tuple = (1, 0),
+                            off0: int = 0) -> jax.Array:
+    """y[n,q,h,w] = sum_{k,r} mix[k,q,r] sum_{s,d} psi[k,h,s,d]
+    * x[n, r, clip(a*h+s+b), (w*stride + off0 + d) % W]."""
+    n, r, h_in, w_in = x.shape
+    k, h_out, s, d = psi_band.shape
     w_out = w_in // stride
-    xp = jnp.concatenate([x_gathered, x_gathered[..., :d]], axis=-1)
-    win = jnp.stack(
-        [xp[..., dd:dd + (w_out - 1) * stride + 1:1][..., ::stride]
-         for dd in range(d)], axis=-2)  # (B, H, S, D, W_out)
-    return jnp.einsum("khsd,bhsdw->bkhw",
-                      psi_band.astype(jnp.float32),
-                      win.astype(jnp.float32))
+    rows = band_rows(h_out, s, affine, h_in)
+    xg = jnp.take(x.astype(jnp.float32), jnp.asarray(rows.reshape(-1)),
+                  axis=2).reshape(n, r, h_out, s, w_in)
+    lon = (stride * np.arange(w_out)[None, :] + off0
+           + np.arange(d)[:, None]) % w_in                    # (D, W_out)
+    win = jnp.take(xg, jnp.asarray(lon.reshape(-1)), axis=-1)
+    win = win.reshape(n, r, h_out, s, d, w_out)
+    z = jnp.einsum("khsd,nrhsdw->nrkhw", psi_band.astype(jnp.float32), win)
+    return jnp.einsum("kqr,nrkhw->nqhw", mix.astype(jnp.float32), z)

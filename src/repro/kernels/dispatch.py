@@ -68,24 +68,25 @@ def _legendre_bwd(interpret, blocks, res, g):
 _legendre.defvjp(_legendre_fwd, _legendre_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _band_contract(xg: jax.Array, psi_band: jax.Array, stride: int,
-                   interpret: bool, blocks=None) -> jax.Array:
-    """Pallas banded DISCO contraction with a reference-math VJP."""
-    return disco_band_contract(xg, psi_band, stride=stride,
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
+def _band_contract(x: jax.Array, psi_band: jax.Array, mix: jax.Array,
+                   stride: int, affine: tuple, off0: int, interpret: bool,
+                   blocks=None) -> jax.Array:
+    """Pallas banded DISCO contraction + channel mix, reference-math VJP."""
+    return disco_band_contract(x, psi_band, mix, stride=stride,
+                               affine=affine, off0=off0,
                                interpret=interpret, blocks=blocks)
 
 
-def _band_fwd(xg, psi_band, stride, interpret, blocks):
-    return (_band_contract(xg, psi_band, stride, interpret, blocks),
-            (xg, psi_band))
+def _band_fwd(x, psi_band, mix, stride, affine, off0, interpret, blocks):
+    return (_band_contract(x, psi_band, mix, stride, affine, off0,
+                           interpret, blocks), (x, psi_band, mix))
 
 
-def _band_bwd(stride, interpret, blocks, res, g):
-    xg, psi_band = res
+def _band_bwd(stride, affine, off0, interpret, blocks, res, g):
     _, vjp = jax.vjp(
-        lambda x_, p_: disco_band_contract_ref(x_, p_, stride=stride),
-        xg, psi_band)
+        lambda x_, p_, m_: disco_band_contract_ref(
+            x_, p_, m_, stride=stride, affine=affine, off0=off0), *res)
     return vjp(g)
 
 
@@ -108,12 +109,13 @@ def sht_forward_pallas(x: jax.Array, wpct: jax.Array,
 
     Same contract (and same longitudinal transform, including the
     DFT-as-GEMM ``REPRO_DFT_MODE``) as ``core.sphere.sht.sht_forward``;
-    only the (..., H, M) x (H, L, M) Legendre contraction changes
-    engine.  ``blocks`` is the "legendre" tile override (None = defaults).
+    only the (..., H, M) x (M, H, L) Legendre contraction changes
+    engine, on the order-major table as ``SHT.table`` builds it.
+    ``blocks`` is the "legendre" tile override (None = defaults).
     """
     if interpret is None:
         interpret = default_interpret()
-    h, l, m = wpct.shape
+    m, h, l = wpct.shape
     w = x.shape[-1]
     xf = fourier.rfft(x.astype(jnp.float32), axis=-1)[..., :m]
     xf = xf * (2.0 * jnp.pi / w)
@@ -127,22 +129,17 @@ def sht_forward_pallas(x: jax.Array, wpct: jax.Array,
 def sht_inverse_pallas(c: jax.Array, pct: jax.Array, nlon: int,
                        interpret: bool | None = None,
                        blocks=None) -> jax.Array:
-    """Inverse SHT with the Legendre stage on the Pallas kernel."""
+    """Inverse SHT with the Legendre stage on the Pallas kernel; ``pct``
+    is the order-major (M, L, H) table, contracted over degree L."""
     if interpret is None:
         interpret = default_interpret()
-    h, l, m = pct.shape
-    table = pct.transpose(1, 0, 2)  # (L, H, M): contract over degree L
+    m, l, h = pct.shape
     re, batch = _flatten_batch(jnp.real(c), 2)
     im, _ = _flatten_batch(jnp.imag(c), 2)
-    sr = _legendre(re.astype(jnp.float32), table, interpret, blocks)
-    si = _legendre(im.astype(jnp.float32), table, interpret, blocks)
-    spec = jax.lax.complex(sr, si).reshape(batch + (h, m))
-    pad = nlon // 2 + 1 - m
-    if pad < 0:
-        raise ValueError(f"mmax={m} too large for nlon={nlon}")
-    if pad:
-        spec = jnp.pad(spec, [(0, 0)] * (spec.ndim - 1) + [(0, pad)])
-    return fourier.irfft(spec, n=nlon, axis=-1) * nlon
+    sr = _legendre(re.astype(jnp.float32), pct, interpret, blocks)
+    si = _legendre(im.astype(jnp.float32), pct, interpret, blocks)
+    return shtlib.synthesize(jax.lax.complex(sr, si).reshape(batch + (h, m)),
+                             nlon)
 
 
 def sht_forward(x: jax.Array, wpct: jax.Array,
@@ -171,64 +168,59 @@ def sht_inverse(c: jax.Array, pct: jax.Array, nlon: int,
 # DISCO dispatch
 # ---------------------------------------------------------------------------
 
-def disco_conv_banded_buffers(x: jax.Array, buffers: dict, stride: int,
-                              affine: tuple[int, int] | None = None,
-                              kernels: KernelConfig | None = None
-                              ) -> jax.Array:
-    """Banded-buffer DISCO contraction: Pallas band + FFT wrap rows.
+#: channel rows one band-kernel call should reach: leading dims of the
+#: input are folded into its channel axis until it is at least this wide,
+#: so the Toeplitz GEMMs stream enough rows through the MXU
+_ROW_TARGET = 128
 
-    x: (..., H_in, W_in) -> (..., K, H_out, W_out), numerically matching
-    ``core.sphere.disco.disco_conv`` on the full psi tensor.  Buffers
-    come from ``DiscoPlan.banded_buffers``; the band tap convention is
-    ``off0 = -(D // 2)`` so all statics derive from buffer shapes.
+
+def disco_conv_mixed(x: jax.Array, weight: jax.Array, buffers: dict,
+                     stride: int, groups: int = 1,
+                     affine: tuple[int, int] | None = None,
+                     kernels: KernelConfig | None = None) -> jax.Array:
+    """DISCO convolution without bias on the banded buffers: Pallas band
+    kernel (contraction fused with the channel mix) + exact FFT wrap rows.
+
+    x: (..., C_in, H_in, W_in); weight: (C_out, C_in // groups, K) ->
+    (..., C_out, H_out, W_out), numerically matching
+    ``core.sphere.disco.apply_disco_conv`` on the full psi tensor minus
+    its bias.  Buffers come from ``DiscoPlan.banded_buffers``; the band
+    tap convention is ``off0 = -(D // 2)``.
     """
+    if affine is None:
+        raise ValueError("the banded DISCO kernel needs an affine plan")
     kc = kernels or _DEFAULT
     _, interpret = kc.resolve("disco")
-    blocks = kc.blocks_for("disco")
     psi_band = buffers["psi_band"]
     k, h_out, s, d = psi_band.shape
-    batch = x.shape[:-2]
-    w_in = x.shape[-1]
-    off0 = -(d // 2)
-    # roll so band tap 0 sits at longitudinal offset off0
-    xr = jnp.roll(x, -off0, axis=-1) if off0 else x
-    xg = discolib._gather_band(xr, buffers["lat_idx"], affine, h_out)
-    xb = xg.reshape((-1,) + xg.shape[-3:]).astype(jnp.float32)
-    out = _band_contract(xb, psi_band.astype(jnp.float32), stride, interpret,
-                         blocks)
-    out = out.reshape(batch + (k, h_out, w_in // stride))
+    lead, c_in = x.shape[:-3], x.shape[-3]
+    h_in, w_in = x.shape[-2:]
+    # fold trailing leading dims (e.g. the 13 pressure levels sharing one
+    # encoder) into the kernel's channel rows, with a block-diagonal mix
+    fold, rows = 0, c_in
+    while rows < _ROW_TARGET and fold < len(lead):
+        fold += 1
+        rows *= lead[-fold]
+    nfold = rows // c_in
+    mix = discolib.dense_mix(weight.astype(jnp.float32), groups)
+    if nfold > 1:
+        eye = jnp.eye(nfold, dtype=jnp.float32)
+        mix = jnp.einsum("kqr,fg->kfqgr", mix, eye).reshape(
+            k, nfold * mix.shape[1], rows)
+    xb = x.reshape((-1, rows, h_in, w_in))
+    y = _band_contract(xb, psi_band.astype(jnp.float32), mix, stride,
+                       tuple(affine), -(d // 2), interpret,
+                       kc.blocks_for("disco"))
+    c_out = weight.shape[0]
+    y = y.reshape(lead + (c_out, h_out, w_in // stride))
     wrap_rows = buffers["wrap_rows"]
     if wrap_rows.shape[0]:
         # Exact FFT circular correlation on the wrap rows only; their
         # psi keeps the full circle of offsets (zero band contribution).
-        # Reuse the already-gathered xg instead of a second gather from
-        # x: a jnp.take over x's latitude axis would make the SPMD
-        # partitioner replicate the whole operand (the failure mode
-        # _gather_band's strided slices exist to avoid).  xg carries the
-        # rolled input, which shifts the correlation by off0 -- undone
-        # by rolling the full-rate output back before striding.
-        xw = jnp.take(xg, wrap_rows, axis=-3)          # (..., Hw, S, W)
-        xf = fourier.rfft(xw.astype(jnp.float32), axis=-1)
-        pf = fourier.rfft(buffers["psi_wrap"].astype(jnp.float32), axis=-1)
-        prod = jnp.einsum("...hsf,khsf->...khf", xf, jnp.conj(pf))
-        outw = fourier.irfft(prod, n=w_in, axis=-1)
-        if off0:
-            outw = jnp.roll(outw, off0, axis=-1)
-        if stride > 1:
-            outw = outw[..., ::stride]
-        out = out.at[..., wrap_rows, :].set(outw)
-    return out
-
-
-def disco_conv(x: jax.Array, buffers: dict, stride: int,
-               affine: tuple[int, int] | None = None,
-               kernels: KernelConfig | None = None) -> jax.Array:
-    """Buffer-layout-routed raw DISCO contraction.
-
-    Banded buffers (pallas dispatch) take the hybrid band-kernel path;
-    full-psi buffers take the reference FFT correlation.
-    """
-    if "psi_band" in buffers:
-        return disco_conv_banded_buffers(x, buffers, stride, affine, kernels)
-    return discolib.disco_conv(x, buffers["psi"], buffers["lat_idx"],
-                               stride, affine)
+        rows_w = jnp.take(buffers["lat_idx"], wrap_rows, axis=0)
+        xw = jnp.take(x, rows_w.reshape(-1), axis=-2)
+        xw = xw.reshape(x.shape[:-2] + rows_w.shape + (w_in,))
+        zw = discolib.fft_correlate(xw, buffers["psi_wrap"], stride)
+        yw = discolib.mix_basis(zw, weight, groups)
+        y = y.at[..., wrap_rows, :].set(yw.astype(y.dtype))
+    return y

@@ -2,7 +2,8 @@
 
 A bundle turns replica boot from a minutes-scale trace+compile into a
 seconds-scale artifact fetch: it packs the ``jax.export`` StableHLO
-blobs, the XLA compilation cache, the precomputed SHT/DISCO geometry
+blobs, the compiled programs of the XLA compilation cache, the
+precomputed SHT/DISCO geometry
 plans and the engine-pool manifest for a declared set of request shapes
 (see ``repro.serving.bundle``).
 
@@ -33,8 +34,8 @@ _log = logging.getLogger("repro.launch.bundle")
 
 
 def _cmd_build(args: argparse.Namespace) -> int:
-    # bundle.pack configures the XLA compilation cache before anything
-    # compiles -- nothing jax-heavy may be imported before this call
+    # bundle.pack configures the process's one XLA compilation cache
+    # (repro.compile_cache) and packs the programs the build used
     from repro.serving.bundle import pack
     from repro.serving.spec import RequestSpec
     specs = []

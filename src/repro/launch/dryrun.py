@@ -1,5 +1,9 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+# The dry run compiles for fake host devices only: pin the CPU backend,
+# so neither this process nor the per-case children it spawns with
+# --all ever claims an attached accelerator.
+os.environ["JAX_PLATFORMS"] = "cpu"
 # DFT-as-GEMM: XLA SPMD replicates fft operands even when only batch dims
 # are sharded (see repro.core.sphere.fourier) -- matmul mode keeps every
 # longitudinal transform rank-local and MXU-bound.
@@ -18,7 +22,9 @@ Usage:
   python -m repro.launch.dryrun --all --out results.jsonl [--jobs 3]
 
 The 512-device XLA flag above MUST precede any other import that touches
-jax (jax locks the device count at first init).
+jax (jax locks the device count at first init); the CPU backend is
+pinned there too, so on a host with a TPU the orchestrating parent and
+its children never contend for the chip.
 """
 
 import argparse      # noqa: E402

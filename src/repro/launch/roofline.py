@@ -131,8 +131,6 @@ class Roofline:
 def analyze(name: str, compiled, chips: int, model_flops: float,
             hlo_text: str | None = None) -> Roofline:
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, list):  # older jax returns [dict]
-        cost = cost[0] if cost else {}
     flops = float(cost.get("flops", 0.0))
     byts = float(cost.get("bytes accessed", 0.0))
     text = hlo_text if hlo_text is not None else compiled.as_text()

@@ -127,6 +127,8 @@ def main() -> None:
     ap.add_argument("--sample", type=int, default=123)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    from repro import compile_cache
+    compile_cache.configure()
     if args.legacy_loop and (args.perturb != "none" or args.calibration
                              or args.scores_out or args.kernels != "auto"):
         ap.error("--perturb/--calibration/--scores-out/--kernels require "

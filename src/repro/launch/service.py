@@ -16,11 +16,13 @@ then, from anywhere::
 
   python -m repro.serving.client --port 8771 --members 2 --lead-steps 4
 
-``--persist-dir D`` persists compiled chunk programs across processes:
-``jax.export`` blobs for the lowered StableHLO (skips Python tracing)
-*and* the XLA compilation cache (skips the backend compile), so a
-restarted service warm-starts from disk.  ``--warm SPEC_JSON`` compiles
-executables for a request shape before the server accepts traffic.
+``--persist-dir D`` persists compiled chunk programs across processes
+as ``jax.export`` blobs of the lowered StableHLO (skips Python
+tracing); the backend compile is served by the process's one XLA
+compilation cache (``repro.compile_cache``: ``JAX_COMPILATION_CACHE_DIR``
+when set, else ``.jax_cache/`` in the checkout), so a restarted service
+warm-starts from disk.  ``--warm SPEC_JSON`` compiles executables for a
+request shape before the server accepts traffic.
 
 ``--bundle PATH`` boots a zero-cold-start replica from a warm-start
 bundle built by ``python -m repro.launch.bundle build``: the manifest
@@ -39,27 +41,14 @@ from __future__ import annotations
 import argparse
 import json
 import logging
-import os
 
 from repro.configs import fcn3 as fcn3cfg
 
 _log = logging.getLogger("repro.launch.service")
 
 
-def _enable_xla_cache(persist_dir: str) -> None:
-    """Point JAX's persistent compilation cache into the persist dir, so
-    a fresh process skips the backend compile of restored programs too."""
-    import jax
-    cache_dir = os.path.join(persist_dir, "xla")
-    os.makedirs(cache_dir, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:  # older jax: keep the default threshold
-        pass
-
-
-def main(argv=None) -> None:
+def build_parser() -> argparse.ArgumentParser:
+    """The launcher's command line (shared with ``chip_smoke.py``)."""
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8771,
@@ -97,8 +86,10 @@ def main(argv=None) -> None:
                          "floor instead of missing (default: within "
                          "25%% of the total deadline budget)")
     ap.add_argument("--persist-dir", default=None,
-                    help="persist compiled chunk programs (jax.export "
-                         "blobs + XLA compilation cache) here")
+                    help="persist compiled chunk programs here as "
+                         "jax.export blobs (the XLA compilation cache is "
+                         "repro.compile_cache's: JAX_COMPILATION_CACHE_DIR "
+                         "or .jax_cache/)")
     ap.add_argument("--tuning-dir", default=None, metavar="DIR",
                     help="install this kernel TuningCache (built by "
                          "repro.launch.tune): every engine resolves the "
@@ -160,21 +151,30 @@ def main(argv=None) -> None:
                          "when disabled, so this mainly declutters")
     ap.add_argument("--log-level", default="INFO",
                     help="level for the repro.* loggers on stderr")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def build_service(args: argparse.Namespace):
+    """Start-up of a replica from parsed launcher arguments: compile
+    cache, model preload, optional tuning and bundle boot, warmup.
+    Returns the ready ``ForecastService`` (not yet listening) and a
+    ``startup`` dict of wall times (``preload`` per config with its
+    ``plans_s``/``calibrate_s`` split, ``warm`` per warmed spec).
+    Raises ``ValueError`` on inconsistent arguments.
+    """
     if args.bundle and args.persist_dir:
-        ap.error("--bundle and --persist-dir are mutually exclusive: a "
-                 "bundle replica serves a readonly executable set")
+        raise ValueError("--bundle and --persist-dir are mutually "
+                         "exclusive: a bundle replica serves a readonly "
+                         "executable set")
     if args.bundle and (args.tune or args.tuning_dir):
-        ap.error("--bundle and --tune/--tuning-dir are mutually "
-                 "exclusive: a bundle replica resolves the tunings "
-                 "packed in the bundle")
+        raise ValueError("--bundle and --tune/--tuning-dir are mutually "
+                         "exclusive: a bundle replica resolves the "
+                         "tunings packed in the bundle")
     if args.tune and not args.tuning_dir:
         args.tuning_dir = ".tuning"
 
-    if args.persist_dir:
-        _enable_xla_cache(args.persist_dir)
-
-    # Imports after the cache config: jax reads it at first use.
+    from repro import compile_cache
+    compile_cache.configure()
     from repro.serving.cache import ExecutableCache
     from repro.serving.observability import (ObservabilityConfig,
                                              setup_logging)
@@ -194,7 +194,7 @@ def main(argv=None) -> None:
             spec = RequestSpec.from_dict(json.loads(raw))
             spec.validate()
         except (ValueError, TypeError, json.JSONDecodeError) as e:
-            ap.error(f"--warm {raw!r}: {e}")
+            raise ValueError(f"--warm {raw!r}: {e}") from e
         warm_specs.append(spec)
 
     faults = None
@@ -203,7 +203,7 @@ def main(argv=None) -> None:
         try:
             faults = FaultInjector.from_args(args.fault)
         except ValueError as e:
-            ap.error(f"--fault: {e}")
+            raise ValueError(f"--fault: {e}") from e
         _log.warning("fault injection ARMED: %s (do not deploy this "
                      "replica to production)", args.fault)
 
@@ -265,11 +265,14 @@ def main(argv=None) -> None:
         scheduler = ForecastScheduler(
             pool=pool, cache=ExecutableCache(args.persist_dir),
             **sched_kwargs)
+    startup = {"preload": {}, "warm": []}
     for name in args.config:
         _log.info("preloading config %r ...", name)
-        pool.get(name)
+        startup["preload"][name] = pool.get(name).build_s
     for spec in warm_specs:
         out = scheduler.warmup(spec)
+        startup["warm"].append({"spec": spec.to_dict(),
+                                "compile_s": out["compile_s"]})
         _log.info("warmed %s: compile_s=%.2f (%s)", spec.to_dict(),
                   out["compile_s"],
                   [o["source"] for o in out["outcomes"]])
@@ -283,8 +286,16 @@ def main(argv=None) -> None:
 
     # Preload + warmup done: flip /readyz from "starting" to "ready".
     scheduler.mark_ready()
+    return ForecastService(scheduler=scheduler), startup
 
-    service = ForecastService(scheduler=scheduler)
+
+def main(argv=None) -> None:
+    ap = build_parser()
+    args = ap.parse_args(argv)
+    try:
+        service, _ = build_service(args)
+    except ValueError as e:
+        ap.error(str(e))
     server = service.make_server(args.host, args.port)
     host, port = server.server_address[:2]
     _log.info("listening on http://%s:%s (POST /v1/forecast, "

@@ -71,6 +71,8 @@ def main(argv=None) -> None:
                     help="re-sweep even when the cache already holds an "
                          "entry for (op, shapes, dtype, backend, jax)")
     args = ap.parse_args(argv)
+    from repro import compile_cache
+    compile_cache.configure()
     if len(args.op) != len(args.shape):
         ap.error(f"got {len(args.op)} --op but {len(args.shape)} "
                  f"--shape; they pair up in order")

@@ -163,32 +163,14 @@ def apply_moe_scatter(params: dict, cfg: MoEConfig, x: jax.Array
 
 
 def _ambient_mesh():
-    """Active mesh: jax>=0.6 abstract context mesh, else the 0.4.x
-    thread-resources physical mesh installed by ``with mesh:``."""
-    get = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get is not None:
-        mesh = get()
-        if mesh is not None and mesh.shape:
-            return mesh
-    pxla = getattr(jax.interpreters, "pxla", None)
-    if pxla is not None and hasattr(pxla, "thread_resources"):
-        mesh = pxla.thread_resources.env.physical_mesh
-        if mesh.shape:
-            return mesh
-    return None
+    """The active (abstract) context mesh, or None outside one."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return mesh if mesh.shape else None
 
 
 def _shard_map(f, *, in_specs, out_specs):
-    """shard_map against the ambient mesh, on both jax 0.4.x and >=0.5."""
-    try:
-        from jax import shard_map
-        return shard_map(f, in_specs=in_specs, out_specs=out_specs)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        # check_rep=False: 0.4.x replication checking has no rules for the
-        # scatter ops used by the local dispatch/combine bodies.
-        return sm(f, mesh=_ambient_mesh(), in_specs=in_specs,
-                  out_specs=out_specs, check_rep=False)
+    """shard_map against the ambient mesh."""
+    return jax.shard_map(f, in_specs=in_specs, out_specs=out_specs)
 
 
 def _dp_size(dp_axes) -> int:

@@ -7,8 +7,9 @@ of *compiling*:
 
 * ``blobs/chunk_<token>.stablehlo`` -- the ``jax.export`` StableHLO
   blobs from the executable cache (skip Python tracing/lowering);
-* ``xla/`` -- the XLA persistent compilation cache (skip the backend
-  compile of the restored modules);
+* ``xla/`` -- the compiled programs the build looked up in the XLA
+  compilation cache (``repro.compile_cache``), copied into the booting
+  replica's cache (skip the backend compile of the restored modules);
 * ``plans/*.npz`` -- precomputed geometry: DISCO psi tensors with their
   memoized banded splits and the SHT Legendre tables (skip the host-side
   plan construction);
@@ -47,8 +48,10 @@ import shutil
 import tarfile
 import tempfile
 
+import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.serving.cache import (ExecutableKey, ReadOnlyCacheMiss,
                                  _code_fingerprint)
 from repro.serving.spec import RequestSpec
@@ -77,7 +80,6 @@ def environment() -> dict:
     """
     import platform
 
-    import jax
     import jaxlib
     return {
         "jax": jax.__version__,
@@ -86,26 +88,6 @@ def environment() -> dict:
         "source_fingerprint": _code_fingerprint(),
         "python": platform.python_version(),
     }
-
-
-def set_xla_cache_dir(path: str) -> None:
-    """Point JAX's persistent compilation cache at ``path``.
-
-    Resets any previously initialized cache instance so the change
-    takes effect mid-process (pack-then-boot in one process, tests).
-    """
-    import jax
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
-    try:
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-    except AttributeError:  # older jax: keep the default threshold
-        pass
-    try:
-        from jax.experimental.compilation_cache import compilation_cache
-        compilation_cache.reset_cache()
-    except Exception:  # noqa: BLE001 -- cache not initialized yet is fine
-        pass
 
 
 def _sha256_file(path: str) -> str:
@@ -170,15 +152,15 @@ def pack(specs: list[RequestSpec], out: str | None = None,
     Builds the model pool and compiles the serial chunk programs for
     every spec (plus the coalesced ``max_batch``-request programs when
     ``max_batch`` > 1) with persistence on, then packs the resulting
-    StableHLO blobs, the XLA compilation cache, the geometry plans and
-    the engine-pool manifest.  With ``out=None`` the bundle is written
+    StableHLO blobs, the compiled programs, the geometry plans and the
+    engine-pool manifest.  With ``out=None`` the bundle is written
     to ``<out_dir>/fcn3-bundle-<bundle_id[:12]>`` (content-addressed
     name); ``tar=True`` produces a single ``.tar`` archive instead of a
     directory.
 
-    Must run before anything else compiles in this process if the XLA
-    cache should land in the bundle (the CLI guarantees this; library
-    callers should call it early).
+    The bundle's ``xla/`` carries the compiled programs this build
+    looked up in the process's compilation cache (``repro.compile_cache``),
+    whether it compiled them or found them there.
     """
 
     def _log(msg: str) -> None:
@@ -196,7 +178,7 @@ def pack(specs: list[RequestSpec], out: str | None = None,
     staging = tempfile.mkdtemp(prefix=".fcn3-bundle-build-", dir=base)
     try:
         blobs_dir = os.path.join(staging, "blobs")
-        set_xla_cache_dir(os.path.join(staging, "xla"))
+        xla_dir = compile_cache.configure()
 
         from repro.serving.cache import ExecutableCache
         from repro.serving.scheduler import ForecastScheduler, ModelPool
@@ -206,38 +188,51 @@ def pack(specs: list[RequestSpec], out: str | None = None,
         engines: list[dict] = []
         plan_payloads: list[dict] = []
         plan_seen: set = set()
-        try:
-            for spec in specs:
-                spec.validate()
-                _log(f"warming {spec.to_dict()}")
-                batches = [None] + ([max_batch] if max_batch > 1 else [])
-                programs = []
-                for b in batches:
-                    out_warm = sched.warmup(spec, batch=b)
+        # every program the build needs goes through the persistent
+        # cache, where it is recorded, not through a jit cache in memory
+        jax.clear_caches()
+        with compile_cache.recording() as used:
+            try:
+                for spec in specs:
+                    spec.validate()
+                    _log(f"warming {spec.to_dict()}")
+                    batches = [None] + ([max_batch] if max_batch > 1 else [])
+                    programs = []
+                    for b in batches:
+                        out_warm = sched.warmup(spec, batch=b)
+                        engine, _ = sched.engine_for(spec)
+                        lens = engine.chunk_lengths(spec.lead_steps)
+                        tokens = [ExecutableKey.for_engine(
+                            spec.config, engine, spec.scored, k,
+                            batch=b).token() for k in lens]
+                        programs.append({
+                            "batch": b, "chunk_lengths": lens,
+                            "tokens": tokens,
+                            "compile_s": round(out_warm["compile_s"], 3)})
                     engine, _ = sched.engine_for(spec)
-                    lens = engine.chunk_lengths(spec.lead_steps)
-                    tokens = [ExecutableKey.for_engine(
-                        spec.config, engine, spec.scored, k,
-                        batch=b).token() for k in lens]
-                    programs.append({
-                        "batch": b, "chunk_lengths": lens,
-                        "tokens": tokens,
-                        "compile_s": round(out_warm["compile_s"], 3)})
-                engine, _ = sched.engine_for(spec)
-                engines.append({
-                    "spec": spec.to_dict(), "programs": programs,
-                    "estimated_bytes": engine.estimated_bytes()})
-                for payload in engine.plan_exports():
-                    pk = (payload["kind"],
-                          json.dumps(payload.get("key",
-                                                 [payload.get("lmax"),
-                                                  payload.get("mmax")])))
-                    if pk in plan_seen:
-                        continue
-                    plan_seen.add(pk)
-                    plan_payloads.append(payload)
-        finally:
-            sched.close()
+                    engines.append({
+                        "spec": spec.to_dict(), "programs": programs,
+                        "estimated_bytes": engine.estimated_bytes()})
+                    for payload in engine.plan_exports():
+                        pk = (payload["kind"],
+                              json.dumps(payload.get("key",
+                                                     [payload.get("lmax"),
+                                                      payload.get("mmax")])))
+                        if pk in plan_seen:
+                            continue
+                        plan_seen.add(pk)
+                        plan_payloads.append(payload)
+            finally:
+                sched.close()
+
+        # the compiled programs this build looked up: new entries and
+        # hits alike (a program the cache already held must ship too),
+        # and nothing else the shared cache holds
+        os.makedirs(os.path.join(staging, "xla"), exist_ok=True)
+        for name in compile_cache.entries(xla_dir):
+            if name.removesuffix("-cache") in used:
+                shutil.copyfile(os.path.join(xla_dir, name),
+                                os.path.join(staging, "xla", name))
 
         plans_dir = os.path.join(staging, "plans")
         os.makedirs(plans_dir, exist_ok=True)
@@ -326,7 +321,7 @@ def pack(specs: list[RequestSpec], out: str | None = None,
 class WarmStartBundle:
     """A loaded bundle: the manifest plus the on-disk root directory.
 
-    ``load`` -> ``verify`` -> ``install_plans`` + ``enable_xla_cache``
+    ``load`` -> ``verify`` -> ``install_plans`` + ``install_xla_cache``
     -> ``boot(scheduler)`` is the replica boot sequence
     (``boot_scheduler`` runs all of it).  Every step refuses with a
     ``BundleError`` naming the mismatched field rather than falling
@@ -346,10 +341,7 @@ class WarmStartBundle:
         if os.path.isfile(path):
             root = tempfile.mkdtemp(prefix="fcn3-bundle-")
             with tarfile.open(path) as tf:
-                try:
-                    tf.extractall(root, filter="data")
-                except TypeError:  # Python without the filter= parameter
-                    tf.extractall(root)
+                tf.extractall(root, filter="data")
         mpath = os.path.join(root, "manifest.json")
         if not os.path.exists(mpath):
             raise BundleError(f"{path!r} has no manifest.json -- not a "
@@ -455,11 +447,20 @@ class WarmStartBundle:
         autotune.install_tuning_cache(os.path.join(self.root, "tunings"))
         return len(packed)
 
-    def enable_xla_cache(self) -> None:
-        """Point JAX's persistent compilation cache at the bundle's
-        ``xla/`` directory, so importing the StableHLO blobs skips the
-        backend compile too."""
-        set_xla_cache_dir(os.path.join(self.root, "xla"))
+    def install_xla_cache(self) -> int:
+        """Copy the bundle's compiled programs into the process's XLA
+        compilation cache (``repro.compile_cache``), so importing the
+        StableHLO blobs skips the backend compile too.  Entries already
+        present are kept.  Returns the number copied."""
+        dest = compile_cache.configure()
+        src = os.path.join(self.root, "xla")
+        copied = 0
+        for name in compile_cache.entries(src):
+            target = os.path.join(dest, name)
+            if not os.path.exists(target):
+                shutil.copyfile(os.path.join(src, name), target)
+                copied += 1
+        return copied
 
     def boot(self, scheduler) -> dict:
         """Pre-warm ``scheduler`` with every engine in the manifest.
@@ -520,7 +521,7 @@ def boot_scheduler(bundle: "WarmStartBundle | str", pool=None,
     if isinstance(bundle, str):
         bundle = WarmStartBundle.load(bundle)
     bundle.verify()
-    bundle.enable_xla_cache()
+    bundle.install_xla_cache()
     bundle.install_plans()
     bundle.install_tunings()
     from repro.serving.cache import ExecutableCache

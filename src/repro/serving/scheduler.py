@@ -237,19 +237,28 @@ class ModelBundle:
     ds: dlib.SyntheticERA5
     buffers: dict
     params: dict
+    #: build wall seconds: ``plans_s`` (host geometry + device buffers)
+    #: and ``calibrate_s`` (initial state + params: restore or
+    #: calibrated init)
+    build_s: dict = dataclasses.field(default_factory=dict)
 
 
 def build_bundle(name: str, ckpt: str | None = None) -> ModelBundle:
     """Deterministic bundle construction (calibrated on sample 0), so a
     direct ``ForecastEngine`` built from the same config reproduces
     served results bit-for-bit."""
+    t0 = time.perf_counter()
     cfg = fcn3cfg.NAMED_CONFIGS[name]()
     model = FCN3(cfg)
     ds = dlib.SyntheticERA5(cfg)
-    buffers = model.make_buffers()
-    params = load_params(model, ds, buffers, ds.state(0, 0), ckpt)
+    buffers = jax.block_until_ready(model.make_buffers())
+    t1 = time.perf_counter()
+    params = jax.block_until_ready(
+        load_params(model, ds, buffers, ds.state(0, 0), ckpt))
     return ModelBundle(name=name, model=model, ds=ds, buffers=buffers,
-                       params=params)
+                       params=params,
+                       build_s={"plans_s": t1 - t0,
+                                "calibrate_s": time.perf_counter() - t1})
 
 
 class ModelPool:
@@ -826,9 +835,7 @@ class ForecastScheduler:
                     "lead_chunk": key[1].lead_chunk,
                     "precision": key[1].compute_dtype,
                     "perturb": key[1].perturb.kind,
-                    "kernels": (key[1].kernels.effective()
-                                if key[1].kernels is not None
-                                else "inherit"),
+                    "kernels": eng.model.cfg.kernels.effective(),
                     "estimated_bytes": sizes[key],
                     "dispatch": eng.dispatch_stats()}
                    for key, eng in snap.items()]

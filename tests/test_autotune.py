@@ -35,7 +35,8 @@ import pytest
 from _hypothesis_compat import given, settings, st
 from repro.kernels import autotune
 from repro.kernels.autotune import TuningCache
-from repro.kernels.config import BLOCK_DEFAULTS, BlockConfig, KernelConfig
+from repro.kernels.config import (BLOCK_DEFAULTS, TILE_MULTIPLES, BlockConfig,
+                                  KernelConfig, legal_tile)
 
 
 def fake_timer(us_for):
@@ -56,7 +57,7 @@ def no_leaked_cache():
 class TestCandidates:
     @pytest.mark.parametrize("op,shapes", [
         ("legendre", (16, 32, 17, 17)),
-        ("disco", (8, 32, 5, 128, 3, 9, 2)),
+        ("disco", (2, 200, 64, 32, 5, 128, 3, 9, 16, 2)),
         ("crps", (4, 4096)),
         ("ssd", (6, 16, 2, 8, 1, 4)),
     ])
@@ -70,8 +71,34 @@ class TestCandidates:
         for dims in cands[1:]:
             assert autotune.feasible(op, dims, shapes)
 
+    @pytest.mark.parametrize("op", sorted(BLOCK_DEFAULTS))
+    def test_defaults_obey_minor_dims_rule(self, op):
+        # Mosaic refuses a block whose sublane dim is not a multiple of
+        # 8 or whose lane dim is not a multiple of 128
+        assert legal_tile(op, BLOCK_DEFAULTS[op])
+        for name, mult in TILE_MULTIPLES[op].items():
+            assert mult in (8, 128)
+            assert BLOCK_DEFAULTS[op][name] % mult == 0
+
+    @settings(max_examples=25, deadline=None)
+    @given(op=st.sampled_from(["legendre", "disco", "crps"]),
+           a=st.integers(1, 800), b=st.integers(1, 800),
+           c=st.integers(1, 800), stride=st.sampled_from([1, 2]))
+    def test_every_candidate_obeys_minor_dims_rule(self, op, a, b, c,
+                                                   stride):
+        shapes = {"legendre": (a, b, c, 1 + a % 17),
+                  "disco": (2, a, 2 * b, b, 1 + c % 13, 4 * c, 7,
+                            1 + a % 31, 1 + c, stride),
+                  "crps": (2 + a % 6, b * c)}[op]
+        cands = autotune.candidates(op, shapes, max_candidates=None)
+        assert cands
+        for dims in cands:
+            assert legal_tile(op, dims), (op, shapes, dims)
+            for name, mult in TILE_MULTIPLES[op].items():
+                assert dims[name] % mult == 0
+
     def test_max_candidates_caps(self):
-        shapes = (16, 32, 17, 17)
+        shapes = (16, 200, 200, 17)
         assert len(autotune.candidates("legendre", shapes,
                                        max_candidates=4)) == 4
         unlimited = autotune.candidates("legendre", shapes,
@@ -291,7 +318,7 @@ class TestPaddingExactness:
         from repro.kernels.legendre.legendre import legendre_contract
         rng = np.random.default_rng(b * 100 + k * 10 + n + m)
         x = jnp.asarray(rng.normal(size=(b, k, m)), jnp.float32)
-        t = jnp.asarray(rng.normal(size=(k, n, m)), jnp.float32)
+        t = jnp.asarray(rng.normal(size=(m, k, n)), jnp.float32)
         bc = BlockConfig.make("legendre", b_blk=b_blk, k_blk=k_blk,
                               n_blk=n_blk, m_blk=m_blk)
         got = legendre_contract(x, t, interpret=True, blocks=bc)
@@ -300,17 +327,19 @@ class TestPaddingExactness:
                                    rtol=2e-5, atol=2e-5)
 
     @settings(max_examples=4, deadline=None)
-    @given(b=st.integers(1, 5), h=st.integers(2, 7),
-           b_blk=st.sampled_from([2, 4]), h_blk=st.sampled_from([2, 4]))
-    def test_disco_any_tile(self, b, h, b_blk, h_blk):
+    @given(r=st.integers(1, 20), h=st.integers(2, 7),
+           c_blk=st.sampled_from([8, 16]), w_blk=st.sampled_from([8, 128]))
+    def test_disco_any_tile(self, r, h, c_blk, w_blk):
         from repro.kernels.disco.disco import disco_band_contract
-        rng = np.random.default_rng(b * 10 + h)
-        x = jnp.asarray(rng.normal(size=(b, h, 3, 16)), jnp.float32)
+        rng = np.random.default_rng(r * 10 + h)
+        x = jnp.asarray(rng.normal(size=(1, r, h + 2, 32)), jnp.float32)
         psi = jnp.asarray(rng.normal(size=(2, h, 3, 5)), jnp.float32)
-        bc = BlockConfig.make("disco", b_blk=b_blk, h_blk=h_blk)
-        got = disco_band_contract(x, psi, stride=2, interpret=True,
-                                  blocks=bc)
-        want = disco_band_contract(x, psi, stride=2, interpret=True)
+        mix = jnp.asarray(rng.normal(size=(2, 3, r)), jnp.float32)
+        bc = BlockConfig.make("disco", c_blk=c_blk, w_blk=w_blk)
+        got = disco_band_contract(x, psi, mix, stride=2, off0=-2,
+                                  interpret=True, blocks=bc)
+        want = disco_band_contract(x, psi, mix, stride=2, off0=-2,
+                                   interpret=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=2e-5, atol=2e-5)
 

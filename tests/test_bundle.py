@@ -25,6 +25,7 @@ import jax
 import numpy as np
 import pytest
 
+from repro import compile_cache
 from repro.inference import ForecastEngine
 from repro.serving import transport
 from repro.serving.bundle import (BundleError, WarmStartBundle, _canonical,
@@ -34,6 +35,16 @@ from repro.serving.scheduler import ModelPool, RequestSpec
 
 SPEC = RequestSpec(config="smoke", members=2, lead_steps=2, lead_chunk=2,
                    scored=True, return_state=True)
+
+
+@pytest.fixture(scope="module", autouse=True)
+def xla_cache_dir(tmp_path_factory):
+    """This module's compilation cache, placed from outside the program
+    the way a deployment places it: ``JAX_COMPILATION_CACHE_DIR``."""
+    path = str(tmp_path_factory.mktemp("xla-cache"))
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv(compile_cache.ENV_VAR, path)
+        yield path
 
 
 @pytest.fixture(scope="module")
@@ -337,3 +348,66 @@ class TestBundleTunings:
                 sched.close()
         finally:
             autotune.install_tuning_cache(prev)
+
+
+class TestCompileCachePlacement:
+    """One compilation cache per process, placed from outside: with
+    ``JAX_COMPILATION_CACHE_DIR`` set nothing points JAX anywhere else
+    (not the bundle build, not the replica boot, not the launcher);
+    without it the cache sits at one fixed path in the checkout."""
+
+    def test_pack_and_boot_keep_the_env_dir(self, bundle_dir, booted,
+                                            xla_cache_dir):
+        assert jax.config.jax_compilation_cache_dir == xla_cache_dir
+        # the bundle carries the programs the build compiled, and boot
+        # copied them into the one cache directory
+        packed = compile_cache.entries(os.path.join(bundle_dir, "xla"))
+        assert packed
+        assert set(packed) <= set(compile_cache.entries(xla_cache_dir))
+
+    def test_pack_carries_only_the_programs_it_used(self, bundle_dir,
+                                                    xla_cache_dir,
+                                                    tmp_path):
+        # an unrelated program in the shared cache (another shape, an
+        # earlier build) must neither ship nor change the packed content
+        stray = os.path.join(xla_cache_dir, "jit_unrelated-0123abcd-cache")
+        with open(stray, "wb") as f:
+            f.write(b"not this bundle's program")
+        try:
+            again = pack([SPEC], out=str(tmp_path / "again"))
+        finally:
+            os.remove(stray)
+        first = WarmStartBundle.load(bundle_dir).manifest["files"]
+        second = WarmStartBundle.load(again).manifest["files"]
+        xla = {k: v for k, v in second.items() if k.startswith("xla/")}
+        assert xla, "the rebuild packed no compiled program"
+        assert "xla/" + os.path.basename(stray) not in second
+        # the same programs, byte for byte (the rebuild may skip a few
+        # eager conversions whose inputs this process already cached)
+        assert all(first.get(k) == v for k, v in xla.items())
+
+    def test_build_service_keeps_the_env_dir(self, xla_cache_dir):
+        from repro.launch import service as launcher
+        args = launcher.build_parser().parse_args(
+            ["--config", "smoke", "--port", "0", "--log-level", "WARNING"])
+        service, startup = launcher.build_service(args)
+        try:
+            assert jax.config.jax_compilation_cache_dir == xla_cache_dir
+            assert set(startup["preload"]["smoke"]) == {"plans_s",
+                                                        "calibrate_s"}
+        finally:
+            service.close()
+
+    def test_default_is_one_fixed_path_in_the_checkout(self, monkeypatch,
+                                                       xla_cache_dir):
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        monkeypatch.delenv(compile_cache.ENV_VAR)
+        try:
+            assert compile_cache.cache_dir() == os.path.join(repo,
+                                                             ".jax_cache")
+            assert compile_cache.configure() == compile_cache.DEFAULT_DIR
+            assert jax.config.jax_compilation_cache_dir \
+                == compile_cache.DEFAULT_DIR
+        finally:
+            monkeypatch.undo()
+            assert compile_cache.configure() == xla_cache_dir

@@ -104,6 +104,23 @@ class TestDonation:
         first, second = run(), run()
         np.testing.assert_array_equal(first, second)
 
+    def test_sequential_members_match_vmapped(self, setup, monkeypatch):
+        # Full-width models step members one after another (memory);
+        # forcing that path at smoke size must reproduce the vmapped
+        # step up to float reassociation.
+        from repro.inference import engine as englib
+        cfg, model, ds, buffers, params, state0 = setup
+        outs = []
+        for threshold in (englib.SEQUENTIAL_MEMBER_BYTES, 0):
+            monkeypatch.setattr(englib, "SEQUENTIAL_MEMBER_BYTES", threshold)
+            eng = ForecastEngine(model, EngineConfig(members=MEMBERS,
+                                                     lead_chunk=2))
+            assert eng._members_in_sequence == (threshold == 0)
+            outs.append(np.asarray(eng.forecast(
+                params, buffers, state0, _aux_fn(ds), KEY,
+                steps=STEPS).final_state))
+        np.testing.assert_allclose(outs[1], outs[0], rtol=1e-5, atol=1e-5)
+
     def test_donation_off_matches_on(self, setup):
         cfg, model, ds, buffers, params, state0 = setup
         outs = []

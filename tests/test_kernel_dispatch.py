@@ -58,6 +58,20 @@ class TestKernelConfig:
         assert KernelConfig(sht="pallas",
                             interpret=True).resolve("sht") == ("pallas", True)
 
+    def test_compiled_backend_never_interprets(self, monkeypatch):
+        # steer resolution to a compiled backend (TPU) from the test: a
+        # "pallas" op there always compiles, and asking for the
+        # interpreter is an error, not a slow path
+        from repro.kernels import config as kconfig
+        monkeypatch.setattr(kconfig, "compiled_backend", lambda: True)
+        assert KernelConfig().resolve("disco") == ("pallas", False)
+        assert KernelConfig(sht="pallas").resolve("sht") == ("pallas", False)
+        assert KernelConfig(sht="reference").resolve("sht")[0] == "reference"
+        with pytest.raises(ValueError, match="interpret"):
+            KernelConfig(sht="pallas", interpret=True).resolve("sht")
+        assert KernelConfig().effective() == {"sht": "pallas",
+                                              "disco": "pallas"}
+
     def test_reference_mode_wins_everywhere(self):
         kc = KernelConfig(sht="reference", disco="reference", interpret=True)
         assert kc.resolve("sht")[0] == "reference"
@@ -139,6 +153,10 @@ class TestSplitPsiBand:
         assert exact == (not outside_mass)
 
 
+def _conv_params(key, c_in, c_out, n_basis, groups):
+    return dlib.init_disco_conv(key, c_out, c_in, n_basis, groups=groups)
+
+
 class TestDiscoDispatchParity:
     @pytest.mark.parametrize("gi,go", [
         ((64, 128, "equiangular"), (32, 64, "gauss")),   # encoder (stride 2)
@@ -148,22 +166,27 @@ class TestDiscoDispatchParity:
     def test_banded_buffers_match_fft_path(self, gi, go):
         plan = dlib.make_disco_plan(grids.make_grid(*gi),
                                     grids.make_grid(*go))
-        x = jax.random.normal(jax.random.PRNGKey(0), (2, gi[0], gi[1]))
-        ref = dlib.disco_conv(x, jnp.asarray(plan.psi),
-                              jnp.asarray(plan.lat_idx), plan.stride,
-                              plan.affine)
-        got = kdispatch.disco_conv_banded_buffers(
-            x, plan.banded_buffers(), plan.stride, plan.affine, PALLAS)
+        # (members, levels, channels): the levels fold into kernel rows
+        x = jax.random.normal(jax.random.PRNGKey(0), (2, 3, 4, gi[0], gi[1]))
+        params = _conv_params(jax.random.PRNGKey(1), 4, 6, plan.n_basis, 2)
+        ref = dlib.apply_disco_conv(params, x, plan.buffers(), plan.stride,
+                                    groups=2, affine=plan.affine)
+        got = dlib.apply_disco_conv(params, x, plan.banded_buffers(),
+                                    plan.stride, groups=2,
+                                    affine=plan.affine, kernels=PALLAS)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=1e-5)
 
     def test_dispatch_follows_buffer_layout(self):
         g = grids.make_grid(16, 32, "gauss")
         plan = dlib.make_disco_plan(g, g)
-        x = jax.random.normal(jax.random.PRNGKey(1), (1, 16, 32))
-        a = kdispatch.disco_conv(x, plan.buffers(), plan.stride, plan.affine)
-        b = kdispatch.disco_conv(x, plan.banded_buffers(), plan.stride,
-                                 plan.affine, PALLAS)
+        x = jax.random.normal(jax.random.PRNGKey(1), (1, 5, 16, 32))
+        params = _conv_params(jax.random.PRNGKey(2), 5, 3, plan.n_basis, 1)
+        a = dlib.apply_disco_conv(params, x, plan.buffers(), plan.stride,
+                                  affine=plan.affine)
+        b = dlib.apply_disco_conv(params, x, plan.banded_buffers(),
+                                  plan.stride, affine=plan.affine,
+                                  kernels=PALLAS)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 

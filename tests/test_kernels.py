@@ -35,7 +35,7 @@ class TestLegendreKernel:
         b, k, n, m = shape
         rng = np.random.default_rng(hash(shape) % 2**31)
         x = jnp.asarray(rng.normal(size=(b, k, m)), jnp.float32)
-        t = jnp.asarray(rng.normal(size=(k, n, m)), jnp.float32)
+        t = jnp.asarray(rng.normal(size=(m, k, n)), jnp.float32)
         got = legendre_contract(x, t)
         ref = legendre_contract_ref(x, t)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
@@ -45,7 +45,7 @@ class TestLegendreKernel:
     def test_dtypes(self, dtype):
         rng = np.random.default_rng(0)
         x = jnp.asarray(rng.normal(size=(4, 40, 6)), dtype)
-        t = jnp.asarray(rng.normal(size=(40, 30, 6)), dtype)
+        t = jnp.asarray(rng.normal(size=(6, 40, 30)), dtype)
         got = legendre_contract(x, t)
         ref = legendre_contract_ref(x, t)
         assert got.dtype == jnp.float32  # fp32 accumulation
@@ -59,7 +59,7 @@ class TestLegendreKernel:
     def test_property_sweep(self, b, k, n, m, seed):
         rng = np.random.default_rng(seed)
         x = jnp.asarray(rng.normal(size=(b, k, m)), jnp.float32)
-        t = jnp.asarray(rng.normal(size=(k, n, m)), jnp.float32)
+        t = jnp.asarray(rng.normal(size=(m, k, n)), jnp.float32)
         np.testing.assert_allclose(np.asarray(legendre_contract(x, t)),
                                    np.asarray(legendre_contract_ref(x, t)),
                                    atol=1e-3, rtol=1e-4)
@@ -79,38 +79,48 @@ class TestLegendreKernel:
             np.asarray(t.inverse(c)), atol=1e-4)
 
 
+def _disco_case(rng, n, r, h_in, w, k, h_out, s, d, q):
+    x = jnp.asarray(rng.normal(size=(n, r, h_in, w)), jnp.float32)
+    psi = jnp.asarray(rng.normal(size=(k, h_out, s, d)), jnp.float32)
+    mix = jnp.asarray(rng.normal(size=(k, q, r)), jnp.float32)
+    return x, psi, mix
+
+
 class TestDiscoKernel:
     @pytest.mark.parametrize("shape", [
-        # (B, H, S, W, K, D, stride)
-        (2, 8, 3, 32, 5, 7, 1),
-        (3, 10, 4, 64, 7, 11, 2),
-        (1, 5, 2, 16, 2, 4, 1),
-        (9, 17, 5, 128, 7, 21, 2),
-        (2, 12, 1, 64, 3, 64, 1),   # full-circle band (D == W)
+        # (N, R, H_in, W, K, H_out, S, D, Q, stride, affine, off0)
+        (2, 3, 10, 32, 5, 8, 3, 7, 4, 1, (1, -1), -3),
+        (3, 4, 20, 64, 7, 10, 4, 11, 6, 2, (2, -1), -5),
+        (1, 2, 5, 16, 2, 5, 2, 4, 3, 1, (1, 0), 0),
+        (2, 9, 34, 128, 7, 17, 5, 21, 5, 2, (2, -2), -10),
+        (2, 1, 12, 64, 3, 12, 1, 64, 2, 1, (1, 0), 0),  # D == W
+        (1, 140, 6, 40, 2, 6, 2, 5, 3, 1, (1, 0), -2),  # two channel tiles
     ])
     def test_matches_oracle(self, shape):
-        b, h, s, w, k, d, stride = shape
+        n, r, h_in, w, k, h_out, s, d, q, stride, affine, off0 = shape
         rng = np.random.default_rng(hash(shape) % 2**31)
-        x = jnp.asarray(rng.normal(size=(b, h, s, w)), jnp.float32)
-        psi = jnp.asarray(rng.normal(size=(k, h, s, d)), jnp.float32)
-        got = disco_band_contract(x, psi, stride=stride)
-        ref = disco_band_contract_ref(x, psi, stride=stride)
+        x, psi, mix = _disco_case(rng, n, r, h_in, w, k, h_out, s, d, q)
+        got = disco_band_contract(x, psi, mix, stride=stride, affine=affine,
+                                  off0=off0)
+        ref = disco_band_contract_ref(x, psi, mix, stride=stride,
+                                      affine=affine, off0=off0)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
-                                   atol=1e-4 * np.sqrt(s * d), rtol=1e-4)
+                                   atol=1e-4 * np.sqrt(s * d * k * r),
+                                   rtol=1e-4)
 
     @settings(max_examples=10, deadline=None)
-    @given(b=st.integers(1, 5), h=st.integers(1, 12), s=st.integers(1, 4),
+    @given(n=st.integers(1, 3), h=st.integers(1, 12), s=st.integers(1, 4),
            wp=st.integers(3, 6), k=st.integers(1, 4),
            seed=st.integers(0, 2**31 - 1))
-    def test_property_sweep(self, b, h, s, wp, k, seed):
+    def test_property_sweep(self, n, h, s, wp, k, seed):
         w = 2 ** wp
         rng = np.random.default_rng(seed)
         d = int(rng.integers(1, w))
-        x = jnp.asarray(rng.normal(size=(b, h, s, w)), jnp.float32)
-        psi = jnp.asarray(rng.normal(size=(k, h, s, d)), jnp.float32)
+        r, q = int(rng.integers(1, 4)), int(rng.integers(1, 4))
+        x, psi, mix = _disco_case(rng, n, r, h + s, w, k, h, s, d, q)
         np.testing.assert_allclose(
-            np.asarray(disco_band_contract(x, psi)),
-            np.asarray(disco_band_contract_ref(x, psi)),
+            np.asarray(disco_band_contract(x, psi, mix)),
+            np.asarray(disco_band_contract_ref(x, psi, mix)),
             atol=1e-3, rtol=1e-4)
 
     def test_banded_equals_fft_path_on_real_plan(self):
@@ -124,9 +134,12 @@ class TestDiscoKernel:
         x = jax.random.normal(jax.random.PRNGKey(0), (2, 64, 128))
         fft_out = dlib.disco_conv(x, jnp.asarray(plan.psi),
                                   jnp.asarray(plan.lat_idx), plan.stride)
-        band_out = disco_ops.disco_conv_banded(
-            x, jnp.asarray(band), jnp.asarray(plan.lat_idx), off0,
-            plan.stride)
+        # identity mix: the kernel's output rows are the K basis responses
+        k = band.shape[0]
+        mix = jnp.eye(k, dtype=jnp.float32)[:, :, None]
+        band_out = disco_band_contract(
+            x[:, None], jnp.asarray(band), mix, stride=plan.stride,
+            affine=plan.affine, off0=off0)
         np.testing.assert_allclose(np.asarray(band_out), np.asarray(fft_out),
                                    atol=1e-5)
 

@@ -90,17 +90,10 @@ def test_moe_scatter_matches_dense_subprocess():
     code = """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import contextlib
 import jax, jax.numpy as jnp, numpy as np, dataclasses
 from repro.models import moe as moelib
 mesh = jax.make_mesh((4, 2), ("data", "model"))
-# jax >= 0.6 installs a context mesh; 0.4.x uses the Mesh context manager.
-if hasattr(jax, "set_mesh"):
-    jax.set_mesh(mesh)
-    ctx = contextlib.nullcontext()
-else:
-    ctx = mesh
-ctx.__enter__()
+jax.set_mesh(mesh)
 cfg_d = moelib.MoEConfig(d_model=32, d_ff=16, n_experts=8, top_k=2,
                          n_shared=1, capacity_factor=2.0)
 cfg_s = dataclasses.replace(cfg_d, dispatch="scatter", dp_axes=("data",))

@@ -170,6 +170,31 @@ class TestDisco:
                                    atol=1e-6)
         assert float(jnp.abs(y0[:, 1] - y1[:, 1]).max()) > 1e-3
 
+    @pytest.mark.parametrize("lead,c_in,c_out,groups", [
+        ((), 5, 3, 1),        # input-channel chunks of 2: one padded
+        ((), 4, 4, 2),        # grouped: whole contraction per call
+        ((2, 2), 5, 3, 1),    # leading dims mapped, then chunked
+        ((2,), 4, 6, 2),      # leading dims mapped, grouped
+    ])
+    def test_memory_bounded_reference_matches_whole(self, monkeypatch, lead,
+                                                    c_in, c_out, groups):
+        # the pieces the reference contracts in above _REF_BAND_ELEMS
+        # (full width) must sum to the one-shot contraction
+        key = jax.random.PRNGKey(2)
+        w = disco.init_disco_conv(key, c_out, c_in, self.plan.n_basis,
+                                  groups=groups, bias=False)["weight"]
+        x = jax.random.normal(key, lead + (c_in, 64, 128))
+        buf = self.plan.buffers()
+        args = (w, x, buf["psi"], buf["lat_idx"], self.plan.stride, groups,
+                self.plan.affine)
+        whole = disco._reference_conv(*args)
+        per_channel = 128 * self.plan.psi.shape[1] * self.plan.psi.shape[2]
+        monkeypatch.setattr(disco, "_REF_BAND_ELEMS", 2 * per_channel)
+        pieces = disco._reference_conv(*args)
+        assert pieces.shape == whole.shape == lead + (c_out, 32, 64)
+        np.testing.assert_allclose(np.asarray(pieces), np.asarray(whole),
+                                   rtol=1e-5, atol=1e-5)
+
 
 # ---------------------------------------------------------------------------
 # Interpolation (B.6)

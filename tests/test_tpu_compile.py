@@ -1,0 +1,119 @@
+"""Compile rehearsals of the main-path Pallas kernels for a TPU v5e.
+
+Each test lowers and compiles one kernel at the shapes the full-width
+model (``fcn3_full``: 721x1440 grid, 360x720 latent, 13 levels) feeds it,
+for a chip that is described, not attached: the installed TPU compiler
+refuses here what the chip would refuse (a tile off Mosaic's (8, 128)
+grid, a strided lane gather, an unsupported shape cast, more VMEM than a
+kernel instance has).  A compile that passes says nothing about results
+or times -- the interpret-mode parity suites and ``chip_smoke.py`` do.
+
+The topology is described inside a module fixture, never at import, so
+every pytest-xdist worker collects the same tests and only the worker
+that runs this file loads the TPU compiler library.
+"""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.crps.crps import crps_fused
+from repro.kernels.disco.disco import disco_band_contract
+from repro.kernels.dispatch import sht_forward_pallas, sht_inverse_pallas
+
+#: full-width plans: (x, psi_band, mix, stride, affine).  x is one
+#: member's kernel input as FCN3 builds it (encoder: 13 levels x 5
+#: variables folded into the rows; latent: 641 latent + 36 conditioning
+#: channels; decoder: one pressure level's 45 channels per call).
+DISCO_PLANS = {
+    "encoder": ((1, 65, 721, 1440), (7, 360, 13, 423), (7, 585, 65), 2,
+                (2, -5)),
+    "latent": ((1, 677, 360, 720), (7, 360, 7, 209), (7, 641, 677), 1,
+               (1, -3)),
+    "decoder": ((1, 45, 721, 1440), (7, 721, 5, 641), (7, 5, 45), 1,
+                (1, -2)),
+}
+
+
+@pytest.fixture(scope="module")
+def topo():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure means "cannot"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A program compiled for a described chip is written to the
+    persistent cache but cannot be read back without one: keep these
+    compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, sharding, *shapes, dtypes=None):
+    dtypes = dtypes or (jnp.float32,) * len(shapes)
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=sharding)
+            for s, dt in zip(shapes, dtypes)]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+@pytest.fixture
+def chip_dft(monkeypatch):
+    """The longitude transform a TPU backend picks (DFT as GEMMs)."""
+    from repro.core.sphere import fourier
+    monkeypatch.setattr(fourier, "_MODE", "matmul")
+
+
+#: the latent global block's two SHTs, through the Pallas wrappers: 641
+#: channels on the 360x720 grid against order-major 360^3 tables --
+#: forward (M, H, L), inverse (M, L, H) from (L, M) coefficients
+LEGENDRE_CASES = {
+    "forward": (lambda x, t: sht_forward_pallas(x, t, interpret=False),
+                (1, 641, 360, 720), jnp.float32),
+    "inverse": (lambda c, t: sht_inverse_pallas(c, t, 720, interpret=False),
+                (1, 641, 360, 360), jnp.complex64),
+}
+
+
+@pytest.mark.parametrize("direction", sorted(LEGENDRE_CASES))
+def test_legendre_latent_slab(one_chip, chip_dft, direction):
+    fn, x, dtype = LEGENDRE_CASES[direction]
+    _compile(fn, one_chip, x, (360, 360, 360), dtypes=(dtype, jnp.float32))
+
+
+@pytest.mark.parametrize("plan", sorted(DISCO_PLANS))
+def test_disco_band_full_width_plan(one_chip, plan):
+    x, psi, mix, stride, affine = DISCO_PLANS[plan]
+    d = psi[-1]
+    compiled = _compile(
+        lambda a, p, m: disco_band_contract(
+            a, p, m, stride=stride, affine=affine, off0=-(d // 2),
+            interpret=False),
+        one_chip, x, psi, mix)
+    out = compiled.out_info
+    assert out.shape == (x[0], mix[1], psi[1], x[-1] // stride)
+
+
+def test_crps_fused_full_state(one_chip):
+    n = 72 * 721 * 1440
+    _compile(lambda e, o: crps_fused(e, o, fair=True, interpret=False),
+             one_chip, (2, n), (n,))
