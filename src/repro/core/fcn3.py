@@ -328,12 +328,14 @@ class FCN3:
         return jax.jit(self.apply)
 
     def apply(self, params: dict, buffers: dict, state: jax.Array,
-              cond_in: jax.Array) -> jax.Array:
+              cond_in: jax.Array, *, clamp_water: bool = True) -> jax.Array:
         """One 6-hour step.
 
         state: (..., 72, H, W) normalized prognostic state u_n.
         cond_in: (..., n_aux + n_noise, H, W) auxiliary + noise fields.
         Returns u_{n+1}, same shape as ``state`` (direct prediction, C.7).
+        With ``clamp_water=False`` the decoder output is returned before
+        the output transformation (C.8) softclamps the water channels.
         """
         cfg = self.cfg
         x, cond = self._encode(params, buffers, state, cond_in)
@@ -349,6 +351,8 @@ class FCN3:
                                   kernels=cfg.kernels))
             x = jax.checkpoint(fn)(p, x, cond, buf)
         out = self._decode(params, buffers, x)
+        if not clamp_water:
+            return out
         # Output transformation (C.8): softclamp water channels.
         water = self.cfg.water_channel_indices()
         mask = np.zeros((cfg.n_state,), bool)

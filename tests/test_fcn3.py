@@ -46,6 +46,20 @@ class TestFCN3Forward:
         other = [c for c in range(cfg.n_state) if c not in set(w.tolist())]
         assert float(out[:, other].min()) < 0.0  # others untouched
 
+    def test_clamp_water_off_returns_the_decoder_output(self, tiny):
+        # apply = softclamp on the water channels of the unclamped output
+        cfg, model, params, buffers = tiny
+        state, cond = _inputs(cfg, model)
+        out = model.apply(params, buffers, state, cond)
+        raw = model.apply(params, buffers, state, cond, clamp_water=False)
+        w = cfg.water_channel_indices()
+        assert float(raw[:, w].min()) < 0.0
+        np.testing.assert_array_equal(np.asarray(out[:, w]),
+                                      np.asarray(blk.softclamp(raw[:, w])))
+        other = [c for c in range(cfg.n_state) if c not in set(w.tolist())]
+        np.testing.assert_array_equal(np.asarray(out[:, other]),
+                                      np.asarray(raw[:, other]))
+
     def test_noise_changes_prediction(self, tiny):
         # Hidden Markov model: different latent noise -> different member.
         cfg, model, params, buffers = tiny
