@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.sphere import disco as discolib
 from repro.core.sphere import spectral_conv as speclib
 from repro.kernels.config import KernelConfig
@@ -89,18 +90,23 @@ def apply_block(params: dict, spec: BlockSpec, x: jax.Array, cond: jax.Array,
     blocks.  ``kernels`` routes the hot contraction through the Pallas
     substrate (``repro.kernels.dispatch``).
     """
-    cond = jnp.broadcast_to(cond, x.shape[:-3] + cond.shape[-3:])
-    h = jnp.concatenate([x, cond], axis=-3)
-    if spec.kind == "local":
-        h = discolib.apply_disco_conv(params["conv"], h, buffers, stride=1,
-                                      groups=1, affine=affine,
-                                      kernels=kernels)
-    else:
-        h = speclib.apply_spectral_conv(params["conv"], h, buffers,
-                                        nlon=x.shape[-1], kernels=kernels)
-    h = jax.nn.gelu(h)
-    h = apply_mlp(params["mlp"], h)
-    return x + params["layer_scale"][:, None, None] * h
+    local = spec.kind == "local"
+    with jax.named_scope(telemetry.SCOPE_LOCAL_CONV if local
+                         else telemetry.SCOPE_SPECTRAL_CONV):
+        cond = jnp.broadcast_to(cond, x.shape[:-3] + cond.shape[-3:])
+        h = jnp.concatenate([x, cond], axis=-3)
+        if local:
+            h = discolib.apply_disco_conv(params["conv"], h, buffers,
+                                          stride=1, groups=1, affine=affine,
+                                          kernels=kernels)
+        else:
+            h = speclib.apply_spectral_conv(params["conv"], h, buffers,
+                                            nlon=x.shape[-1],
+                                            kernels=kernels)
+    with jax.named_scope(telemetry.SCOPE_MLP):
+        h = jax.nn.gelu(h)
+        h = apply_mlp(params["mlp"], h)
+        return x + params["layer_scale"][:, None, None] * h
 
 
 def softclamp(u: jax.Array) -> jax.Array:

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core import blocks as blk
 from repro.core.sphere import disco as discolib
 from repro.core.sphere import grids as glib
@@ -336,9 +337,12 @@ class FCN3:
         Returns u_{n+1}, same shape as ``state`` (direct prediction, C.7).
         With ``clamp_water=False`` the decoder output is returned before
         the output transformation (C.8) softclamps the water channels.
+        Encoders, blocks and decoder run under the named scopes of
+        ``repro.telemetry.SCOPES`` (metadata only: the ops are the same).
         """
         cfg = self.cfg
-        x, cond = self._encode(params, buffers, state, cond_in)
+        with jax.named_scope(telemetry.SCOPE_ENCODER):
+            x, cond = self._encode(params, buffers, state, cond_in)
         for p, spec in zip(params["blocks"], cfg.block_specs()):
             buf = (buffers["latent"] if spec.kind == "local"
                    else buffers["latent_sht"])
@@ -350,15 +354,16 @@ class FCN3:
                   blk.apply_block(pp, _spec, xx, cc, bb, affine=_aff,
                                   kernels=cfg.kernels))
             x = jax.checkpoint(fn)(p, x, cond, buf)
-        out = self._decode(params, buffers, x)
-        if not clamp_water:
-            return out
-        # Output transformation (C.8): softclamp water channels.
-        water = self.cfg.water_channel_indices()
-        mask = np.zeros((cfg.n_state,), bool)
-        mask[water] = True
-        maskj = jnp.asarray(mask)[:, None, None]
-        return jnp.where(maskj, blk.softclamp(out), out)
+        with jax.named_scope(telemetry.SCOPE_DECODER):
+            out = self._decode(params, buffers, x)
+            if not clamp_water:
+                return out
+            # Output transformation (C.8): softclamp water channels.
+            water = self.cfg.water_channel_indices()
+            mask = np.zeros((cfg.n_state,), bool)
+            mask[water] = True
+            maskj = jnp.asarray(mask)[:, None, None]
+            return jnp.where(maskj, blk.softclamp(out), out)
 
     # ------------------------------------------------------------------
     def sample_noise(self, key: jax.Array, batch_shape: tuple[int, ...],

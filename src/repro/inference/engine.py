@@ -72,9 +72,9 @@ Design points:
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import threading
-import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Any, Callable, Iterator
 
@@ -82,6 +82,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import telemetry
 from repro.core.fcn3 import FCN3
 from repro.core.sphere import noise as noiselib
 from repro.evaluation import metrics
@@ -223,6 +224,11 @@ def _concat_results(parts: list[ForecastResult]) -> ForecastResult:
         scores=scores, diagnostics=diag,
         final_state=parts[-1].final_state,
         final_noise=parts[-1].final_noise)
+
+
+def _no_span(name: str, args: dict) -> contextlib.AbstractContextManager:
+    """The ``on_span`` hook where none is given: brackets nothing."""
+    return contextlib.nullcontext()
 
 
 def _cast_floats(tree, dtype):
@@ -541,7 +547,10 @@ class ForecastEngine:
 
     def _run_chunk(self, scored, params, buffers, nbufs, aw, s, z_hat,
                    key, xs):
-        """Scan body shared by both chunk calling conventions."""
+        """Scan body shared by both chunk calling conventions; the
+        noise field and transition, and the in-scan products, run under
+        the named scopes ``telemetry.SCOPE_NOISE`` and
+        ``telemetry.SCOPE_PRODUCTS``."""
         m, c = self.model, self.cfg
         e, dt = c.members, c.jdtype
         diag = self.diagnostics
@@ -549,9 +558,10 @@ class ForecastEngine:
 
         def body(carry, x):
             s, z_hat = carry
-            z = m.noise.to_grid(z_hat, nbufs)
-            if c.centered:
-                z = noiselib.center_noise(z, axis=0)
+            with jax.named_scope(telemetry.SCOPE_NOISE):
+                z = m.noise.to_grid(z_hat, nbufs)
+                if c.centered:
+                    z = noiselib.center_noise(z, axis=0)
             cond = jnp.concatenate(
                 [jnp.broadcast_to(x["aux"], (e,) + x["aux"].shape), z],
                 axis=1)
@@ -561,12 +571,14 @@ class ForecastEngine:
             # shape/dtype is invariant (no-op in fp32).
             s = self._constrain(
                 self._step_members(params, buffers, s, cond).astype(dt))
-            z_hat = m.noise.step(jax.random.fold_in(key, x["n"]),
-                                 z_hat, nbufs)
-            sf = s.astype(jnp.float32)
-            out = {name: fn(sf, x) for name, fn in score_fns.items()}
-            if diag is not None:
-                out["diag"] = diag(sf)
+            with jax.named_scope(telemetry.SCOPE_NOISE):
+                z_hat = m.noise.step(jax.random.fold_in(key, x["n"]),
+                                     z_hat, nbufs)
+            with jax.named_scope(telemetry.SCOPE_PRODUCTS):
+                sf = s.astype(jnp.float32)
+                out = {name: fn(sf, x) for name, fn in score_fns.items()}
+                if diag is not None:
+                    out["diag"] = diag(sf)
             return (s, z_hat), out
 
         return jax.lax.scan(body, (s, z_hat), xs)
@@ -999,11 +1011,11 @@ class ForecastEngine:
                giving the verifying state for lead ``step``; enables
                in-scan scoring.
         steps: total lead steps; required when ``aux`` is a callable.
-        on_span: optional ``fn(name, t0, t1, args)`` observability hook
-               (monotonic ``perf_counter`` bounds) called around each
-               chunk's host->device staging; None (the default) keeps
-               the stage functions exactly as before -- the hook only
-               reads clocks, never touches the staged values.
+        on_span: optional ``fn(name, args)`` observability hook that
+               returns a context manager; each chunk's host->device
+               staging runs inside it, on the stager thread.  None (the
+               default) brackets nothing -- the hook only reads clocks,
+               never touches the staged values.
 
         Host staging is double-buffered through ``_ChunkStager``: chunk
         k+1's aux/truth materialize on a background thread while chunk k
@@ -1021,16 +1033,15 @@ class ForecastEngine:
             scored, orig_buffers,
             buffers if self.cfg.static_buffers else None)
 
+        span = on_span or _no_span
+
         def stage(start: int, k: int) -> dict:
-            t0 = time.perf_counter() if on_span is not None else 0.0
-            xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32),
-                  "aux": self._stage(aux, start, k)}
-            if scored:
-                xs["truth"] = self._stage(truth, start, k)
-            self._count_staged(k)
-            if on_span is not None:
-                on_span("stage_h2d", t0, time.perf_counter(),
-                        {"start": start, "steps": k})
+            with span("stage_h2d", {"start": start, "steps": k}):
+                xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32),
+                      "aux": self._stage(aux, start, k)}
+                if scored:
+                    xs["truth"] = self._stage(truth, start, k)
+                self._count_staged(k)
             return xs
 
         stager = _ChunkStager(bounds, stage)
@@ -1103,8 +1114,8 @@ class ForecastEngine:
         ticks once per shrink.
 
         ``on_span`` is the same clock-only observability hook as
-        ``stream``'s: ``fn(name, t0, t1, args)`` around each chunk's
-        staging, never touching staged values.
+        ``stream``'s: ``fn(name, args)``, a context manager around each
+        chunk's staging, never touching staged values.
         """
         b = len(state0s)
         if b < 1:
@@ -1127,13 +1138,14 @@ class ForecastEngine:
             scored, orig_buffers,
             buffers if self.cfg.static_buffers else None, batch=b)
 
+        span = on_span or _no_span
+
         def stage(start: int, k: int) -> dict:
             # Coalesced requests often share sources (the scheduler
             # hands every member the same aux callable): stage each
             # *distinct* source once and let jnp.stack broadcast it
             # device-side, instead of recomputing and re-copying B
             # identical host chunks.
-            t0 = time.perf_counter() if on_span is not None else 0.0
             staged: dict[int, jax.Array] = {}
 
             def once(src):
@@ -1143,14 +1155,13 @@ class ForecastEngine:
                     staged[id(src)] = out
                 return out
 
-            xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32),
-                  "aux": jnp.stack([once(a) for a in auxs])}
-            if scored:
-                xs["truth"] = jnp.stack([once(t) for t in truths])
-            self._count_staged(k * len({id(a) for a in auxs}))
-            if on_span is not None:
-                on_span("stage_h2d", t0, time.perf_counter(),
-                        {"start": start, "steps": k, "batch": b})
+            with span("stage_h2d", {"start": start, "steps": k,
+                                    "batch": b}):
+                xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32),
+                      "aux": jnp.stack([once(a) for a in auxs])}
+                if scored:
+                    xs["truth"] = jnp.stack([once(t) for t in truths])
+                self._count_staged(k * len({id(a) for a in auxs}))
             return xs
 
         stager = _ChunkStager(bounds, stage)

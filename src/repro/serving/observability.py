@@ -19,6 +19,9 @@ Cost discipline:
   only per-chunk clock reads on the same flag, so the disabled dispatch
   path is structurally the pre-observability one.  The
   ``sec5_observability`` benchmark row proves the delta is noise.
+  Host spans that mark work (``host_span``) are also written into any
+  running ``jax.profiler`` trace as ``repro:<name>`` annotations, on
+  the device trace's clock.
 * **Bit-identical always.** Instrumentation only reads clocks and
   copies already-computed values; the traced, profiled and untraced
   paths run the same lowered programs (``tests/test_observability.py``
@@ -36,8 +39,8 @@ import os
 import threading
 import time
 
-from repro.telemetry import (MetricsRegistry, NULL_TRACE, RequestTrace,
-                             setup_logging)
+from repro.telemetry import (ANNOTATION_PREFIX, MetricsRegistry,
+                             NULL_TRACE, RequestTrace, setup_logging)
 
 __all__ = ["ObservabilityConfig", "Observability", "FlightRecorder",
            "NULL_TRACE", "RequestTrace", "setup_logging", "METRIC_PREFIX"]
@@ -271,6 +274,34 @@ class Observability:
         with self._trace_lock:
             tr = self._traces.get(request_id)
         return tr.to_chrome() if tr is not None else None
+
+    @contextlib.contextmanager
+    def host_span(self, name: str, spans=(), args: dict | None = None,
+                  observe=None):
+        """Bracket host work on the calling thread as one span.
+
+        The span goes into each ``(trace, parent)`` pair of ``spans``
+        and, as ``jax.profiler.TraceAnnotation("repro:<name>")``, into
+        any ``jax.profiler`` trace running, on the device trace's clock;
+        ``observe`` (a histogram) also gets its seconds.  Yields the
+        span's ``args`` dict, which the body may fill in.  A no-op when
+        tracing is disabled.
+        """
+        args = dict(args or {})
+        if not self.config.enabled:
+            yield args
+            return
+        import jax
+        t0 = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(ANNOTATION_PREFIX + name):
+                yield args
+        finally:
+            t1 = time.perf_counter()
+            for trace, parent in spans:
+                trace.add(name, t0, t1, parent=parent, args=args)
+            if observe is not None:
+                observe.observe(t1 - t0)
 
     def note_stream(self, trace, t0: float, t1: float,
                     n_events: int) -> None:

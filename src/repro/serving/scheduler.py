@@ -33,7 +33,8 @@ service core:
   the scheduler's counters are registry instruments (``/v1/stats`` is
   a view over the same values ``/metrics`` exposes), each request gets
   a span tree (queue -> coalesce -> compile|aot_hit -> stage_h2d ->
-  chunk[k] -> score_fetch -> encode) on monotonic clocks, lifecycle
+  dispatch -> score_fetch -> encode) on monotonic clocks, the host
+  spans also written into any running ``jax.profiler`` trace, lifecycle
   events land in the flight recorder, and ``spec.profile`` wraps the
   rollout in a ``jax.profiler`` session -- all of it free when
   disabled and bit-identical always.
@@ -1088,7 +1089,9 @@ class ForecastScheduler:
                     if head is _SHUTDOWN:
                         return None
                     if head is None:
-                        self._cond.wait(timeout=self._next_wake_locked())
+                        with self.obs.host_span("await_request"):
+                            self._cond.wait(
+                                timeout=self._next_wake_locked())
                 batch = [head]
                 key = head.serve_spec.batch_key()
                 if self.max_batch > 1 and head.spec.coalesce:
@@ -1098,7 +1101,8 @@ class ForecastScheduler:
                         remaining = deadline - time.monotonic()
                         if remaining <= 0:
                             break
-                        self._cond.wait(timeout=remaining)
+                        with self.obs.host_span("await_request"):
+                            self._cond.wait(timeout=remaining)
                         self._sweep_locked()
                         self._take_matching(batch, key)
                     # batch re-forming: a solo straggler of a shape with
@@ -1308,10 +1312,9 @@ class ForecastScheduler:
         which start/done events then report as ``degraded_members``.
 
         Observability here is clock-reads and value-copies only: with
-        tracing disabled (``traced`` False and ``on_span`` None) the
-        dispatch path is structurally the pre-observability one, and a
-        traced request runs the same lowered programs in the same order
-        -- bit-identical either way."""
+        tracing disabled (``traced`` False, every ``host_span`` a no-op)
+        nothing is recorded, and a traced request runs the same lowered
+        programs in the same order -- bit-identical either way."""
         spec = streams[0].serve_spec
         b = len(streams)
         t_start = time.perf_counter()
@@ -1419,16 +1422,12 @@ class ForecastScheduler:
             if self.faults is not NULL_FAULTS:
                 by_sample = {k: _staged(v) for k, v in by_sample.items()}
             truths = [by_sample[s.spec.sample] for s in streams]
-        # stage_h2d spans: the stager's background thread reports each
-        # chunk's host materialization through this clock-only hook
-        # (None when observability is off -- the engine then runs the
-        # exact pre-observability stage functions)
-        on_span = None
-        if self.obs.enabled:
-            def on_span(name, s_t0, s_t1, args=None):
-                self.obs.h2d_seconds.observe(s_t1 - s_t0)
-                for st in streams:
-                    st.trace.add(name, s_t0, s_t1, args=args)
+        # stage_h2d spans: the stager's background thread brackets each
+        # chunk's host materialization with this clock-only hook
+        def on_span(name, args=None):
+            return self.obs.host_span(
+                name, [(st.trace, 0) for st in streams], args=args,
+                observe=self.obs.h2d_seconds)
 
         # opt-in device profiling: process-global, so at most one
         # session at a time (the hub's lock arbitrates); never enters
@@ -1467,6 +1466,9 @@ class ForecastScheduler:
                                  args={"batch_size": b})
                 rollout_sids[stream.request_id] = stream.trace.begin(
                     "rollout", args={"batch_size": b})
+        # the per-chunk host spans hang under each request's rollout
+        in_rollout = [(s.trace, rollout_sids.get(s.request_id, 0))
+                      for s in streams]
 
         def fetch_and_emit(index: int, block_list) -> None:
             # Runs on the dedicated fetch thread, in chunk order: the
@@ -1474,37 +1476,45 @@ class ForecastScheduler:
             # thread is already staging and enqueueing chunk k+1 while
             # chunk k's scores download (score_fetch) and encode.
             self.faults.fire("score_fetch", index=index)
-            f0 = time.perf_counter() if traced else 0.0
             host_blocks: list = [None] * len(block_list)
-            for j, (stream, blk) in enumerate(zip(streams, block_list)):
-                if stream.cancelled or blk is None:
-                    # blk is None exactly when the rollout shrank away
-                    # from this (cancelled) member's slot
-                    if blk is None and not shrunk[0]:
-                        shrunk[0] = True
-                        self.obs.batch_shrinks.inc()
-                        for st in streams:
-                            self.obs.flight_record(st.request_id,
-                                                   "shrink", index=index)
-                    continue
-                # materialize the scores on host NOW (same transfer the
-                # fused chunk_event used to do; np.asarray below is then
-                # a no-op view, so the wire bytes are unchanged)
-                host_scores = {k: np.asarray(jax.device_get(v), np.float32)
-                               for k, v in blk.scores.items()}
-                if blk.final_state is not None and stream.spec.return_state:
-                    finals[j] = np.asarray(jax.device_get(blk.final_state))
-                host_blocks[j] = types.SimpleNamespace(
-                    lead_steps=blk.lead_steps, scores=host_scores)
-            f1 = time.perf_counter() if traced else 0.0
+            with self.obs.host_span("score_fetch", in_rollout,
+                                    args={"index": index}):
+                for j, (stream, blk) in enumerate(zip(streams,
+                                                      block_list)):
+                    if stream.cancelled or blk is None:
+                        # blk is None exactly when the rollout shrank
+                        # away from this (cancelled) member's slot
+                        if blk is None and not shrunk[0]:
+                            shrunk[0] = True
+                            self.obs.batch_shrinks.inc()
+                            for st in streams:
+                                self.obs.flight_record(
+                                    st.request_id, "shrink", index=index)
+                        continue
+                    # materialize the scores on host NOW (same transfer
+                    # the fused chunk_event used to do; np.asarray below
+                    # is then a no-op view, so the wire bytes are
+                    # unchanged)
+                    host_scores = {k: np.asarray(jax.device_get(v),
+                                                 np.float32)
+                                   for k, v in blk.scores.items()}
+                    if (blk.final_state is not None
+                            and stream.spec.return_state):
+                        finals[j] = np.asarray(
+                            jax.device_get(blk.final_state))
+                    host_blocks[j] = types.SimpleNamespace(
+                        lead_steps=blk.lead_steps, scores=host_scores)
             evs = []
-            for j, (stream, blk) in enumerate(zip(streams, host_blocks)):
-                if blk is None:
-                    continue
-                evs.append((j, stream,
-                            transport.chunk_event(stream.request_id,
-                                                  index, blk)))
-            now = time.perf_counter()
+            with self.obs.host_span("encode", in_rollout,
+                                    args={"index": index}):
+                for j, (stream, blk) in enumerate(zip(streams,
+                                                      host_blocks)):
+                    if blk is None:
+                        continue
+                    evs.append((j, stream,
+                                transport.chunk_event(stream.request_id,
+                                                      index, blk)))
+                now = time.perf_counter()
             dt = now - last_ready[0]
             last_ready[0] = now
             for j, stream, ev in evs:
@@ -1514,13 +1524,6 @@ class ForecastScheduler:
                     continue  # retry re-dispatch: this chunk already went
                 stream.next_chunk = index + 1
                 stream.put(ev)
-            if traced:
-                for j, stream, ev in evs:
-                    parent = rollout_sids.get(stream.request_id, 0)
-                    stream.trace.add("score_fetch", f0, f1, parent=parent,
-                                     args={"index": index})
-                    stream.trace.add("encode", f1, now, parent=parent,
-                                     args={"index": index})
 
         futures = []
         with prof_cm as prof_path:
@@ -1528,20 +1531,16 @@ class ForecastScheduler:
                                     thread_name_prefix="d2h-fetch") as ex:
                 block_iter = enumerate(blocks)
                 while True:
-                    c0 = time.perf_counter() if traced else 0.0
-                    try:
-                        index, block_list = next(block_iter)
-                    except StopIteration:
-                        break
+                    # dispatch: the wait for the stager's chunk and the
+                    # enqueue of its program (the device runs it later)
+                    with self.obs.host_span("dispatch",
+                                            in_rollout) as span_args:
+                        try:
+                            index, block_list = next(block_iter)
+                        except StopIteration:
+                            break
+                        span_args["index"] = index
                     self.faults.fire("rollout_chunk", index=index)
-                    if traced:
-                        c1 = time.perf_counter()
-                        for stream in streams:
-                            stream.trace.add(
-                                f"chunk[{index}]", c0, c1,
-                                parent=rollout_sids.get(stream.request_id,
-                                                        0),
-                                args={"index": index})
                     futures.append(ex.submit(fetch_and_emit, index,
                                              block_list))
                     if all(s.cancelled for s in streams):

@@ -122,7 +122,8 @@ class _ForecastHandler(BaseHTTPRequestHandler):
         the consumer's connection died -- so the stream is parked for
         resume (``note_disconnect``: events keep accumulating in the
         replay ring for the scheduler's grace window) instead of the
-        rollout being cancelled outright.
+        rollout being cancelled outright.  Each write is a
+        ``stream_write`` host span.
         """
         sched = self.service.scheduler
         t_stream = time.perf_counter()
@@ -131,8 +132,10 @@ class _ForecastHandler(BaseHTTPRequestHandler):
             for ev in events:
                 sched.faults.fire("stream_write",
                                   request_id=stream.request_id)
-                self.wfile.write(transport.dump_event(ev))
-                self.wfile.flush()
+                with sched.obs.host_span("stream_write",
+                                         [(stream.trace, 0)]):
+                    self.wfile.write(transport.dump_event(ev))
+                    self.wfile.flush()
                 n_events += 1
         except (BrokenPipeError, ConnectionResetError, InjectedFault):
             sched.note_disconnect(stream)

@@ -5,7 +5,7 @@ deliberately imports nothing heavier than the standard library so light
 client processes (and tests) can parse ``/metrics`` or load a trace
 without dragging in jax.
 
-Three building blocks:
+Four building blocks:
 
 * **Metrics** -- ``Counter`` / ``Gauge`` / ``Histogram`` registered in a
   ``MetricsRegistry`` and rendered in Prometheus text exposition format
@@ -22,6 +22,9 @@ Three building blocks:
   threads) and export as Chrome/Perfetto trace-event JSON via
   ``to_chrome``.  ``NULL_TRACE`` is the no-op twin used when tracing is
   disabled, so instrumented code never branches.
+* **Profile names** -- ``SCOPES``, the ``jax.named_scope`` names the
+  model and engine put on their device ops, and ``ANNOTATION_PREFIX``
+  of the host spans written into a ``jax.profiler`` trace.
 * **Logging** -- ``setup_logging`` configures the ``repro`` logger
   hierarchy once, writing to stderr (stdout stays machine-readable for
   CLIs that print artifact paths).
@@ -520,6 +523,30 @@ class _NullTrace:
 
 #: shared no-op trace: ``stream.trace is NULL_TRACE`` tests "untraced".
 NULL_TRACE = _NullTrace()
+
+
+# ---------------------------------------------------------------------------
+# names in a device profile
+
+#: ``jax.named_scope`` names of the FCN3 operators (``repro.core``) and
+#: of the forecast engine's scan body (``repro.inference.engine``).  The
+#: compiled ops carry them in their HLO ``op_name`` metadata, so a
+#: ``jax.profiler`` trace of the device puts each op's time down to one
+#: of these (the innermost, where scopes nest).
+SCOPE_ENCODER = "fcn3.encoder"
+SCOPE_LOCAL_CONV = "fcn3.local_conv"
+SCOPE_SPECTRAL_CONV = "fcn3.spectral_conv"
+SCOPE_MLP = "fcn3.mlp"
+SCOPE_DECODER = "fcn3.decoder"
+SCOPE_NOISE = "engine.noise"
+SCOPE_PRODUCTS = "engine.products"
+SCOPES = (SCOPE_ENCODER, SCOPE_LOCAL_CONV, SCOPE_SPECTRAL_CONV, SCOPE_MLP,
+          SCOPE_DECODER, SCOPE_NOISE, SCOPE_PRODUCTS)
+
+#: prefix of the host spans the serving stack also writes into a
+#: ``jax.profiler`` trace (``jax.profiler.TraceAnnotation``), so they lie
+#: on the device trace's clock
+ANNOTATION_PREFIX = "repro:"
 
 
 # ---------------------------------------------------------------------------

@@ -122,8 +122,8 @@ class TestRequestTrace:
         tr = RequestTrace("r1", {"k": "v"}, t0=100.0)
         a = tr.add("queue", 100.0, 101.0)
         roll = tr.add("rollout", 101.0, 103.5)
-        tr.add("chunk[0]", 101.0, 102.0, parent=roll)
-        tr.add("chunk[1]", 102.0, 103.5, parent=roll)
+        tr.add("dispatch", 101.0, 102.0, parent=roll, args={"index": 0})
+        tr.add("dispatch", 102.0, 103.5, parent=roll, args={"index": 1})
         live = tr.begin("stream")  # begin/end pair uses the real clock
         tr.end(live)
         tr.finish()
@@ -133,7 +133,8 @@ class TestRequestTrace:
         kids = {c["name"]: c for c in tree["children"]}
         assert set(kids) == {"queue", "rollout", "stream"}
         chunks = kids["rollout"]["children"]
-        assert [c["name"] for c in chunks] == ["chunk[0]", "chunk[1]"]
+        assert [(c["name"], c["args"]["index"]) for c in chunks] == [
+            ("dispatch", 0), ("dispatch", 1)]
         # child durations sum to exactly their parent's (contiguous)
         assert sum(c["dur_s"] for c in chunks) == \
             pytest.approx(kids["rollout"]["dur_s"])
@@ -215,7 +216,7 @@ class TestServedTraces:
         names = {e["name"] for e in trace["traceEvents"]
                  if e.get("ph") == "X"}
         required = {"request", "admit", "queue", "coalesce",
-                    "engine_build", "inputs", "rollout", "chunk[0]",
+                    "engine_build", "inputs", "rollout", "dispatch",
                     "score_fetch", "encode", "finalize"}
         assert required <= names, names
         assert "compile" in names or "aot_hit" in names

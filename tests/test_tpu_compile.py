@@ -117,3 +117,54 @@ def test_crps_fused_full_state(one_chip):
     n = 72 * 721 * 1440
     _compile(lambda e, o: crps_fused(e, o, fair=True, interpret=False),
              one_chip, (2, n), (n,))
+
+
+#: the named scope each Pallas kernel's calls may sit under
+KERNEL_SCOPES = {
+    "disco_band_contract": {"fcn3.encoder", "fcn3.local_conv",
+                            "fcn3.decoder"},
+    "legendre_contract": {"fcn3.spectral_conv"},
+}
+
+
+def test_fcn3_step_kernel_calls_carry_scopes(one_chip, chip_dft,
+                                             monkeypatch):
+    """The smoke-size FCN3 step over two members, compiled with the
+    Pallas kernels for a v5e: each kernel call carries the named scope
+    of the operator that makes it (``repro.telemetry.SCOPES``) in its
+    ``op_name``, which is how a chip trace puts its time down to an
+    operator."""
+    import re
+
+    from repro import telemetry
+    from repro.configs import fcn3 as fcn3cfg
+    from repro.core.fcn3 import FCN3
+    from repro.kernels import config as kconfig
+    monkeypatch.setattr(kconfig, "compiled_backend", lambda: True)
+    cfg = fcn3cfg.fcn3_smoke()
+    model = FCN3(cfg)
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    x = (2, cfg.n_state, cfg.nlat, cfg.nlon)
+    c = (2, cfg.n_cond_in, cfg.nlat, cfg.nlon)
+    text = jax.jit(jax.vmap(model.apply, in_axes=(None, None, 0, 0))).lower(
+        on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0))),
+        on_chip(jax.eval_shape(model.make_buffers)),
+        jax.ShapeDtypeStruct(x, jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct(c, jnp.float32, sharding=one_chip),
+    ).compile().as_text()
+    seen = {k: set() for k in KERNEL_SCOPES}
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line or " = " not in line:
+            continue
+        kernel = line.split(" = ")[0].strip().lstrip("%").rsplit(".")[0]
+        stack = re.search(r'op_name="([^"]*)"', line).group(1)
+        scope = next((p for p in (q.rstrip(")").rsplit("(", 1)[-1]
+                                  for q in reversed(stack.split("/")))
+                      if p in telemetry.SCOPES), None)
+        assert scope in KERNEL_SCOPES[kernel], (kernel, stack)
+        seen[kernel].add(scope)
+    assert seen == KERNEL_SCOPES
