@@ -502,7 +502,8 @@ def bench_sec5_kernels() -> None:
     dis_ref = jax.jit(lambda x: dlib.apply_disco_conv(
         wd, x, full, plan.stride, affine=plan.affine))
     dis_pal = jax.jit(lambda x: dlib.apply_disco_conv(
-        wd, x, band, plan.stride, affine=plan.affine, kernels=kc))
+        wd, x, band, plan.stride, affine=plan.affine, kernels=kc,
+        runs=plan.band_runs))
     us_r, us_p = _ab_timeit([lambda: dis_ref(xd), lambda: dis_pal(xd)], n=5)
     _row("sec5_kernels_disco", us_p,
          f"ref_us={us_r:.1f};mode={mode};blocks={dis_blocks};"

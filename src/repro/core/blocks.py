@@ -80,6 +80,7 @@ def init_block(key: jax.Array, spec: BlockSpec, dtype=jnp.float32) -> dict:
 def apply_block(params: dict, spec: BlockSpec, x: jax.Array, cond: jax.Array,
                 buffers: dict,
                 affine: tuple[int, int] | None = None,
+                runs: tuple | None = None,
                 kernels: KernelConfig | None = None) -> jax.Array:
     """One processor block.
 
@@ -87,8 +88,9 @@ def apply_block(params: dict, spec: BlockSpec, x: jax.Array, cond: jax.Array,
     conditioning (auxiliary + noise embeddings, constant across blocks).
     buffers: latent-grid geometry -- {"psi", "lat_idx"} (or the banded
     pallas layout) for local blocks and {"wpct", "pct"} for global
-    blocks.  ``kernels`` routes the hot contraction through the Pallas
-    substrate (``repro.kernels.dispatch``).
+    blocks; ``affine`` and ``runs`` are the latent plan's static band
+    data for local blocks.  ``kernels`` routes the hot contraction
+    through the Pallas substrate (``repro.kernels.dispatch``).
     """
     local = spec.kind == "local"
     with jax.named_scope(telemetry.SCOPE_LOCAL_CONV if local
@@ -98,7 +100,7 @@ def apply_block(params: dict, spec: BlockSpec, x: jax.Array, cond: jax.Array,
         if local:
             h = discolib.apply_disco_conv(params["conv"], h, buffers,
                                           stride=1, groups=1, affine=affine,
-                                          kernels=kernels)
+                                          kernels=kernels, runs=runs)
         else:
             h = speclib.apply_spectral_conv(params["conv"], h, buffers,
                                             nlon=x.shape[-1],

@@ -119,6 +119,12 @@ class FCN3Config:
         return specs
 
 
+def _runs(plan: discolib.DiscoPlan, buffers: dict) -> tuple | None:
+    """The plan's band row runs where ``buffers`` hold the banded split
+    (the reference layout needs none, and is spared the split)."""
+    return plan.band_runs if "psi_band" in buffers else None
+
+
 class FCN3:
     """Functional module: ``init`` -> params, ``make_buffers`` -> geometry,
     ``apply(params, buffers, state, cond) -> next state``."""
@@ -172,6 +178,16 @@ class FCN3:
             "dec": self.dec_plan.buffer_specs(dt, kc),
             "latent_sht": self.latent_sht.buffer_specs(),
         }
+
+    def disco_band_report(self) -> dict:
+        """Per DISCO plan, the row runs the banded kernel is called on
+        and the share of the whole band's Toeplitz lanes they issue
+        (``DiscoPlan.band_window_share``)."""
+        return {name: {"runs": len(plan.band_runs),
+                       "window_share": round(plan.band_window_share, 4)}
+                for name, plan in (("enc", self.enc_plan),
+                                   ("latent", self.latent_plan),
+                                   ("dec", self.dec_plan))}
 
     # ------------------------------------------------------------------
     def init(self, key: jax.Array) -> dict:
@@ -272,24 +288,23 @@ class FCN3:
         hw = atmos.shape[-2:]
         # (..., L, A, H, W): shared encoder applied per level.
         atmos = atmos.reshape(b + (nl, na) + hw)
-        kc = cfg.kernels
-        za = discolib.apply_disco_conv(params["enc_atmos"], atmos,
-                                       buffers["enc"], self.enc_plan.stride,
-                                       groups=na,
-                                       affine=self.enc_plan.affine,
-                                       kernels=kc)
+        enc = buffers["enc"]
+        za = self._conv(params["enc_atmos"], atmos, enc, self.enc_plan, na)
         za = za.reshape(b + (nl * cfg.atmos_embed,) + za.shape[-2:])
-        zs = discolib.apply_disco_conv(params["enc_surface"], surface,
-                                       buffers["enc"], self.enc_plan.stride,
-                                       groups=cfg.n_surface,
-                                       affine=self.enc_plan.affine,
-                                       kernels=kc)
-        zc = discolib.apply_disco_conv(params["enc_cond"], cond_in,
-                                       buffers["enc"], self.enc_plan.stride,
-                                       groups=cfg.n_cond_in,
-                                       affine=self.enc_plan.affine,
-                                       kernels=kc)
+        zs = self._conv(params["enc_surface"], surface, enc, self.enc_plan,
+                        cfg.n_surface)
+        zc = self._conv(params["enc_cond"], cond_in, enc, self.enc_plan,
+                        cfg.n_cond_in)
         return jnp.concatenate([za, zs], axis=-3), zc
+
+    def _conv(self, params: dict, x: jax.Array, buffers: dict,
+              plan: discolib.DiscoPlan, groups: int) -> jax.Array:
+        """One DISCO convolution on ``plan``'s geometry; banded buffers
+        also take the plan's static row runs."""
+        return discolib.apply_disco_conv(
+            params, x, buffers, plan.stride, groups=groups,
+            affine=plan.affine, kernels=self.cfg.kernels,
+            runs=_runs(plan, buffers))
 
     def _decode(self, params: dict, buffers: dict, latent: jax.Array
                 ) -> jax.Array:
@@ -299,7 +314,6 @@ class FCN3:
         so it is never materialized for all levels at once."""
         cfg = self.cfg
         nl = cfg.n_levels
-        kc = cfg.kernels
         atmos_lat = latent[..., : nl * cfg.atmos_embed, :, :]
         surf_lat = latent[..., nl * cfg.atmos_embed:, :, :]
         b = atmos_lat.shape[:-3]
@@ -308,18 +322,13 @@ class FCN3:
             atmos_lat.reshape(b + (nl, cfg.atmos_embed) + hw), -4, 0)
 
         def decode_level(lat):
-            return discolib.apply_disco_conv(
-                params["dec_atmos"], self.upsample(lat), buffers["dec"], 1,
-                groups=cfg.n_atmos, affine=self.dec_plan.affine, kernels=kc)
+            return self._conv(params["dec_atmos"], self.upsample(lat),
+                              buffers["dec"], self.dec_plan, cfg.n_atmos)
 
         ua = jnp.moveaxis(jax.lax.map(decode_level, levels), 0, -4)
         ua = ua.reshape(b + (nl * cfg.n_atmos,) + ua.shape[-2:])
-        us = discolib.apply_disco_conv(params["dec_surface"],
-                                       self.upsample(surf_lat),
-                                       buffers["dec"], 1,
-                                       groups=cfg.n_surface,
-                                       affine=self.dec_plan.affine,
-                                       kernels=kc)
+        us = self._conv(params["dec_surface"], self.upsample(surf_lat),
+                        buffers["dec"], self.dec_plan, cfg.n_surface)
         return jnp.concatenate([ua, us], axis=-3)
 
     @functools.cached_property
@@ -349,10 +358,12 @@ class FCN3:
             # remat per block: activation recomputation keeps the rollout
             # training memory linear in depth (the paper trades this against
             # deeper spatial parallelism; we support both levers).
-            affine = self.latent_plan.affine if spec.kind == "local" else None
-            fn = (lambda pp, xx, cc, bb, _spec=spec, _aff=affine:
+            local = spec.kind == "local"
+            affine = self.latent_plan.affine if local else None
+            runs = _runs(self.latent_plan, buf) if local else None
+            fn = (lambda pp, xx, cc, bb, _spec=spec, _aff=affine, _runs=runs:
                   blk.apply_block(pp, _spec, xx, cc, bb, affine=_aff,
-                                  kernels=cfg.kernels))
+                                  runs=_runs, kernels=cfg.kernels))
             x = jax.checkpoint(fn)(p, x, cond, buf)
         with jax.named_scope(telemetry.SCOPE_DECODER):
             out = self._decode(params, buffers, x)

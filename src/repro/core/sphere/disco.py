@@ -25,6 +25,8 @@ Two execution paths produce identical results, selected per
   kernel consumes and the few near-pole *wrap rows* whose support circles
   the globe; dispatch recomputes those by the exact FFT correlation, so
   the full (K, H, S, W) psi never needs to be materialized on device.
+  ``band_runs`` groups the interior rows into runs that share one
+  Toeplitz window, and the kernel is called once per run.
 
 Filter basis: Morlet-like wavelets on the cutoff disk, paper eq. (24):
 ``k_{l,m}(t', a) = cos^2(pi/2 t') * exp(i pi t' (l sin a + m cos a))``,
@@ -42,7 +44,7 @@ import numpy as np
 
 from repro.core.sphere import fourier
 from repro.core.sphere import grids as glib
-from repro.kernels.config import KernelConfig
+from repro.kernels.config import BLOCK_DEFAULTS, KernelConfig, disco_window
 
 
 # ---------------------------------------------------------------------------
@@ -167,6 +169,25 @@ class DiscoPlan:
             cached = split_psi_band(self.psi)
             object.__setattr__(self, "_split_cache", cached)
         return cached
+
+    @functools.cached_property
+    def band_runs(self) -> tuple[tuple[int, int, int], ...]:
+        """The banded kernel's row runs ``(h0, h1, d)`` (``band_runs``
+        on the memoized split): dispatch makes one kernel call per run."""
+        band, wrap_rows, _ = self._banded_split()
+        return band_runs(band, wrap_rows, self.stride)
+
+    @property
+    def band_window_share(self) -> float:
+        """Toeplitz lanes the run calls issue, over those one call on the
+        whole band issued: sum of window x rows over the runs, divided by
+        the widest row's window x every row."""
+        band, _, _ = self._banded_split()
+        w_blk = BLOCK_DEFAULTS["disco"]["w_blk"]
+        issued = sum(disco_window(d, self.stride, w_blk) * (h1 - h0)
+                     for h0, h1, d in self.band_runs)
+        return issued / (disco_window(band.shape[-1], self.stride, w_blk)
+                         * band.shape[1])
 
     def buffer_specs(self, dtype=jnp.float32,
                      kernels: KernelConfig | None = None
@@ -363,6 +384,8 @@ def split_psi_band(psi: np.ndarray, d_max: int | None = None
     share one symmetric band of D = 2*max_half_width + 1 taps covering
     offsets ``-(D//2) .. D//2``; the convention is baked into dispatch
     (``off0 = -(D // 2)``) so D is recoverable from the buffer shape.
+    Dispatch trims the band to each run's own centred taps
+    (``band_runs``).
 
     Returns ``(psi_band, wrap_rows, psi_wrap)``:
       psi_band: (K, H, S, D) with wrap rows zeroed;
@@ -390,6 +413,40 @@ def split_psi_band(psi: np.ndarray, d_max: int | None = None
     band[:, wrap_rows] = 0.0
     psi_wrap = psi[:, wrap_rows].copy()
     return band.astype(np.float32), wrap_rows, psi_wrap.astype(np.float32)
+
+
+def band_runs(psi_band: np.ndarray, wrap_rows: np.ndarray, stride: int,
+              w_blk: int = BLOCK_DEFAULTS["disco"]["w_blk"]
+              ) -> tuple[tuple[int, int, int], ...]:
+    """Group the band's rows into the runs the kernel is called on.
+
+    Row h's own support is ``D_h = 2 * half_h + 1`` taps, ``half_h`` its
+    support half-width (read off ``psi_band``, which holds every nonzero
+    entry of the rows that are not wrap rows).  A run ``(h0, h1, d)`` is
+    a maximal stretch of consecutive rows, none of them a wrap row, that
+    share one Toeplitz window ``disco_window(D_h, stride, w_blk)``; ``d``
+    is the odd width of its widest row, so the centred taps
+    ``D//2 - d//2 .. D//2 + d//2`` hold every nonzero entry of its rows.
+    Wrap rows belong to no run: the rows between runs are exactly
+    ``wrap_rows``.  A band whose rows all share one window is one run.
+    """
+    _, h, _, d_band = psi_band.shape
+    taps = np.abs(np.arange(d_band) - d_band // 2)
+    nz = np.abs(psi_band).max(axis=(0, 2)) > 0              # (H, D)
+    half = np.where(nz, taps[None, :], 0).max(axis=1)       # (H,)
+    wrap = np.zeros(h, bool)
+    wrap[np.asarray(wrap_rows)] = True
+    runs: list[tuple[int, int, int]] = []
+    for row in np.flatnonzero(~wrap):
+        row, d = int(row), 2 * int(half[row]) + 1
+        if runs and runs[-1][1] == row and (
+                disco_window(runs[-1][2], stride, w_blk)
+                == disco_window(d, stride, w_blk)):
+            h0, _, d0 = runs[-1]
+            runs[-1] = (h0, row + 1, max(d0, d))
+        else:
+            runs.append((row, row + 1, d))
+    return tuple(runs)
 
 
 # ---------------------------------------------------------------------------
@@ -553,20 +610,23 @@ def init_disco_conv(key: jax.Array, c_out: int, c_in: int, n_basis: int,
 def apply_disco_conv(params: dict, x: jax.Array, buffers: dict,
                      stride: int, groups: int = 1,
                      affine: tuple[int, int] | None = None,
-                     kernels: KernelConfig | None = None) -> jax.Array:
+                     kernels: KernelConfig | None = None,
+                     runs: tuple | None = None) -> jax.Array:
     """x: (..., C_in, H_in, W_in) -> (..., C_out, H_out, W_out).
 
     The contraction dispatches on the buffer layout: banded buffers
     (built by ``DiscoPlan.buffers`` under pallas dispatch) route through
-    the Pallas band kernel, which also applies the channel mix, with the
-    FFT fallback on wrap rows; full-psi buffers take the reference FFT
-    correlation.  ``kernels`` supplies the interpret flag and tile shape
-    for the Pallas call.
+    the Pallas band kernel, once per row run of the plan
+    (``DiscoPlan.band_runs``, static like ``affine``), which also applies
+    the channel mix, with the FFT fallback on wrap rows; full-psi
+    buffers take the reference FFT correlation and need no runs.
+    ``kernels`` supplies the interpret flag and tile shape for the
+    Pallas call.
     """
     if "psi_band" in buffers:
         from repro.kernels import dispatch as kdispatch
         y = kdispatch.disco_conv_mixed(x, params["weight"], buffers, stride,
-                                       groups, affine, kernels)
+                                       groups, affine, runs, kernels)
     else:
         y = _reference_conv(params["weight"], x, buffers["psi"],
                             buffers["lat_idx"], stride, groups, affine)

@@ -64,6 +64,19 @@ TILE_MULTIPLES = {
 }
 
 
+#: lanes in a vector register row: the minor-dim multiple of that rule
+LANE = 128
+
+
+def disco_window(d: int, stride: int, w_blk: int) -> int:
+    """Input longitudes one banded-DISCO output tile of ``w_blk``
+    longitudes reads for a band of ``d`` taps at longitudinal ``stride``:
+    ``w_blk + ceil(d / stride) - 1``, rounded up to whole lanes.  The
+    kernel issues this many multiply-adds per output, channel and basis
+    function (``repro.kernels.disco.disco``), whatever the taps hold."""
+    return -(-(w_blk + -(-d // stride) - 1) // LANE) * LANE
+
+
 def legal_tile(op: str, dims: dict) -> bool:
     """True iff every tile dim of ``dims`` obeys ``TILE_MULTIPLES[op]``."""
     rule = TILE_MULTIPLES[op]

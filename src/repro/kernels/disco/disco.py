@@ -44,9 +44,8 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.config import block_sizes, default_interpret
+from repro.kernels.config import block_sizes, default_interpret, disco_window
 
-LANE = 128
 SUBLANE = 8
 
 
@@ -67,7 +66,7 @@ def tile_geometry(r: int, d: int, w_out: int, stride: int,
     # a lane multiple -- it is the minor dim of the mix block
     c_blk = (_round_up(r, SUBLANE) if r <= bs["c_blk"] else bs["c_blk"])
     d_ph = -(-d // stride)
-    wwin = _round_up(w_blk + d_ph - 1, LANE)
+    wwin = disco_window(d, stride, w_blk)
     n_wt = -(-w_out // w_blk)
     return {"c_blk": c_blk, "w_blk": w_blk, "d_ph": d_ph, "wwin": wwin,
             "n_wt": n_wt, "w_out_pad": n_wt * w_blk,

@@ -13,11 +13,12 @@ path:
   rules whose backward passes run the reference oracles, so a model
   dispatched through Pallas still trains / calibrates (the kernels
   themselves define no transpose rules).
-* **Exact pole handling.**  The banded DISCO route uses the dense band
-  kernel for interior rows and falls back to the exact FFT correlation
-  for the few near-pole *wrap rows* whose filter support circles the
-  globe (``repro.core.sphere.disco.split_psi_band``); the union covers
-  every nonzero psi entry, so the hybrid is lossless.
+* **Exact pole handling.**  The banded DISCO route runs the dense band
+  kernel on the interior rows, one call per run of rows that share a
+  Toeplitz window (``DiscoPlan.band_runs``), and falls back to the
+  exact FFT correlation for the few near-pole *wrap rows* whose filter
+  support circles the globe (``repro.core.sphere.disco.split_psi_band``);
+  the union covers every nonzero psi entry, so the hybrid is lossless.
 
 Layering: this module may import ``repro.core.sphere`` (pure reference
 ops) and the Pallas kernel packages; ``repro.core`` only ever imports it
@@ -174,9 +175,22 @@ def sht_inverse(c: jax.Array, pct: jax.Array, nlon: int,
 _ROW_TARGET = 128
 
 
+def band_run_rows(h0: int, h1: int, s: int, affine: tuple[int, int],
+                  h_in: int) -> tuple[int, int, tuple[int, int]]:
+    """The input rows ``[r0, r1)`` that output rows ``h0 .. h1 - 1`` of
+    an affine band of ``s`` rings read (``clip(a*h + s + b, 0, h_in-1)``),
+    and the affine map of those rows into the slice: the clip to the
+    slice's ends reads the same rows as the clip to the grid's."""
+    a, b = affine
+    r0 = min(max(a * h0 + b, 0), h_in - 1)
+    r1 = min(max(a * (h1 - 1) + s - 1 + b, 0), h_in - 1) + 1
+    return r0, r1, (a, a * h0 + b - r0)
+
+
 def disco_conv_mixed(x: jax.Array, weight: jax.Array, buffers: dict,
                      stride: int, groups: int = 1,
                      affine: tuple[int, int] | None = None,
+                     runs: tuple | None = None,
                      kernels: KernelConfig | None = None) -> jax.Array:
     """DISCO convolution without bias on the banded buffers: Pallas band
     kernel (contraction fused with the channel mix) + exact FFT wrap rows.
@@ -186,15 +200,30 @@ def disco_conv_mixed(x: jax.Array, weight: jax.Array, buffers: dict,
     ``core.sphere.disco.apply_disco_conv`` on the full psi tensor minus
     its bias.  Buffers come from ``DiscoPlan.banded_buffers``; the band
     tap convention is ``off0 = -(D // 2)``.
+
+    ``runs`` are the plan's static row runs ``(h0, h1, d)``
+    (``DiscoPlan.band_runs``).  The band kernel is called once per run,
+    on the run's rows, its centred ``d`` taps (the others are zero on
+    every row of the run) and only the input rows they read, so each run
+    issues the Toeplitz window its own support needs rather than the
+    widest row's.  The rows between runs are the wrap rows: the FFT
+    correlation computes them, and the pieces are put together in row
+    order.
     """
-    if affine is None:
-        raise ValueError("the banded DISCO kernel needs an affine plan")
+    if affine is None or runs is None:
+        raise ValueError("the banded DISCO kernel needs an affine plan "
+                         "and its row runs")
     kc = kernels or _DEFAULT
     _, interpret = kc.resolve("disco")
-    psi_band = buffers["psi_band"]
-    k, h_out, s, d = psi_band.shape
+    psi_band = buffers["psi_band"].astype(jnp.float32)
+    k, h_out, s, d_band = psi_band.shape
     lead, c_in = x.shape[:-3], x.shape[-3]
     h_in, w_in = x.shape[-2:]
+    c_out, w_out = weight.shape[0], w_in // stride
+    n_wrap = buffers["wrap_rows"].shape[0]
+    if n_wrap != h_out - sum(h1 - h0 for h0, h1, _ in runs):
+        raise ValueError(f"runs {runs} leave other rows than the plan's "
+                         f"{n_wrap} wrap rows")
     # fold trailing leading dims (e.g. the 13 pressure levels sharing one
     # encoder) into the kernel's channel rows, with a block-diagonal mix
     fold, rows = 0, c_in
@@ -208,19 +237,29 @@ def disco_conv_mixed(x: jax.Array, weight: jax.Array, buffers: dict,
         mix = jnp.einsum("kqr,fg->kfqgr", mix, eye).reshape(
             k, nfold * mix.shape[1], rows)
     xb = x.reshape((-1, rows, h_in, w_in))
-    y = _band_contract(xb, psi_band.astype(jnp.float32), mix, stride,
-                       tuple(affine), -(d // 2), interpret,
-                       kc.blocks_for("disco"))
-    c_out = weight.shape[0]
-    y = y.reshape(lead + (c_out, h_out, w_in // stride))
-    wrap_rows = buffers["wrap_rows"]
-    if wrap_rows.shape[0]:
+    yw = None
+    if n_wrap:
         # Exact FFT circular correlation on the wrap rows only; their
-        # psi keeps the full circle of offsets (zero band contribution).
+        # psi keeps the full circle of offsets.
+        wrap_rows = buffers["wrap_rows"]
         rows_w = jnp.take(buffers["lat_idx"], wrap_rows, axis=0)
         xw = jnp.take(x, rows_w.reshape(-1), axis=-2)
         xw = xw.reshape(x.shape[:-2] + rows_w.shape + (w_in,))
         zw = discolib.fft_correlate(xw, buffers["psi_wrap"], stride)
-        yw = discolib.mix_basis(zw, weight, groups)
-        y = y.at[..., wrap_rows, :].set(yw.astype(y.dtype))
-    return y
+        yw = discolib.mix_basis(zw, weight, groups).astype(jnp.float32)
+    pieces, done, n_wrapped = [], 0, 0
+    # an empty run at h_out closes the list, so the last wrap rows land
+    for h0, h1, d in (*runs, (h_out, h_out, 0)):
+        if h0 > done:            # wrap rows, in row order
+            pieces.append(yw[..., n_wrapped:n_wrapped + h0 - done, :])
+            n_wrapped += h0 - done
+        if h1 == h0:
+            continue
+        r0, r1, run_affine = band_run_rows(h0, h1, s, affine, h_in)
+        taps = psi_band[:, h0:h1, :, d_band // 2 - d // 2:
+                        d_band // 2 + d // 2 + 1]
+        y = _band_contract(xb[:, :, r0:r1], taps, mix, stride, run_affine,
+                           -(d // 2), interpret, kc.blocks_for("disco"))
+        pieces.append(y.reshape(lead + (c_out, h1 - h0, w_out)))
+        done = h1
+    return jnp.concatenate(pieces, axis=-2)

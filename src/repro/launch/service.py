@@ -219,13 +219,15 @@ def build_service(args: argparse.Namespace):
         if args.tune:
             model = pool.get(args.config[0]).model
             sweeps = 0
-            for op, shapes in autotune.model_op_shapes(model).items():
-                entry = autotune.sweep_op(op, shapes, cache=cache)
-                sweeps += entry["swept"]
-                _log.info("tune %s %s: %s (default_us=%.1f best_us=%.1f)",
-                          op, "x".join(str(v) for v in shapes),
-                          autotune.format_blocks(op, entry["dims"]),
-                          entry["default_us"], entry["best_us"])
+            for op, all_shapes in autotune.model_op_shapes(model).items():
+                for shapes in all_shapes:
+                    entry = autotune.sweep_op(op, shapes, cache=cache)
+                    sweeps += entry["swept"]
+                    _log.info(
+                        "tune %s %s: %s (default_us=%.1f best_us=%.1f)",
+                        op, "x".join(str(v) for v in shapes),
+                        autotune.format_blocks(op, entry["dims"]),
+                        entry["default_us"], entry["best_us"])
             _log.info("tuning ready: sweeps=%d %s", sweeps, cache.stats())
         else:
             _log.info("tuning cache installed: %s", cache.stats())
@@ -268,7 +270,13 @@ def build_service(args: argparse.Namespace):
     startup = {"preload": {}, "warm": []}
     for name in args.config:
         _log.info("preloading config %r ...", name)
-        startup["preload"][name] = pool.get(name).build_s
+        built = pool.get(name)
+        startup["preload"][name] = built.build_s
+        kernels = built.model.cfg.kernels.effective()
+        band = (built.model.disco_band_report()
+                if kernels["disco"].startswith("pallas") else None)
+        _log.info("config %r: kernels %s, disco band runs %s", name,
+                  kernels, band)
     for spec in warm_specs:
         out = scheduler.warmup(spec)
         startup["warm"].append({"spec": spec.to_dict(),

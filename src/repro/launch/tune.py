@@ -1,7 +1,9 @@
 """Offline Pallas kernel autotuning (docs/kernels.md#autotuning).
 
 Sweeps the candidate tile lattice for each hot-op family at concrete
-shapes -- either derived from a named model config or given explicitly --
+shapes -- either derived from a named model config (for DISCO, one shape
+per row run of the latent plan, as dispatch calls the kernel) or given
+explicitly, an ``--op`` repeated once per shape --
 and persists the winners in a ``TuningCache`` directory.  A second run
 over the same shapes reports ``sweeps=0``: everything resolves from the
 cache.  Serve with the results via ``repro.launch.service --tuning-dir``
@@ -85,7 +87,7 @@ def main(argv=None) -> None:
             except ValueError:
                 ap.error(f"--shape {raw!r} is not a comma-separated "
                          f"integer list")
-            ops_shapes[op] = shape
+            ops_shapes.setdefault(op, []).append(shape)
     else:
         ops_shapes = _model_shapes(args.config, args.members)
 
@@ -93,17 +95,19 @@ def main(argv=None) -> None:
     interpret = True if args.interpret else None
     sweeps = 0
     print("op,shapes,swept,candidates,default_us,best_us,speedup,blocks")
-    for op, shapes in ops_shapes.items():
-        entry = autotune.sweep_op(
-            op, shapes, cache=cache, force=args.force,
-            interpret=interpret, max_candidates=args.max_candidates,
-            iters=args.iters)
-        sweeps += entry["swept"]
-        speedup = entry["default_us"] / max(entry["best_us"], 1e-9)
-        print(f"{op},{'x'.join(str(v) for v in shapes)},"
-              f"{int(entry['swept'])},{len(entry['candidates'])},"
-              f"{entry['default_us']:.1f},{entry['best_us']:.1f},"
-              f"{speedup:.2f}x,{autotune.format_blocks(op, entry['dims'])}")
+    for op, all_shapes in ops_shapes.items():
+        for shapes in all_shapes:
+            entry = autotune.sweep_op(
+                op, shapes, cache=cache, force=args.force,
+                interpret=interpret, max_candidates=args.max_candidates,
+                iters=args.iters)
+            sweeps += entry["swept"]
+            speedup = entry["default_us"] / max(entry["best_us"], 1e-9)
+            print(f"{op},{'x'.join(str(v) for v in shapes)},"
+                  f"{int(entry['swept'])},{len(entry['candidates'])},"
+                  f"{entry['default_us']:.1f},{entry['best_us']:.1f},"
+                  f"{speedup:.2f}x,"
+                  f"{autotune.format_blocks(op, entry['dims'])}")
     stats = cache.stats()
     print(f"sweeps={sweeps} entries={stats['entries']} dir={stats['dir']}")
 

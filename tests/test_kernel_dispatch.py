@@ -6,8 +6,10 @@ Load-bearing guarantees:
   Pallas interpreter on CPU, and "pallas" on CPU requires an explicit
   ``interpret=True``;
 * the banded psi split is lossless (band + wrap rows cover every nonzero
-  filter entry) and the banded dispatch reproduces the exact FFT DISCO
-  convolution on real plans;
+  filter entry), its row runs cover every band row once with taps that
+  hold every nonzero entry of their rows, and the banded dispatch (one
+  kernel call per run) reproduces the exact FFT DISCO convolution on
+  real plans;
 * ``FCN3.make_buffers`` under pallas dispatch materializes the banded
   layout only -- never the full (K, H, S, W) psi;
 * ``FCN3.apply`` and a full ``ForecastEngine.forecast`` rollout match
@@ -30,12 +32,19 @@ from repro.core.fcn3 import FCN3
 from repro.core.sphere import disco as dlib
 from repro.core.sphere import grids, sht
 from repro.kernels import dispatch as kdispatch
-from repro.kernels.config import KernelConfig
+from repro.kernels.config import BLOCK_DEFAULTS, KernelConfig, disco_window
 from repro.kernels.disco import ops as disco_ops
 
 #: explicit CPU-CI pallas dispatch (interpret mode); on TPU/GPU the same
 #: tests would exercise the compiled kernels.
 PALLAS = KernelConfig(sht="pallas", disco="pallas", interpret=True)
+
+
+#: plans whose band splits into three runs: stride 1 and stride 2
+RUN_PLANS = [
+    ((33, 320, "equiangular"), (33, 320, "equiangular")),
+    ((33, 640, "equiangular"), (33, 320, "equiangular")),
+]
 
 
 class TestKernelConfig:
@@ -132,6 +141,46 @@ class TestSplitPsiBand:
         assert band1.shape[-1] <= 5
         assert len(wrap1) >= len(wrap0)
 
+    @pytest.mark.parametrize("gi,go", RUN_PLANS)
+    def test_runs_cover_band_rows_once_in_order(self, gi, go):
+        plan = dlib.make_disco_plan(grids.make_grid(*gi),
+                                    grids.make_grid(*go))
+        band, wrap_rows, _ = dlib.split_psi_band(plan.psi)
+        runs = plan.band_runs
+        h = plan.psi.shape[1]
+        rows = [r for h0, h1, _ in runs for r in range(h0, h1)]
+        assert rows == sorted(set(range(h)) - set(wrap_rows.tolist()))
+        assert all(h0 < h1 and d % 2 == 1 and d <= band.shape[-1]
+                   for h0, h1, d in runs)
+        # maximal: two touching runs differ in their window
+        w_blk = BLOCK_DEFAULTS["disco"]["w_blk"]
+        for (_, e0, d0), (s1, _, d1) in zip(runs, runs[1:]):
+            assert e0 < s1 or (disco_window(d0, plan.stride, w_blk)
+                               != disco_window(d1, plan.stride, w_blk))
+        assert 0 < plan.band_window_share <= 1
+
+    @pytest.mark.parametrize("gi,go", RUN_PLANS)
+    def test_run_taps_hold_every_nonzero_entry(self, gi, go):
+        # structural and lossless, like the split: each run's centred
+        # d taps plus the wrap rows rebuild psi exactly
+        plan = dlib.make_disco_plan(grids.make_grid(*gi),
+                                    grids.make_grid(*go))
+        band, wrap_rows, psi_wrap = dlib.split_psi_band(plan.psi)
+        w, dh = plan.psi.shape[-1], band.shape[-1] // 2
+        recon = np.zeros_like(plan.psi)
+        for h0, h1, d in plan.band_runs:
+            taps = np.arange(dh - d // 2, dh + d // 2 + 1)
+            recon[:, h0:h1][..., (taps - dh) % w] = band[:, h0:h1][..., taps]
+        recon[:, wrap_rows] = psi_wrap
+        np.testing.assert_array_equal(recon, plan.psi)
+
+    def test_uniform_rows_make_one_run(self):
+        plan = dlib.make_disco_plan(grids.make_grid(16, 32, "gauss"),
+                                    grids.make_grid(16, 32, "gauss"))
+        _, wrap_rows, _ = dlib.split_psi_band(plan.psi)
+        assert plan.band_runs == ((3, 13, 11),)
+        assert wrap_rows.tolist() == [0, 1, 2, 13, 14, 15]
+
     @settings(max_examples=15, deadline=None)
     @given(nlat=st.sampled_from([12, 16, 24]),
            d_max=st.integers(1, 64),
@@ -173,9 +222,41 @@ class TestDiscoDispatchParity:
                                     groups=2, affine=plan.affine)
         got = dlib.apply_disco_conv(params, x, plan.banded_buffers(),
                                     plan.stride, groups=2,
-                                    affine=plan.affine, kernels=PALLAS)
+                                    affine=plan.affine, kernels=PALLAS,
+                                    runs=plan.band_runs)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
                                    atol=1e-5)
+
+    @pytest.mark.parametrize("gi,go", RUN_PLANS, ids=["stride1", "stride2"])
+    def test_run_calls_match_fft_path(self, gi, go):
+        plan = dlib.make_disco_plan(grids.make_grid(*gi),
+                                    grids.make_grid(*go))
+        assert len(plan.band_runs) >= 3
+        assert plan.stride == gi[1] // go[1]
+        x = jax.random.normal(jax.random.PRNGKey(3), (2, 4, gi[0], gi[1]))
+        params = _conv_params(jax.random.PRNGKey(4), 4, 6, plan.n_basis, 2)
+        ref = dlib.apply_disco_conv(params, x, plan.buffers(), plan.stride,
+                                    groups=2, affine=plan.affine)
+        got = dlib.apply_disco_conv(params, x, plan.banded_buffers(),
+                                    plan.stride, groups=2,
+                                    affine=plan.affine, kernels=PALLAS,
+                                    runs=plan.band_runs)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(ref),
+                                   atol=1e-5)
+
+    def test_banded_dispatch_needs_the_runs(self):
+        g = grids.make_grid(16, 32, "gauss")
+        plan = dlib.make_disco_plan(g, g)
+        x = jnp.zeros((1, 2, 16, 32))
+        params = _conv_params(jax.random.PRNGKey(0), 2, 2, plan.n_basis, 1)
+        with pytest.raises(ValueError, match="runs"):
+            dlib.apply_disco_conv(params, x, plan.banded_buffers(),
+                                  plan.stride, affine=plan.affine,
+                                  kernels=PALLAS)
+        with pytest.raises(ValueError, match="wrap rows"):
+            dlib.apply_disco_conv(params, x, plan.banded_buffers(),
+                                  plan.stride, affine=plan.affine,
+                                  kernels=PALLAS, runs=((3, 12, 11),))
 
     def test_dispatch_follows_buffer_layout(self):
         g = grids.make_grid(16, 32, "gauss")
@@ -186,7 +267,7 @@ class TestDiscoDispatchParity:
                                   affine=plan.affine)
         b = dlib.apply_disco_conv(params, x, plan.banded_buffers(),
                                   plan.stride, affine=plan.affine,
-                                  kernels=PALLAS)
+                                  kernels=PALLAS, runs=plan.band_runs)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=1e-5)
 
 
@@ -249,6 +330,15 @@ class TestFCN3PallasDispatch:
             assert bufs["psi_wrap"].shape == (k, hw, s, w)
             # and the reference layout still carries the full psi
             assert b_ref[name]["psi"].shape == (k, h, s, w)
+
+    def test_band_report_counts_each_plans_runs(self, models):
+        cfg, m_ref, m_pal, params, b_ref, b_pal = models
+        report = m_pal.disco_band_report()
+        for name, plan in (("enc", m_pal.enc_plan),
+                           ("latent", m_pal.latent_plan),
+                           ("dec", m_pal.dec_plan)):
+            assert report[name]["runs"] == len(plan.band_runs) >= 1
+            assert 0 < report[name]["window_share"] <= 1
 
     def test_buffer_specs_mirror_buffers(self, models):
         cfg, m_ref, m_pal, params, b_ref, b_pal = models

@@ -20,7 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.crps.crps import crps_fused
 from repro.kernels.disco.disco import disco_band_contract
-from repro.kernels.dispatch import sht_forward_pallas, sht_inverse_pallas
+from repro.kernels.dispatch import (band_run_rows, sht_forward_pallas,
+                                    sht_inverse_pallas)
 
 #: full-width plans: (x, psi_band, mix, stride, affine).  x is one
 #: member's kernel input as FCN3 builds it (encoder: 13 levels x 5
@@ -34,6 +35,32 @@ DISCO_PLANS = {
     "decoder": ((1, 45, 721, 1440), (7, 721, 5, 641), (7, 5, 45), 1,
                 (1, -2)),
 }
+
+#: each plan's row runs (h0, h1, d), as ``DiscoPlan.band_runs`` gives
+#: them at full width: dispatch calls the kernel once per run
+DISCO_RUNS = {
+    "encoder": ((3, 5, 423), (5, 355, 251), (355, 357, 423)),
+    "latent": ((3, 5, 209), (5, 355, 125), (355, 357, 209)),
+    "decoder": ((3, 4, 641), (4, 6, 385), (6, 11, 239), (11, 710, 125),
+                (710, 715, 239), (715, 717, 385), (717, 718, 641)),
+}
+
+
+def _run_call(plan: str, run: int | None):
+    """(x, psi_band, mix, stride, affine) of one kernel call: the plan's
+    whole band (``run`` None) or one of its runs, as dispatch slices it."""
+    x, psi, mix, stride, affine = DISCO_PLANS[plan]
+    if run is None:
+        return x, psi, mix, stride, affine
+    h0, h1, d = DISCO_RUNS[plan][run]
+    r0, r1, run_affine = band_run_rows(h0, h1, psi[2], affine, x[2])
+    return ((*x[:2], r1 - r0, x[3]), (psi[0], h1 - h0, psi[2], d), mix,
+            stride, run_affine)
+
+
+DISCO_CALLS = [pytest.param(p, None, id=p) for p in sorted(DISCO_PLANS)] + [
+    pytest.param(p, i, id=f"{p}-run{i}")
+    for p in sorted(DISCO_RUNS) for i in range(len(DISCO_RUNS[p]))]
 
 
 @pytest.fixture(scope="module")
@@ -100,9 +127,9 @@ def test_legendre_latent_slab(one_chip, chip_dft, direction):
     _compile(fn, one_chip, x, (360, 360, 360), dtypes=(dtype, jnp.float32))
 
 
-@pytest.mark.parametrize("plan", sorted(DISCO_PLANS))
-def test_disco_band_full_width_plan(one_chip, plan):
-    x, psi, mix, stride, affine = DISCO_PLANS[plan]
+@pytest.mark.parametrize("plan,run", DISCO_CALLS)
+def test_disco_band_full_width_plan(one_chip, plan, run):
+    x, psi, mix, stride, affine = _run_call(plan, run)
     d = psi[-1]
     compiled = _compile(
         lambda a, p, m: disco_band_contract(
