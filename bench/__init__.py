@@ -1,1 +1,1 @@
-"""Chip benchmark of the served FCN3 forecast (see PERF.md)."""
+"""Chip benchmark of the served forecast (see PERF.md)."""

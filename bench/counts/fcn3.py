@@ -11,6 +11,10 @@ work than is counted here, and reads as a lower share of its roofline.
 Data, weights and tables move at the served precision's width:
 ``value_bytes`` 4 for float32, 2 for bfloat16 (``VALUE_BYTES``).  A
 multiply-add is 2 operations.  Nothing here imports the service.
+
+This is the ``fcn3`` family's counts module (``bench/family.py``): the
+harness reads ``VALUE_BYTES``, ``member_step``, ``step_calls`` and
+``SCOPES``.
 """
 
 from __future__ import annotations
@@ -26,6 +30,11 @@ from bench.reference import grids as glib
 VALUE_BYTES = {"float32": 4, "bfloat16": 2}
 #: Morlet basis size for ell_max = m_max = 2 (sin(0, 0) vanishes)
 N_BASIS = 7
+#: the ``jax.named_scope`` names the program puts on the FCN3 operators
+#: (``repro.telemetry.SCOPES``), which ``bench/scopes.py`` attributes
+#: device time to
+SCOPES = ("fcn3.encoder", "fcn3.local_conv", "fcn3.spectral_conv",
+          "fcn3.mlp", "fcn3.decoder")
 
 
 @dataclasses.dataclass(frozen=True)

@@ -1,5 +1,5 @@
-"""Plain float32 FCN3 (paper Section 3 / Appendix C), its synthetic data
-and its served products, written against this package alone.
+"""Plain float32 FCN3 (paper Section 3 / Appendix C), written against
+this package alone: the benchmark's ``fcn3`` family.
 
 The step is the paper's: grouped DISCO encoders onto the latent Gaussian
 grid, ``n_blocks`` processor blocks (one global spectral block, then
@@ -10,7 +10,10 @@ correlation over a full ``psi``; every transform is a plain SHT.  Run it
 under ``jax.default_matmul_precision("highest")`` on a TPU, or its
 matmuls take bf16 passes.
 
-The benchmark also makes the served model's weights here (``init`` plus
+What the harness (``bench/run.py``) takes from a family's reference:
+``Config.from_dict``, ``Reference(cfg)`` with ``.model.init`` and
+``.rollout``, ``make_weights`` and ``weights_key``.  The benchmark makes
+the served model's weights here (``init``, the blocks' layer scale and
 the LSUV-style calibration of C.6) and hands them to the service as a
 checkpoint, so the reference never takes weights from the program.
 """
@@ -29,6 +32,7 @@ from . import grids as glib
 from . import interp as interplib
 from . import noise as noiselib
 from . import sht as shtlib
+from .data import SyntheticERA5, scores
 
 
 @dataclasses.dataclass(frozen=True)
@@ -292,106 +296,6 @@ class FCN3:
                          out)
 
 
-# ---------------------------------------------------------------------------
-# Synthetic ERA5: the initial conditions, forcings and truth
-# ---------------------------------------------------------------------------
-
-class SyntheticERA5:
-    """Deterministic spectral surrogate of the 72-channel state: a
-    band-limited power law (PSD ~ l^-3 beyond l = 4), AR(1) in time with
-    rho = 0.95 per 6 h, a zonal climatology offset, softplus on water
-    channels; aux = land/sea masks, orography, cosine zenith angle."""
-
-    def __init__(self, cfg: Config, sht: shtlib.SHT):
-        self.cfg = cfg
-        self.sht = sht
-        self.grid = sht.grid
-        self.sigma_l = noiselib.power_law_sigma_l(sht.lmax, 3.0, 4)
-        g = self.grid
-        lat = np.pi / 2 - g.colat[:, None]
-        lon = g.lons[None, :]
-        conts = (np.sin(2 * lat) * np.cos(3 * lon)
-                 + 0.5 * np.sin(5 * lat + 1.3) * np.sin(2 * lon + 0.7))
-        land = (conts > 0.15).astype(np.float32)
-        oro = np.maximum(conts - 0.15, 0.0).astype(np.float32) * 2.0
-        self.static_aux = np.stack([land, 1.0 - land, oro]).astype(np.float32)
-
-    def aux_fields(self, t_hours: float) -> np.ndarray:
-        g = self.grid
-        day = t_hours / 24.0
-        decl = np.deg2rad(23.44) * np.sin(2 * np.pi * (day - 81.0) / 365.25)
-        lat = np.pi / 2 - g.colat
-        ha = (t_hours % 24.0) / 24.0 * 2 * np.pi + g.lons[None, :] - np.pi
-        cz = (np.sin(lat)[:, None] * np.sin(decl)
-              + np.cos(lat)[:, None] * np.cos(decl) * np.cos(ha))
-        cz = np.maximum(cz, 0.0).astype(np.float32)
-        return np.concatenate([self.static_aux, cz[None]], axis=0)
-
-    def _field(self, key, pct):
-        lmax, mmax = self.sht.lmax, self.sht.mmax
-        kr, ki = jax.random.split(key)
-        shape = (self.cfg.n_state, lmax, mmax)
-        re = jax.random.normal(kr, shape)
-        im = jax.random.normal(ki, shape)
-        m = jnp.arange(mmax)
-        im = jnp.where(m == 0, 0.0, im) * np.sqrt(0.5)
-        re = re * jnp.where(m == 0, 1.0, np.sqrt(0.5))
-        mask = jnp.asarray(shtlib.mode_mask(lmax, mmax), jnp.float32)
-        c = (jax.lax.complex(re, im) * mask
-             * jnp.asarray(self.sigma_l)[:, None])
-        return self.sht.inverse(c, pct)
-
-    def state(self, sample: int, t: int = 0, pct: jax.Array | None = None
-              ) -> jax.Array:
-        """(C, H, W) state of ``sample`` at 6-hour offset ``t``; pass the
-        inverse table ``pct`` under ``jit``."""
-        cfg = self.cfg
-        base = jax.random.fold_in(jax.random.PRNGKey(20200101), sample)
-        x = self._field(jax.random.fold_in(base, 0), pct)
-        rho = 0.95
-        for k in range(1, t + 1):
-            x = (rho * x + np.sqrt(1 - rho * rho)
-                 * self._field(jax.random.fold_in(base, k), pct))
-        colat = jnp.asarray(self.grid.colat, jnp.float32)
-        chan = jnp.arange(cfg.n_state, dtype=jnp.float32)
-        x = x + (0.5 * jnp.cos(colat)[None, :, None]
-                 * jnp.cos(chan * 0.37)[:, None, None])
-        water = np.zeros((cfg.n_state,), bool)
-        water[cfg.water_channel_indices()] = True
-        return jnp.where(jnp.asarray(water)[:, None, None],
-                         jax.nn.softplus(x), x)
-
-
-# ---------------------------------------------------------------------------
-# Served products (paper Appendix D, F.7)
-# ---------------------------------------------------------------------------
-
-def _spatial_mean(x, aw):
-    return jnp.einsum("...hw,hw->...", x, aw) / jnp.sum(aw)
-
-
-def scores(ens: jax.Array, truth: jax.Array | None, aw: jax.Array,
-           wpct: jax.Array | None) -> dict[str, jax.Array]:
-    """Per-channel products of one lead: fair CRPS, ensemble-mean RMSE,
-    spread, spread-skill ratio (with the sqrt((E+1)/E) correction) and,
-    with ``wpct``, the member-mean angular power spectrum (C, L)."""
-    out = {}
-    e = ens.shape[0]
-    if truth is not None:
-        err = jnp.mean(jnp.abs(ens - truth[None]), axis=0)
-        pair = jnp.mean(jnp.abs(ens[:, None] - ens[None, :]), axis=(0, 1))
-        out["crps"] = _spatial_mean(err - 0.5 * e / (e - 1.0) * pair, aw)
-        skill = jnp.sqrt(_spatial_mean((ens.mean(0) - truth) ** 2, aw))
-        spread = jnp.sqrt(_spatial_mean(jnp.var(ens, axis=0, ddof=1), aw))
-        out["ens_rmse"] = skill
-        out["spread"] = spread
-        out["ssr"] = jnp.sqrt((e + 1.0) / e) * spread / skill
-    if wpct is not None:
-        out["spectrum"] = jnp.mean(
-            shtlib.spectrum(shtlib.sht_forward(ens, wpct)), axis=0)
-    return out
-
-
 class Reference:
     """Rolls a served request's ensemble: the same initial condition,
     noise stream (``PRNGKey(seed)``, centered antithetic pairs, AR(1)
@@ -437,3 +341,38 @@ class Reference:
             truth = self._state(sample, n + 1, self.pct) if scored else None
             out.append(jax.device_get(self._scores(s, truth, self.aw, wpct)))
         return out
+
+
+# ---------------------------------------------------------------------------
+# The benchmark's weights
+# ---------------------------------------------------------------------------
+
+def weights_key(cfg_file: dict) -> tuple[str, dict]:
+    """The checkpoint's directory under the configuration's, and what its
+    manifest records of how the weights were made."""
+    scale = float(cfg_file["weights_layer_scale"])
+    return (f"seed{cfg_file['weights_seed']}_scale{scale}",
+            {"weights_seed": cfg_file["weights_seed"],
+             "weights_layer_scale": scale})
+
+
+def make_weights(reference: Reference, cfg_file: dict) -> dict:
+    """``init`` in one jitted call from the configuration's
+    ``weights_seed``, every block's layer scale set to
+    ``weights_layer_scale``, then calibrated (paper C.6) on sample 0 of
+    the synthetic data.  The init's layer scale (1e-3) leaves the blocks,
+    and with them the noise that spreads the ensemble, all but out of a
+    step's output; a trained model's blocks carry its dynamics, and only
+    then can a fault in the ensemble show in what is served."""
+    scale = float(cfg_file["weights_layer_scale"])
+    m = reference.model
+    with jax.default_matmul_precision("highest"):
+        params = jax.jit(m.init)(jax.random.PRNGKey(cfg_file["weights_seed"]))
+        for block in params["blocks"]:
+            block["layer_scale"] = jnp.full_like(block["layer_scale"], scale)
+        ds = reference.ds
+        cond = jnp.concatenate(
+            [jnp.asarray(ds.aux_fields(0.0))[None],
+             m.noise.to_grid(m.noise.init_state(jax.random.PRNGKey(1),
+                                                (1,)))], axis=1)[0]
+        return m.calibrate(params, reference.buffers, ds.state(0, 0), cond)

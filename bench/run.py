@@ -6,11 +6,14 @@
 
 A cell (``workloads`` in ``BENCHMARK.json``) names a configuration
 (``bench/configs/<config>.json``) and a traffic mix
-(``bench/traffic/<traffic>.json``).  The run, all in this one process:
+(``bench/traffic/<traffic>.json``).  The configuration names its model
+family, whose modules hold everything model-specific
+(``bench/family.py``).  The run, all in this one process:
 
-1. the weights, made once per checkout from the configuration's
-   ``weights_seed`` by ``bench.reference`` and kept as a checkpoint under
-   ``bench/.cache/`` (the benchmark's data, not set-up);
+1. the weights, made once per checkout from the configuration by the
+   family's reference (``bench/reference/<family>.py``) and kept as a
+   checkpoint under ``bench/.cache/`` (the benchmark's data, not
+   set-up);
 2. set-up (``setup_s``): the forecast service started with the
    launcher's own code (``repro.launch.service.build_service``) on the
    checkpoint, the cell's request shape warmed, and warm requests over
@@ -23,7 +26,7 @@ A cell (``workloads`` in ``BENCHMARK.json``) names a configuration
 4. peak device memory is read, the service is closed and every device
    array it held is freed;
 5. the check: a seeded sample of the window's requests is rolled again
-   by the float32 reference at "highest" and compared
+   by the family's float32 reference at "highest" and compared
    (``bench/check.py``, limits in ``bench/checks/<workload>.json``).
 
 The last line of stdout is one JSON object: ``correct``, ``attempted``,
@@ -105,38 +108,19 @@ def _flat(tree) -> dict:
 
 def weights(cfg: dict) -> str:
     """The configuration's weights as a checkpoint directory the
-    launcher's ``--ckpt`` restores, made once per checkout: ``init`` in
-    one jitted call from ``weights_seed``, every block's layer scale set
-    to ``weights_layer_scale``, then calibrated (paper C.6) on sample 0
-    of the synthetic data.  The init's layer scale (1e-3) leaves the
-    blocks, and with them the noise that spreads the ensemble, all but
-    out of a step's output; a trained model's blocks carry its dynamics,
-    and only then can a fault in the ensemble show in what is served."""
-    import jax
-    import jax.numpy as jnp
+    launcher's ``--ckpt`` restores, made once per checkout by the
+    family's ``make_weights`` and kept under the directory its
+    ``weights_key`` names."""
     import numpy as np
 
-    from bench.reference import model as ref
-
-    scale = float(cfg["weights_layer_scale"])
-    path = os.path.join(CACHE, "weights", cfg["name"],
-                        f"seed{cfg['weights_seed']}_scale{scale}",
-                        "ckpt_00000000")
+    from bench import family
+    ref = family.reference(cfg)
+    key, made_with = ref.weights_key(cfg)
+    path = os.path.join(CACHE, "weights", cfg["name"], key, "ckpt_00000000")
     if os.path.exists(os.path.join(path, "manifest.json")):
         return path
     reference = ref.Reference(ref.Config.from_dict(cfg["model"]))
-    m = reference.model
-    with jax.default_matmul_precision("highest"):
-        params = jax.jit(m.init)(jax.random.PRNGKey(cfg["weights_seed"]))
-        for block in params["blocks"]:
-            block["layer_scale"] = jnp.full_like(block["layer_scale"], scale)
-        ds = reference.ds
-        cond = jnp.concatenate(
-            [jnp.asarray(ds.aux_fields(0.0))[None],
-             m.noise.to_grid(m.noise.init_state(jax.random.PRNGKey(1),
-                                                (1,)))], axis=1)[0]
-        params = m.calibrate(params, reference.buffers, ds.state(0, 0),
-                             cond)
+    params = ref.make_weights(reference, cfg)
     flat = {f"params/{k}": np.asarray(v) for k, v in _flat(params).items()}
     tmp = path + ".tmp"
     shutil.rmtree(tmp, ignore_errors=True)
@@ -144,12 +128,10 @@ def weights(cfg: dict) -> str:
     np.savez(os.path.join(tmp, "arrays.npz"), **flat)
     with open(os.path.join(tmp, "manifest.json"), "w") as f:
         json.dump({"step": 0, "keys": sorted(flat), "shardings": {},
-                   "extra": {"config": cfg["name"],
-                             "weights_seed": cfg["weights_seed"],
-                             "weights_layer_scale": scale}}, f)
+                   "extra": {"config": cfg["name"], **made_with}}, f)
     shutil.rmtree(path, ignore_errors=True)
     os.replace(tmp, path)
-    del reference, params, cond
+    del reference, params
     free_device()
     return path
 
@@ -171,9 +153,10 @@ def read_params(path: str, reference) -> dict:
 # ---------------------------------------------------------------------------
 
 def check_config(cfg: dict) -> None:
-    """The service's named configuration must be the one in the file."""
-    from repro.configs import fcn3 as fcn3cfg
-    svc = fcn3cfg.NAMED_CONFIGS[cfg["named_config"]]()
+    """The service's named configuration (``repro.configs.<family>``)
+    must be the one in the file."""
+    from bench import family
+    svc = family.service_configs(cfg)[cfg["named_config"]]()
     diff = {k: (v, getattr(svc, k, None)) for k, v in cfg["model"].items()
             if getattr(svc, k, None) != v}
     if diff:
@@ -385,8 +368,10 @@ def teardown(st: dict, free: bool = True) -> None:
 
 
 def reference(cfg: dict, ckpt: str):
-    """The float32 reference of the configuration and its weights."""
-    from bench.reference import model as ref
+    """The family's float32 reference of the configuration and its
+    weights."""
+    from bench import family
+    ref = family.reference(cfg)
     r = ref.Reference(ref.Config.from_dict(cfg["model"]))
     return r, read_params(ckpt, r)
 
@@ -422,9 +407,9 @@ def run_cell(args, bench: dict, cell: dict, cfg: dict, traffic: dict,
              check: dict, device: dict, peak: dict) -> dict:
     import jax
 
-    from bench import check as checklib
-    from bench.counts import fcn3 as counts
+    from bench import check as checklib, family
 
+    counts = family.counts(cfg)
     st = setup(cfg, traffic)
     setup_s, ckpt = st["setup_s"], st["ckpt"]
     trace_dir = (os.path.join(CACHE, "trace", cell["name"]) if args.trace
@@ -439,7 +424,7 @@ def run_cell(args, bench: dict, cell: dict, cfg: dict, traffic: dict,
     run = {"records": records, "t0": t0, "t1": t1, "window_s": t1 - t0,
            "busy_to_s": summary["last_chunk"] - t0,
            "member_steps": summary["member_steps"], "model": cfg["model"],
-           "value_bytes": counts.VALUE_BYTES[
+           "counts": counts, "value_bytes": counts.VALUE_BYTES[
                cfg.get("request", {}).get("precision", "float32")],
            "peaks": peak, "device": device, "setup_s": setup_s,
            "summary": summary, "trace": None}

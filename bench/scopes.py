@@ -1,29 +1,26 @@
-"""Device time per named scope of the program, and the program's own
-host spans, from the ``jax.profiler`` trace of one measured window.
+"""Device time per named scope of the program, and the device's module
+executions, from the ``jax.profiler`` trace of one measured window.
 
-The program runs each FCN3 operator, and the noise and products of the
-engine's scan body, under a ``jax.named_scope`` (``SCOPES``, the
-program's ``repro.telemetry.SCOPES``).  On a TPU each op of the device
-plane's "XLA Ops" line has event metadata whose ``tf_op`` stat is the
-op's name stack (``jit(chunk)/while/body/.../fcn3.decoder/...``): its
-scope is the innermost name of ``SCOPES`` in that stack, and an op with
-none is unscoped.  ``jax.profiler.ProfileData`` does not show event
-metadata, so ``name_stacks`` reads it from the ``.xplane.pb`` protobuf
-itself (a few wire-format fields, with the standard library only).  An
-op name whose stacks give two different scopes (two programs with the
-same op text) is attributed to neither: it counts as unscoped and is
-listed by ``conflicts``.
-
-The program also writes its host spans into the trace
-(``jax.profiler.TraceAnnotation("repro:<span>")``), on the device
-trace's clock: ``label`` names an idle stretch by the innermost program
-span open in it, and falls back to the benchmark's own annotation.
+The program runs each operator of a model family, and the noise and
+products of the engine's scan body, under a ``jax.named_scope``
+(``SCOPES``: every family's ``bench/counts/<family>.py`` ``SCOPES`` and
+the engine's two, the program's ``repro.telemetry.SCOPES``).  On a TPU
+each op of the device plane's "XLA Ops" line has event metadata whose
+``tf_op`` stat is the op's name stack
+(``jit(chunk)/while/body/.../<family>.<operator>/...``): its scope is
+the innermost name of ``SCOPES`` in that stack, and an op with none is
+unscoped.  ``jax.profiler.ProfileData`` does not show event metadata,
+so ``name_stacks`` reads it from the ``.xplane.pb`` protobuf itself (a
+few wire-format fields, with the standard library only).  An op name
+whose stacks give two different scopes (two programs with the same op
+text) is attributed to neither: it counts as unscoped and is listed by
+``conflicts``.
 
     python3 -m bench.scopes bench/.cache/trace/full_forecast
 
 prints, for the newest trace under a directory, the device seconds per
 scope and the op families in each, the chunk program's executions and
-the idle gaps labelled by program spans.
+the idle gaps labelled by program spans (``tracereduce.label``).
 """
 
 from __future__ import annotations
@@ -36,13 +33,11 @@ import os
 import statistics
 import sys
 
-from bench import tracereduce
+from bench import family, tracereduce
 
-SCOPES = ("fcn3.encoder", "fcn3.local_conv", "fcn3.spectral_conv",
-          "fcn3.mlp", "fcn3.decoder", "engine.noise", "engine.products")
+#: the engine's scan body, which every family's chunk program runs
+ENGINE_SCOPES = ("engine.noise", "engine.products")
 UNSCOPED = "unscoped"
-#: prefix of the program's host spans
-PROGRAM = "repro:"
 #: the engine's chunk program on the device's "XLA Modules" line
 #: (``jax.jit`` of the engine's ``chunk`` function)
 CHUNK_MODULE = "jit_chunk"
@@ -141,9 +136,19 @@ def name_stacks(path: str, device_prefix: str = "/device:TPU",
 # Scopes
 # ---------------------------------------------------------------------------
 
+def vocabulary() -> tuple[str, ...]:
+    """Every family's ``SCOPES`` (``bench/counts/<family>.py``, families
+    by name), then the engine's, each name once."""
+    names = [s for c in family.all_counts() for s in c.SCOPES]
+    return tuple(dict.fromkeys(names + list(ENGINE_SCOPES)))
+
+
+SCOPES = vocabulary()
+
+
 def scope_of(stack: str) -> str | None:
     """The innermost name of ``SCOPES`` in a name stack; a component
-    may come wrapped by a transformation (``vmap(fcn3.mlp)``)."""
+    may come wrapped by a transformation (``vmap(<scope>)``)."""
     for part in reversed(stack.split("/")):
         part = part.rstrip(")").rsplit("(", 1)[-1]
         if part in SCOPES:
@@ -197,22 +202,21 @@ def scope_seconds(trace: tracereduce.Trace, stacks: dict[str, set[str]],
 @dataclasses.dataclass
 class Profile:
     """What the metrics read besides ``tracereduce.Trace``: op name
-    stacks, the device's module executions (name, start_ns, end_ns) and
-    the host annotations of program and benchmark, window excluded."""
+    stacks and the device's module executions (name, start_ns, end_ns),
+    with the window's bounds."""
 
     stacks: dict[str, set[str]]
     modules: list[tuple[str, float, float]]
-    notes: list[tuple[str, float, float]]
     lo: float
     hi: float
 
 
 def load(path: str, device_prefix: str = "/device:TPU") -> Profile:
     """Read one ``.xplane.pb``: module executions from the "XLA
-    Modules" line of the first device plane, ``repro:`` and ``bench:``
-    host annotations, and the op name stacks (``name_stacks``)."""
+    Modules" line of the first device plane, the window's annotation
+    and the op name stacks (``name_stacks``)."""
     from jax.profiler import ProfileData
-    modules, notes = [], []
+    modules, win = [], []
     for plane in ProfileData.from_file(path).planes:
         if plane.name.startswith(device_prefix) and not modules:
             for ln in plane.lines:
@@ -222,16 +226,13 @@ def load(path: str, device_prefix: str = "/device:TPU") -> Profile:
                                for ev in ln.events]
         if plane.name.startswith("/host"):
             for ln in plane.lines:
-                for ev in ln.events:
-                    if ev.name.startswith((PROGRAM, tracereduce.PREFIX)):
-                        notes.append((ev.name, ev.start_ns,
-                                      ev.start_ns + ev.duration_ns))
-    win = [n for n in notes if n[0] == tracereduce.WINDOW]
+                win.extend((ev.start_ns, ev.start_ns + ev.duration_ns)
+                           for ev in ln.events
+                           if ev.name == tracereduce.WINDOW)
     if not win:
         raise ValueError(f"{path}: no {tracereduce.WINDOW!r} annotation")
     return Profile(stacks=name_stacks(path, device_prefix), modules=modules,
-                   notes=[n for n in notes if n[0] != tracereduce.WINDOW],
-                   lo=win[0][1], hi=win[0][2])
+                   lo=win[0][0], hi=win[0][1])
 
 
 def module_runs(profile: Profile, module: str = CHUNK_MODULE,
@@ -244,25 +245,6 @@ def module_runs(profile: Profile, module: str = CHUNK_MODULE,
     return [(b - a) * 1e-9 for name, a, b in profile.modules
             if name.split("(", 1)[0] == module
             and profile.lo <= a and b <= hi]
-
-
-def label(notes, t: float) -> str:
-    """The innermost program span (``repro:``) open at ``t``; else the
-    innermost benchmark annotation (``bench:``); else
-    ``bench:unlabelled``."""
-    for prefix in (PROGRAM, tracereduce.PREFIX):
-        open_ = [(b - a, name) for name, a, b in notes
-                 if name.startswith(prefix) and a <= t < b]
-        if open_:
-            return min(open_)[1]
-    return "bench:unlabelled"
-
-
-def idle_gaps(trace: tracereduce.Trace, notes, top: int = 10) -> list[list]:
-    """The longest idle gaps as [label, seconds], longest first, each
-    labelled by ``label`` at its middle."""
-    gs = sorted(tracereduce.gaps(trace), key=lambda g: g[0] - g[1])[:top]
-    return [[label(notes, (a + b) / 2), (b - a) * 1e-9] for a, b in gs]
 
 
 # ---------------------------------------------------------------------------
@@ -365,7 +347,7 @@ def main(argv=None) -> int:
                       for s, f in families.items()},
         "conflicts": conflicts(profile.stacks)[:20],
         "chunk_runs_s": module_runs(profile),
-        "idle_gaps": idle_gaps(trace, profile.notes)}, indent=1))
+        "idle_gaps": tracereduce.idle_gaps(trace)}, indent=1))
     return 0
 
 

@@ -1,4 +1,5 @@
-"""Hand counts of the algorithm's operations and bytes at small shapes."""
+"""Hand counts of the algorithm's operations and bytes at small shapes,
+for the counts the smoke configuration's family gives."""
 
 import itertools
 import json
@@ -7,10 +8,13 @@ import os
 import numpy as np
 import pytest
 
-from bench.counts import fcn3 as counts
+from bench import family
 from bench.reference import grids as glib
 
 HERE = os.path.dirname(os.path.abspath(__file__))
+with open(os.path.join(HERE, "data", "fcn3_smoke.json")) as _f:
+    SMOKE = json.load(_f)
+counts = family.counts(SMOKE)
 
 
 def test_legendre_counts_the_lower_triangle_only():
@@ -57,8 +61,7 @@ def test_disco_flops_by_hand():
 
 
 def test_member_step_at_smoke_shapes():
-    with open(os.path.join(HERE, "data", "fcn3_smoke.json")) as f:
-        m = json.load(f)["model"]
+    m = SMOKE["model"]
     calls = counts.step_calls(m)
     # 2 blocks, one global every 2: one local DISCO block, one spectral
     assert [n for _w, n in calls["disco_kernel"]] == [1, 1, 1, 1, 2, 1]
@@ -73,8 +76,7 @@ def test_member_step_at_smoke_shapes():
 
 def test_bytes_move_at_the_served_width():
     """bfloat16 halves every byte and leaves the operations alone."""
-    with open(os.path.join(HERE, "data", "fcn3_smoke.json")) as f:
-        m = json.load(f)["model"]
+    m = SMOKE["model"]
     f32 = counts.member_step(m, counts.VALUE_BYTES["float32"])
     bf16 = counts.member_step(m, counts.VALUE_BYTES["bfloat16"])
     assert bf16.flops == f32.flops

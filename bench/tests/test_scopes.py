@@ -10,7 +10,7 @@ import time
 
 import pytest
 
-from bench import scopes
+from bench import family, scopes
 from bench import tracereduce as tr
 
 HERE = os.path.dirname(os.path.abspath(__file__))
@@ -89,22 +89,41 @@ def test_program_spans_label_the_gaps(recorded):
     _d, path, trace, _stacks = recorded
     profile = scopes.load(path, device_prefix="/host:CPU")
     assert (profile.lo, profile.hi) == (trace.lo, trace.hi)
-    names = {n for n, _a, _b in profile.notes}
+    names = {n for n, _a, _b in trace.annotations}
     assert names == {"bench:await_chunk", "repro:stage_h2d"}
-    labels = {g[0] for g in scopes.idle_gaps(trace, profile.notes)}
+    labels = {g[0] for g in tr.idle_gaps(trace)}
     assert "repro:stage_h2d" in labels
     assert "bench:await_chunk" not in labels   # the program span wins
     assert profile.stacks == {}                # no tf_op stat on the CPU
 
 
+def test_program_spans_change_the_labels_alone(recorded):
+    """The trace read with the program's spans and as it was read
+    without them: busy time, idle share and the gaps are the same to
+    the bit, and only the gaps' labels differ."""
+    from bench.metrics import device_idle_share
+    _d, _path, trace, _stacks = recorded
+    bench_only = tr.Trace(ops=trace.ops, lo=trace.lo, hi=trace.hi,
+                          annotations=[n for n in trace.annotations
+                                       if n[0].startswith(tr.PREFIX)])
+    assert len(bench_only.annotations) < len(trace.annotations)
+    assert tr.busy_s(trace) == tr.busy_s(bench_only)
+    assert device_idle_share.read({"trace": trace}) == \
+        device_idle_share.read({"trace": bench_only})
+    gaps, before = tr.idle_gaps(trace), tr.idle_gaps(bench_only)
+    assert [g[1] for g in gaps] == [g[1] for g in before]
+    assert [g[0] for g in gaps] != [g[0] for g in before]
+
+
 def test_label_prefers_an_open_program_span():
-    notes = [("bench:await_chunk", 0, 100), ("repro:dispatch", 10, 60),
-             ("repro:stage_h2d", 20, 30), ("bench:inner", 70, 80)]
-    assert scopes.label(notes, 25) == "repro:stage_h2d"
-    assert scopes.label(notes, 40) == "repro:dispatch"
-    assert scopes.label(notes, 75) == "bench:inner"
-    assert scopes.label(notes, 90) == "bench:await_chunk"
-    assert scopes.label(notes, 200) == "bench:unlabelled"
+    trace = tr.Trace(ops={}, lo=0, hi=300, annotations=[
+        ("bench:await_chunk", 0, 100), ("repro:dispatch", 10, 60),
+        ("repro:stage_h2d", 20, 30), ("bench:inner", 70, 80)])
+    assert tr.label(trace, 25) == "repro:stage_h2d"
+    assert tr.label(trace, 40) == "repro:dispatch"
+    assert tr.label(trace, 75) == "bench:inner"
+    assert tr.label(trace, 90) == "bench:await_chunk"
+    assert tr.label(trace, 200) == "bench:unlabelled"
 
 
 def test_for_run_finds_the_runs_trace(recorded, monkeypatch):
@@ -196,7 +215,7 @@ def test_scope_of_takes_the_innermost_vocabulary_name():
 def test_the_vocabulary_is_the_programs():
     from repro import telemetry
     assert scopes.SCOPES == telemetry.SCOPES
-    assert scopes.PROGRAM == telemetry.ANNOTATION_PREFIX
+    assert tr.PROGRAM == telemetry.ANNOTATION_PREFIX
 
 
 # ---------------------------------------------------------------------------
@@ -211,7 +230,7 @@ def _run(profile=None) -> dict:
     Legendre call; a gap; a DISCO call and a copy; a gap to the end; 6
     member-steps, the last completion at 90 s."""
     with open(os.path.join(HERE, "data", "fcn3_smoke.json")) as f:
-        model = json.load(f)["model"]
+        cfg = json.load(f)
     trace = tr.Trace(ops={"/device:TPU:0": [
         ("%while.9 = (s32[]) while(...)", 0, 45e9),
         (DISCO.format(1), 1e9, 31e9),
@@ -226,7 +245,8 @@ def _run(profile=None) -> dict:
                                           (90, 31.5)])]
     return {"records": [{"events": events}], "t0": t0, "t1": t0 + 100,
             "window_s": 100.0, "busy_to_s": 90.0, "member_steps": 6,
-            "model": model, "value_bytes": 2,
+            "model": cfg["model"], "counts": family.counts(cfg),
+            "value_bytes": 2,
             "peaks": {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9},
             "device": {"memory_peak_bytes": 12_506_000_000},
             "trace": trace}
@@ -268,7 +288,7 @@ def test_scope_readers(monkeypatch):
                  ("jit_other(8)", 46e9, 47e9),
                  ("jit_chunk(7)", 87e9, 130e9),
                  ("jit_chunk(7)", 91e9, 99e9)],
-        notes=[], lo=0.0, hi=100e9)
+        lo=0.0, hi=100e9)
     monkeypatch.setattr(scopes, "for_run", lambda r: profile)
 
     def read(name):

@@ -4,9 +4,11 @@ Device operations are the events of the device planes' "XLA Ops" lines
 (on a TPU: ``/device:TPU:<n>``).  Busy time is the union of their
 intervals inside the window, so overlapping operations count once; an
 idle gap is a stretch of the window with no operation on the device,
-labelled by the benchmark's own host annotation (``bench:...``) that was
-open at its middle.  The window itself is the ``bench:window``
-annotation.  Only ``jax`` is needed to read the trace.
+labelled by what the host was doing at its middle: the innermost of the
+program's host spans (``repro:...``, on the profiler's clock) open
+there, else the benchmark's own annotation (``bench:...``).  The window
+itself is the ``bench:window`` annotation.  Only ``jax`` is needed to
+read the trace.
 """
 
 from __future__ import annotations
@@ -17,12 +19,15 @@ import os
 
 WINDOW = "bench:window"
 PREFIX = "bench:"
+#: prefix of the program's host spans
+PROGRAM = "repro:"
 
 
 @dataclasses.dataclass
 class Trace:
-    """Device ops per device ((name, start_ns, end_ns) each), the
-    benchmark's host annotations and the window's bounds, in ns."""
+    """Device ops per device ((name, start_ns, end_ns) each), the host
+    annotations of program and benchmark and the window's bounds, in
+    ns."""
 
     ops: dict[str, list[tuple[str, float, float]]]
     annotations: list[tuple[str, float, float]]
@@ -73,7 +78,7 @@ def read(path: str, device_prefix: str = "/device:TPU") -> Trace:
         if plane.name.startswith("/host"):
             for ln in lines:
                 for ev in ln.events:
-                    if ev.name.startswith(PREFIX):
+                    if ev.name.startswith((PROGRAM, PREFIX)):
                         notes.append((ev.name, ev.start_ns,
                                       ev.start_ns + ev.duration_ns))
     win = [n for n in notes if n[0] == WINDOW]
@@ -123,13 +128,20 @@ def gaps(trace: Trace) -> list[tuple[float, float]]:
 
 
 def label(trace: Trace, t: float) -> str:
-    """The innermost benchmark annotation open at ``t``."""
-    open_ = [(b - a, name) for name, a, b in trace.annotations if a <= t < b]
-    return min(open_)[1] if open_ else "bench:unlabelled"
+    """The innermost program span (``repro:``) open at ``t``; else the
+    innermost benchmark annotation (``bench:``); else
+    ``bench:unlabelled``."""
+    for prefix in (PROGRAM, PREFIX):
+        open_ = [(b - a, name) for name, a, b in trace.annotations
+                 if name.startswith(prefix) and a <= t < b]
+        if open_:
+            return min(open_)[1]
+    return "bench:unlabelled"
 
 
 def idle_gaps(trace: Trace, top: int = 10) -> list[list]:
-    """The longest idle gaps as [label, seconds], longest first."""
+    """The longest idle gaps as [label, seconds], longest first, each
+    labelled by ``label`` at its middle."""
     gs = sorted(gaps(trace), key=lambda g: g[0] - g[1])[:top]
     return [[label(trace, (a + b) / 2), (b - a) * 1e-9] for a, b in gs]
 
