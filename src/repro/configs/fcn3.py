@@ -11,7 +11,7 @@ import dataclasses
 
 import numpy as np
 
-from repro.core.fcn3 import FCN3Config
+from repro.core.fcn3 import FCN3, FCN3Config
 
 PRESSURE_LEVELS = (50, 100, 150, 200, 250, 300, 400, 500, 600, 700, 850, 925,
                    1000)  # hPa, 13 levels
@@ -95,3 +95,6 @@ NAMED_CONFIGS = {
     "small": fcn3_small,
     "full": fcn3_full,
 }
+
+#: the family's model class (``repro.configs.registry``)
+MODEL = FCN3

@@ -103,6 +103,12 @@ class FCN3Config:
         tcwv = np.array([self.n_levels * self.n_atmos + 6])
         return np.concatenate([q, tcwv])
 
+    def legendre_table_bytes(self) -> int:
+        """float32 bytes of the Legendre tables in the model's buffers
+        (the latent grid's two)."""
+        lmax = self.latent_nlat
+        return 2 * 4 * lmax * lmax * min(lmax, self.latent_nlon // 2 + 1)
+
     def block_specs(self) -> list[blk.BlockSpec]:
         n_basis = len(discolib.morlet_basis_spec(self.filter_ell_max,
                                                  self.filter_m_max))
@@ -149,6 +155,28 @@ class FCN3:
                                                           self.grid_in)
         self.noise = noiselib.SphericalDiffusion(sht=self.in_sht)
         self.n_basis = self.enc_plan.n_basis
+
+    @property
+    def disco_plans(self) -> tuple:
+        """The encoder, latent and decoder DISCO plans."""
+        return (self.enc_plan, self.latent_plan, self.dec_plan)
+
+    def member_activation_bytes(self, itemsize: int) -> int:
+        """One member's latent activation, the size that decides whether
+        the engine steps members side by side."""
+        cfg = self.cfg
+        return cfg.c_latent * cfg.latent_nlat * cfg.latent_nlon * itemsize
+
+    def default_params(self, ds, buffers: dict, state0: jax.Array) -> dict:
+        """Params of a service started without a checkpoint: the
+        calibrated init on ``state0`` with fixed keys (PRNGKey(0)
+        calibration, PRNGKey(1) noise sample), so the same (config,
+        state0) always yields the same params."""
+        cond0 = jnp.concatenate(
+            [jnp.asarray(ds.aux_fields(0.0))[None],
+             self.sample_noise(jax.random.PRNGKey(1), (1,))], axis=1)
+        return self.init_calibrated(jax.random.PRNGKey(0), state0[None],
+                                    cond0, buffers)
 
     # ------------------------------------------------------------------
     def make_buffers(self) -> dict:

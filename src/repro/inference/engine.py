@@ -76,14 +76,13 @@ import contextlib
 import dataclasses
 import threading
 from concurrent.futures import Future, ThreadPoolExecutor
-from typing import Any, Callable, Iterator
+from typing import Any, Callable, Iterator, Protocol
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro import telemetry
-from repro.core.fcn3 import FCN3
 from repro.core.sphere import noise as noiselib
 from repro.evaluation import metrics
 from repro.inference import perturbations as perturblib
@@ -98,9 +97,51 @@ SCORE_NAMES = ("crps", "ens_rmse", "spread", "ssr", "rank_hist",
                "spectrum", "spectrum_truth")
 
 
-#: one member's latent activation (bytes) above which the engine steps
-#: ensemble members sequentially (see ``ForecastEngine._members_in_sequence``)
+#: one member's activation (bytes, ``member_activation_bytes``) above
+#: which the engine steps ensemble members sequentially (see
+#: ``ForecastEngine._members_in_sequence``)
 SEQUENTIAL_MEMBER_BYTES = 2**28
+
+
+class ForecastModel(Protocol):
+    """What the engine asks of a model family (``repro.core.fcn3.FCN3``,
+    ``repro.core.sfno.SFNO``; ``repro.configs.registry`` builds them).
+
+    * ``apply(params, buffers, state, cond)``: one step of one member,
+      (C, H, W) -> (C, H, W); ``cond`` is the member's auxiliary and
+      noise fields, or None for a model without a noise process.
+    * ``noise``: the carry init and transition of the noise process
+      (``repro.core.sphere.noise.SphericalDiffusion``), or None for a
+      deterministic model, which then carries no noise and is given no
+      conditioning.
+    * ``in_sht``: the input grid's transform; the spectra product takes
+      its forward table.
+    * ``member_activation_bytes(itemsize)``: one member's activation,
+      which decides whether members step side by side.
+    * ``default_params(ds, buffers, state0)``: the params of a service
+      started without a checkpoint (``repro.inference.params``).
+
+    Besides: ``cfg`` (``n_state``, ``kernels``; ``n_aux`` and ``n_noise``
+    with a noise process), ``grid_in``, ``init``, ``make_buffers`` and
+    ``disco_plans`` (the DISCO plans a warm-start bundle exports).
+    """
+
+    cfg: Any
+    grid_in: Any
+    in_sht: Any
+    noise: Any
+    disco_plans: tuple
+
+    def apply(self, params, buffers, state: jax.Array,
+              cond: jax.Array | None) -> jax.Array: ...
+
+    def member_activation_bytes(self, itemsize: int) -> int: ...
+
+    def default_params(self, ds, buffers, state0: jax.Array) -> dict: ...
+
+    def init(self, key: jax.Array) -> dict: ...
+
+    def make_buffers(self) -> dict: ...
 
 
 def in_scan_rank_histogram(ens: jax.Array, target: jax.Array,
@@ -163,7 +204,7 @@ class EngineConfig:
                     and lead, so opt-in.
     kernels:        kernel substrate for the model's hot contractions
                     (``repro.kernels.config.KernelConfig``).  ``None``
-                    inherits the model's own ``FCN3Config.kernels``;
+                    inherits the model's own ``cfg.kernels``;
                     an explicit config makes the engine rebuild its
                     model view (and its buffer layout) around that
                     substrate.  Part of the engine identity, so the
@@ -299,7 +340,8 @@ class _ChunkStager:
 
 
 class ForecastEngine:
-    """Compiled autoregressive ensemble forecaster for an FCN3 model.
+    """Compiled autoregressive ensemble forecaster for a
+    ``ForecastModel`` (FCN3, or the deterministic SFNO).
 
     Typical use::
 
@@ -312,7 +354,7 @@ class ForecastEngine:
     chunk at a time.
     """
 
-    def __init__(self, model: FCN3, cfg: EngineConfig,
+    def __init__(self, model: ForecastModel, cfg: EngineConfig,
                  diagnostics: Callable[[jax.Array], Any] | None = None,
                  perturbation: perturblib.InitialConditionPerturbation
                  | None = None):
@@ -321,13 +363,18 @@ class ForecastEngine:
         # by grid, so this costs a config object, not a rebuild of the
         # static geometry).
         if cfg.kernels is not None and cfg.kernels != model.cfg.kernels:
-            model = FCN3(dataclasses.replace(model.cfg, kernels=cfg.kernels))
+            model = type(model)(dataclasses.replace(model.cfg,
+                                                    kernels=cfg.kernels))
         self.model = model
         self.cfg = cfg
         self.diagnostics = diagnostics
-        self.noise_buffers = model.noise.buffers()
+        #: the noise process; None for a deterministic model, whose
+        #: rollout carries no noise and stages no conditioning
+        self.noise = model.noise
+        self.noise_buffers = ({} if self.noise is None
+                              else self.noise.buffers())
         if cfg.spectra:
-            # spectra run a forward SHT on the IO grid the noise shares
+            # spectra run a forward SHT on the IO grid (the noise's)
             self.noise_buffers["wpct"] = model.in_sht.table("wpct")
         self.area_weights = jnp.asarray(model.grid_in.area_weights_2d(),
                                         jnp.float32)
@@ -379,21 +426,21 @@ class ForecastEngine:
     @property
     def _members_in_sequence(self) -> bool:
         """Step the members one after another instead of side by side
-        (``vmap``) when one member's latent activation passes
+        (``vmap``) when one member's activation
+        (``model.member_activation_bytes``) passes
         ``SEQUENTIAL_MEMBER_BYTES``: at 721x1440 the step's transient
         activations then scale with one member, not E, which is what
         lets an ensemble fit one chip.  The sequence is unrolled, not a
         loop: out of a loop over members XLA hoists the rounded copies
         of loop-invariant weights and tables and holds all of them for
         the whole loop (2 GB at full width, compile rehearsal)."""
-        mc = self.model.cfg
-        latent = (mc.c_latent * mc.latent_nlat * mc.latent_nlon
-                  * self.cfg.jdtype.itemsize)
-        return latent > SEQUENTIAL_MEMBER_BYTES
+        return (self.model.member_activation_bytes(self.cfg.jdtype.itemsize)
+                > SEQUENTIAL_MEMBER_BYTES)
 
     def _step_members(self, params, buffers, s: jax.Array,
                       cond: jax.Array) -> jax.Array:
-        """One model step of every member: (E, C, H, W) -> (E, C, H, W).
+        """One model step of every member: (E, C, H, W) -> (E, C, H, W);
+        ``cond`` is (E, ...) or None (a model without conditioning).
 
         With ``member_axes`` the step runs under ``shard_map`` over those
         mesh axes -- each device steps its own members with replicated
@@ -403,8 +450,10 @@ class ForecastEngine:
 
         def step(params, buffers, s, cond):
             if self._members_in_sequence:
-                return jnp.stack([m.apply(params, buffers, s[i], cond[i])
-                                  for i in range(s.shape[0])])
+                return jnp.stack([
+                    m.apply(params, buffers, s[i],
+                            None if cond is None else cond[i])
+                    for i in range(s.shape[0])])
             return jax.vmap(
                 lambda se, ce: m.apply(params, buffers, se, ce))(s, cond)
 
@@ -438,15 +487,18 @@ class ForecastEngine:
         device inside a compiled program (obs-error sampling needs nothing
         extra; bred vectors additionally need ``params``/``buffers`` and
         ``aux0``, the frozen conditioning fields the breeding rollouts run
-        under).  The perturbation key stream is salted away from the noise
-        process, so kind="none" stays bit-identical to the unperturbed
-        engine.
+        under, where the model takes conditioning).  A deterministic
+        model's carry holds no noise (None).  The perturbation key
+        stream is salted away from the noise process, so kind="none"
+        stays bit-identical to the unperturbed engine.
         """
         e = self.cfg.members
-        z_hat = self.model.noise.init_state(key, (e,), self.noise_buffers)
+        z_hat = (None if self.noise is None else
+                 self.noise.init_state(key, (e,), self.noise_buffers))
         if self._perturb_cfg.active:
             if self._perturb_cfg.kind == "bred" and (
-                    params is None or buffers is None or aux0 is None):
+                    params is None or buffers is None
+                    or (aux0 is None and self.noise is not None)):
                 raise ValueError(
                     "bred perturbations need params=, buffers= and aux0=")
             s = self._get_init_fn()(state0, key, params, buffers, aux0)
@@ -490,7 +542,7 @@ class ForecastEngine:
             def bred_init(params, buffers, state0, aux0, key, pb):
                 # Breeding runs the deterministic control dynamics: frozen
                 # aux conditioning, zero noise channels, fp32 carries.
-                cond = jnp.concatenate(
+                cond = None if aux0 is None else jnp.concatenate(
                     [aux0, jnp.zeros((m.cfg.n_noise,) + state0.shape[-2:],
                                      aux0.dtype)], axis=0)
 
@@ -510,7 +562,7 @@ class ForecastEngine:
     def noise_fields(self, z_hat: jax.Array) -> jax.Array:
         """Grid-space conditioning noise exactly as the scan body sees it
         (antithetically centered when the engine is configured so)."""
-        z = self.model.noise.to_grid(z_hat, self.noise_buffers)
+        z = self.noise.to_grid(z_hat, self.noise_buffers)
         if self.cfg.centered:
             z = noiselib.center_noise(z, axis=0)
         return z
@@ -550,30 +602,34 @@ class ForecastEngine:
         """Scan body shared by both chunk calling conventions; the
         noise field and transition, and the in-scan products, run under
         the named scopes ``telemetry.SCOPE_NOISE`` and
-        ``telemetry.SCOPE_PRODUCTS``."""
-        m, c = self.model, self.cfg
+        ``telemetry.SCOPE_PRODUCTS``.  A deterministic model (no noise
+        process) carries ``z_hat`` None and steps without conditioning."""
+        c, noise = self.cfg, self.noise
         e, dt = c.members, c.jdtype
         diag = self.diagnostics
         score_fns = self._score_fns(scored, nbufs, aw)
 
         def body(carry, x):
             s, z_hat = carry
-            with jax.named_scope(telemetry.SCOPE_NOISE):
-                z = m.noise.to_grid(z_hat, nbufs)
-                if c.centered:
-                    z = noiselib.center_noise(z, axis=0)
-            cond = jnp.concatenate(
-                [jnp.broadcast_to(x["aux"], (e,) + x["aux"].shape), z],
-                axis=1)
-            cond = self._constrain(cond.astype(dt))
+            cond = None
+            if noise is not None:
+                with jax.named_scope(telemetry.SCOPE_NOISE):
+                    z = noise.to_grid(z_hat, nbufs)
+                    if c.centered:
+                        z = noiselib.center_noise(z, axis=0)
+                cond = jnp.concatenate(
+                    [jnp.broadcast_to(x["aux"], (e,) + x["aux"].shape), z],
+                    axis=1)
+                cond = self._constrain(cond.astype(dt))
             # The spectral path promotes to fp32 through the FFT; pin the
             # carry back to the compute dtype so the scan carry
             # shape/dtype is invariant (no-op in fp32).
             s = self._constrain(
                 self._step_members(params, buffers, s, cond).astype(dt))
-            with jax.named_scope(telemetry.SCOPE_NOISE):
-                z_hat = m.noise.step(jax.random.fold_in(key, x["n"]),
-                                     z_hat, nbufs)
+            if noise is not None:
+                with jax.named_scope(telemetry.SCOPE_NOISE):
+                    z_hat = noise.step(jax.random.fold_in(key, x["n"]),
+                                       z_hat, nbufs)
             with jax.named_scope(telemetry.SCOPE_PRODUCTS):
                 sf = s.astype(jnp.float32)
                 out = {name: fn(sf, x) for name, fn in score_fns.items()}
@@ -754,6 +810,8 @@ class ForecastEngine:
         is identity-cached per incoming object, like the precision
         casts.
         """
+        if not self.model.disco_plans:
+            return buffers      # no DISCO geometry to lay out
         disco_bufs = buffers.get("enc") or buffers.get("latent") or {}
         want = self.model.cfg.kernels.resolve("disco")[0] == "pallas"
         if ("psi_band" in disco_bufs) == want:
@@ -809,14 +867,16 @@ class ForecastEngine:
         lead = () if batch is None else (batch,)
         s_av = jax.ShapeDtypeStruct(
             lead + (cfg.members, m.cfg.n_state, h, w), cfg.jdtype)
-        z_av = jax.ShapeDtypeStruct(
-            lead + (cfg.members, m.noise.n_proc, m.in_sht.lmax,
-                    m.in_sht.mmax), jnp.complex64)
         k0 = jax.random.PRNGKey(0)
         key_av = jax.ShapeDtypeStruct(lead + k0.shape, k0.dtype)
-        xs_av = {"n": jax.ShapeDtypeStruct((k,), jnp.int32),
-                 "aux": jax.ShapeDtypeStruct(
-                     lead + (k, m.cfg.n_aux, h, w), jnp.float32)}
+        xs_av = {"n": jax.ShapeDtypeStruct((k,), jnp.int32)}
+        z_av = None
+        if self.noise is not None:
+            z_av = jax.ShapeDtypeStruct(
+                lead + (cfg.members, self.noise.n_proc, m.in_sht.lmax,
+                        m.in_sht.mmax), jnp.complex64)
+            xs_av["aux"] = jax.ShapeDtypeStruct(
+                lead + (k, m.cfg.n_aux, h, w), jnp.float32)
         if scored:
             xs_av["truth"] = jax.ShapeDtypeStruct(
                 lead + (k, m.cfg.n_state, h, w), jnp.float32)
@@ -928,10 +988,12 @@ class ForecastEngine:
                 b = batch or 1
                 state = (b * cfg.members * m.cfg.n_state * h * w
                          * cfg.jdtype.itemsize)
-                noise = (b * cfg.members * m.noise.n_proc * m.in_sht.lmax
-                         * m.in_sht.mmax * 8)
-                xs = (b * k * (m.cfg.n_aux
-                               + (m.cfg.n_state if scored else 0))
+                noise = n_aux = 0
+                if self.noise is not None:
+                    noise = (b * cfg.members * self.noise.n_proc
+                             * m.in_sht.lmax * m.in_sht.mmax * 8)
+                    n_aux = m.cfg.n_aux
+                xs = (b * k * (n_aux + (m.cfg.n_state if scored else 0))
                       * h * w * 4)
                 est = 2 * (state + noise) + xs
                 if baked:
@@ -943,11 +1005,12 @@ class ForecastEngine:
         """Serializable geometry-plan payloads for warm-start bundles.
 
         One payload per distinct precomputed plan this engine's model
-        dispatches: the three DISCO plans (encoder, latent, decoder --
-        deduplicated by ``DiscoPlan.plan_key``, the 9-tuple grid +
-        filter-hyperparameter identity) and the Legendre tables of the
-        IO and latent SHTs (keyed (lmax, mmax, colat)).  A fresh replica
-        installs these via ``repro.core.sphere.disco.install_plan`` /
+        dispatches: its DISCO plans (FCN3's encoder, latent and decoder
+        plans -- deduplicated by ``DiscoPlan.plan_key``, the 9-tuple
+        grid + filter-hyperparameter identity) and the Legendre tables
+        of the IO and latent SHTs (keyed (lmax, mmax, colat)).  A fresh
+        replica installs these via
+        ``repro.core.sphere.disco.install_plan`` /
         ``legendre.install_legendre_table`` and skips the psi-tensor and
         Legendre-recurrence construction entirely (seconds at smoke
         scale, minutes at 721x1440).  Payloads are plain scalars + numpy
@@ -958,7 +1021,7 @@ class ForecastEngine:
         m = self.model
         payloads: list[dict] = []
         seen: set = set()
-        for plan in (m.enc_plan, m.latent_plan, m.dec_plan):
+        for plan in m.disco_plans:
             key = ("disco",) + plan.plan_key()
             if key in seen:
                 continue
@@ -1037,8 +1100,9 @@ class ForecastEngine:
 
         def stage(start: int, k: int) -> dict:
             with span("stage_h2d", {"start": start, "steps": k}):
-                xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32),
-                      "aux": self._stage(aux, start, k)}
+                xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32)}
+                if self.noise is not None:
+                    xs["aux"] = self._stage(aux, start, k)
                 if scored:
                     xs["truth"] = self._stage(truth, start, k)
                 self._count_staged(k)
@@ -1051,7 +1115,8 @@ class ForecastEngine:
             # -- taken from the already-staged first chunk, never a
             # second H2D copy of step 0.
             aux0 = (jnp.asarray(stager.peek(0)["aux"][0], jnp.float32)
-                    if self._perturb_cfg.kind == "bred" else None)
+                    if self._perturb_cfg.kind == "bred"
+                    and self.noise is not None else None)
             s, z_hat = self.init_carry(jnp.asarray(state0), key,
                                        params=params, buffers=buffers,
                                        aux0=aux0)
@@ -1157,8 +1222,9 @@ class ForecastEngine:
 
             with span("stage_h2d", {"start": start, "steps": k,
                                     "batch": b}):
-                xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32),
-                      "aux": jnp.stack([once(a) for a in auxs])}
+                xs = {"n": jnp.arange(start, start + k, dtype=jnp.int32)}
+                if self.noise is not None:
+                    xs["aux"] = jnp.stack([once(a) for a in auxs])
                 if scored:
                     xs["truth"] = jnp.stack([once(t) for t in truths])
                 self._count_staged(k * len({id(a) for a in auxs}))
@@ -1167,7 +1233,7 @@ class ForecastEngine:
         stager = _ChunkStager(bounds, stage)
         try:
             aux0s = [None] * b
-            if self._perturb_cfg.kind == "bred":
+            if self._perturb_cfg.kind == "bred" and self.noise is not None:
                 xs0 = stager.peek(0)
                 aux0s = [jnp.asarray(xs0["aux"][i, 0], jnp.float32)
                          for i in range(b)]
@@ -1179,7 +1245,9 @@ class ForecastEngine:
                                        buffers=buffers, aux0=a0)
                        for s0, k_i, a0 in zip(state0s, keys, aux0s)]
             s = jnp.stack([c[0] for c in carries])
-            z_hat = jnp.stack([c[1] for c in carries])
+            # None for a deterministic model (no noise carry)
+            z_hat = jax.tree.map(lambda *zs: jnp.stack(zs),
+                                 *[c[1] for c in carries])
             key_b = jnp.stack([jnp.asarray(k_i) for k_i in keys])
             diag = self.diagnostics
             # original request indices the rollout still carries, in
@@ -1199,13 +1267,13 @@ class ForecastEngine:
                                 batch=nb) for kk in rem):
                             pos = [active.index(j) for j in alive]
                             if nb is None:
-                                s, z_hat = s[pos[0]], z_hat[pos[0]]
-                                key_b = key_b[pos[0]]
+                                s, z_hat, key_b = jax.tree.map(
+                                    lambda a: a[pos[0]], (s, z_hat, key_b))
                                 serial = True
                             else:
                                 idx = jnp.asarray(pos)
-                                s, z_hat = s[idx], z_hat[idx]
-                                key_b = key_b[idx]
+                                s, z_hat, key_b = jax.tree.map(
+                                    lambda a: a[idx], (s, z_hat, key_b))
                             fn = self._get_chunk_fn(
                                 scored, orig_buffers,
                                 (buffers if self.cfg.static_buffers
@@ -1237,7 +1305,8 @@ class ForecastEngine:
                         diagnostics=(jax.tree.map(pick, out["diag"])
                                      if diag is not None else None),
                         final_state=pick(s) if last else None,
-                        final_noise=pick(z_hat) if last else None)
+                        final_noise=(jax.tree.map(pick, z_hat) if last
+                                     else None))
                 yield block
         finally:
             stager.close()

@@ -8,24 +8,16 @@ them stay bit-identical by construction.
 from __future__ import annotations
 
 import jax
-import jax.numpy as jnp
 
 
 def load_params(model, ds, buffers, state0, ckpt: str | None = None):
-    """Checkpoint restore, or deterministic calibrated init.
-
-    Without a checkpoint: LSUV-style calibrated init on ``state0`` with
-    fixed keys (PRNGKey(0) calibration, PRNGKey(1) noise sample), so the
-    same (config, state0) always yields the same params.
-    """
+    """Checkpoint restore, or the model family's own params for a
+    service started without one (``model.default_params``: FCN3's
+    calibrated init, SFNO's plain seeded init)."""
     if ckpt:
         from repro.train import checkpoint as ckptlib
         template = {"params": jax.eval_shape(model.init,
                                              jax.random.PRNGKey(0))}
         restored, _ = ckptlib.restore_checkpoint(ckpt, template)
         return restored["params"]
-    cond0 = jnp.concatenate(
-        [jnp.asarray(ds.aux_fields(0.0))[None],
-         model.sample_noise(jax.random.PRNGKey(1), (1,))], axis=1)
-    return model.init_calibrated(jax.random.PRNGKey(0), state0[None],
-                                 cond0, buffers)
+    return model.default_params(ds, buffers, state0)

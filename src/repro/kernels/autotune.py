@@ -510,34 +510,37 @@ def resolve_kernel_config(kernels: KernelConfig | None
 # ---------------------------------------------------------------------------
 
 def model_op_shapes(model, members: int = 2) -> dict:
-    """Concrete tuning shapes for a live ``FCN3``'s hot ops, as a list
-    of shapes per op family.
+    """Concrete tuning shapes for a live model's hot ops (FCN3 or SFNO),
+    as a list of shapes per op family.
 
     legendre: the latent-grid SHT slab batched over ``members`` member
-    channels (the spectral-convolution hot spot); disco: the local
-    blocks' latent-plan contraction with its channel mix, one shape per
-    distinct row run (``DiscoPlan.band_runs``: the rows, taps and input
-    rows of each kernel call dispatch makes); crps: the pointwise score
-    over the full state.  ``TuningCache.best_for`` serves the largest
-    tuned slab, so the dominant run's winner rides serving.
+    channels (the spectral-convolution hot spot); disco (FCN3's): the
+    local blocks' latent-plan contraction with its channel mix, one
+    shape per distinct row run (``DiscoPlan.band_runs``: the rows, taps
+    and input rows of each kernel call dispatch makes); crps: the
+    pointwise score over the full state.  ``TuningCache.best_for``
+    serves the largest tuned slab, so the dominant run's winner rides
+    serving.
     """
     from repro.kernels.dispatch import band_run_rows
     cfg = model.cfg
     m, h, l = model.latent_sht.buffer_specs()["wpct"].shape
-    plan = model.latent_plan
-    k, _, s, w_in = plan.psi.shape
-    c_in = cfg.c_latent + cfg.cond_embed
-    disco = []
-    for h0, h1, d in plan.band_runs:
-        r0, r1, _ = band_run_rows(h0, h1, s, plan.affine,
-                                  model.grid_latent.nlat)
-        shape = (members, c_in, r1 - r0, h1 - h0, s, w_in, k, d,
-                 cfg.c_latent, plan.stride)
-        if shape not in disco:
-            disco.append(shape)
-    return {"legendre": [(members * cfg.c_latent, h, l, m)],
-            "disco": disco,
-            "crps": [(members, cfg.n_state * cfg.nlat * cfg.nlon)]}
+    out = {"legendre": [(members * cfg.c_latent, h, l, m)]}
+    if model.disco_plans:
+        plan = model.latent_plan
+        k, _, s, w_in = plan.psi.shape
+        c_in = cfg.c_latent + cfg.cond_embed
+        disco = []
+        for h0, h1, d in plan.band_runs:
+            r0, r1, _ = band_run_rows(h0, h1, s, plan.affine,
+                                      model.grid_latent.nlat)
+            shape = (members, c_in, r1 - r0, h1 - h0, s, w_in, k, d,
+                     cfg.c_latent, plan.stride)
+            if shape not in disco:
+                disco.append(shape)
+        out["disco"] = disco
+    out["crps"] = [(members, cfg.n_state * cfg.nlat * cfg.nlon)]
+    return out
 
 
 def op_flops_bytes(op: str, shapes) -> tuple[float, float]:

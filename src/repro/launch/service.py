@@ -42,7 +42,7 @@ import argparse
 import json
 import logging
 
-from repro.configs import fcn3 as fcn3cfg
+from repro.configs import registry
 
 _log = logging.getLogger("repro.launch.service")
 
@@ -54,7 +54,7 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--port", type=int, default=8771,
                     help="0 picks an ephemeral port (printed at startup)")
     ap.add_argument("--config", nargs="+", default=["smoke"],
-                    choices=sorted(fcn3cfg.NAMED_CONFIGS),
+                    choices=sorted(registry.named_configs()),
                     help="configs to preload (model + params built at "
                          "startup, not on first request)")
     ap.add_argument("--ckpt", default=None,
@@ -274,7 +274,8 @@ def build_service(args: argparse.Namespace):
         startup["preload"][name] = built.build_s
         kernels = built.model.cfg.kernels.effective()
         band = (built.model.disco_band_report()
-                if kernels["disco"].startswith("pallas") else None)
+                if kernels["disco"].startswith("pallas")
+                and built.model.disco_plans else None)
         _log.info("config %r: kernels %s, disco band runs %s", name,
                   kernels, band)
     for spec in warm_specs:

@@ -35,11 +35,9 @@ _log = logging.getLogger("repro.launch.tune")
 
 
 def _model_shapes(config: str, members: int) -> dict:
-    from repro.configs import fcn3 as fcn3cfg
-    from repro.core.fcn3 import FCN3
+    from repro.configs import registry
     from repro.kernels.autotune import model_op_shapes
-    model = FCN3(fcn3cfg.NAMED_CONFIGS[config]())
-    return model_op_shapes(model, members=members)
+    return model_op_shapes(registry.build_model(config), members=members)
 
 
 def main(argv=None) -> None:

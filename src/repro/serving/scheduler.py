@@ -56,8 +56,7 @@ from concurrent.futures import ThreadPoolExecutor
 import jax
 import numpy as np
 
-from repro.configs import fcn3 as fcn3cfg
-from repro.core.fcn3 import FCN3
+from repro.configs import registry
 from repro.data import era5_synthetic as dlib
 from repro.inference import ForecastEngine, InitialConditionPerturbation
 from repro.inference.params import load_params
@@ -230,11 +229,12 @@ class EnginePool:
 
 @dataclasses.dataclass
 class ModelBundle:
-    """Everything per named config the engines share: the model, the
-    (synthetic-ERA5) data source, geometry buffers and params."""
+    """Everything per named config the engines share: the model (of any
+    family, ``repro.configs.registry``), the (synthetic-ERA5) data
+    source, geometry buffers and params."""
 
     name: str
-    model: FCN3
+    model: object
     ds: dlib.SyntheticERA5
     buffers: dict
     params: dict
@@ -245,13 +245,12 @@ class ModelBundle:
 
 
 def build_bundle(name: str, ckpt: str | None = None) -> ModelBundle:
-    """Deterministic bundle construction (calibrated on sample 0), so a
-    direct ``ForecastEngine`` built from the same config reproduces
-    served results bit-for-bit."""
+    """Deterministic bundle construction (the family's default params on
+    sample 0 without a checkpoint), so a direct ``ForecastEngine`` built
+    from the same config reproduces served results bit-for-bit."""
     t0 = time.perf_counter()
-    cfg = fcn3cfg.NAMED_CONFIGS[name]()
-    model = FCN3(cfg)
-    ds = dlib.SyntheticERA5(cfg)
+    model = registry.build_model(name)
+    ds = dlib.SyntheticERA5(model.cfg)
     buffers = jax.block_until_ready(model.make_buffers())
     t1 = time.perf_counter()
     params = jax.block_until_ready(

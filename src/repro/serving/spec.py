@@ -14,6 +14,11 @@ import dataclasses
 PRECISIONS = ("float32", "bfloat16")
 KERNEL_MODES = ("auto", "reference", "pallas")
 PRIORITIES = ("interactive", "batch")
+#: float32 bytes of a config's Legendre tables up to which its engines
+#: bake the geometry into the executable: the 1-degree FCN3 (5.8 MB)
+#: bakes, FCN3 at 721x1440 (373 MB) and SFNO's four tables (443 MB) do
+#: not
+STATIC_TABLE_BYTES = 2**26
 
 
 @dataclasses.dataclass(frozen=True)
@@ -116,11 +121,13 @@ class RequestSpec:
                                   ensemble_transform=self.ensemble_transform)
 
     def engine_config(self):
-        """The ``EngineConfig`` a warm engine for this spec runs with."""
-        # Single-host service: bake the geometry into the executable
-        # except at full resolution, where the Legendre tables are
-        # GB-scale and must stay jit arguments (same policy as the
-        # serve CLI).
+        """The ``EngineConfig`` a warm engine for this spec runs with.
+
+        Single-host service: the geometry is baked into the executable
+        (``static_buffers``) unless the config's Legendre tables pass
+        ``STATIC_TABLE_BYTES``; larger tables stay jit arguments, where
+        a compile does not fold them into constants."""
+        from repro.configs import registry
         from repro.inference import EngineConfig
         from repro.kernels import autotune
         from repro.kernels.config import KernelConfig
@@ -135,7 +142,10 @@ class RequestSpec:
         return EngineConfig(members=self.members,
                             lead_chunk=self.lead_chunk,
                             compute_dtype=self.precision,
-                            static_buffers=self.config != "full",
+                            static_buffers=(
+                                registry.config(self.config)
+                                .legendre_table_bytes()
+                                <= STATIC_TABLE_BYTES),
                             perturb=self.perturbation_config(),
                             spectra=self.spectra,
                             kernels=kernels)
@@ -206,12 +216,13 @@ class RequestSpec:
         if problems:
             # type errors first; the value checks below assume them
             raise ValueError("; ".join(problems))
-        from repro.configs import fcn3 as fcn3cfg
+        from repro.configs import registry
         from repro.inference import perturbations as perturblib
-        if self.config not in fcn3cfg.NAMED_CONFIGS:
+        configs = registry.named_configs()
+        if self.config not in configs:
             problems.append(
                 f"unknown config {self.config!r}; expected one of "
-                f"{sorted(fcn3cfg.NAMED_CONFIGS)}")
+                f"{sorted(configs)}")
         if self.lead_steps < 1:
             problems.append(f"lead_steps must be >= 1, got {self.lead_steps}")
         if self.lead_chunk < 1:

@@ -528,20 +528,30 @@ NULL_TRACE = _NullTrace()
 # ---------------------------------------------------------------------------
 # names in a device profile
 
-#: ``jax.named_scope`` names of the FCN3 operators (``repro.core``) and
-#: of the forecast engine's scan body (``repro.inference.engine``).  The
-#: compiled ops carry them in their HLO ``op_name`` metadata, so a
-#: ``jax.profiler`` trace of the device puts each op's time down to one
-#: of these (the innermost, where scopes nest).
+#: ``jax.named_scope`` names of each model family's operators
+#: (``repro.core.fcn3``, ``repro.core.sfno``) and of the forecast
+#: engine's scan body (``repro.inference.engine``).  The compiled ops
+#: carry them in their HLO ``op_name`` metadata, so a ``jax.profiler``
+#: trace of the device puts each op's time down to one of these (the
+#: innermost, where scopes nest).
 SCOPE_ENCODER = "fcn3.encoder"
 SCOPE_LOCAL_CONV = "fcn3.local_conv"
 SCOPE_SPECTRAL_CONV = "fcn3.spectral_conv"
 SCOPE_MLP = "fcn3.mlp"
 SCOPE_DECODER = "fcn3.decoder"
+FCN3_SCOPES = (SCOPE_ENCODER, SCOPE_LOCAL_CONV, SCOPE_SPECTRAL_CONV,
+               SCOPE_MLP, SCOPE_DECODER)
+SCOPE_SFNO_ENCODER = "sfno.encoder"
+SCOPE_SFNO_SHT = "sfno.sht"
+SCOPE_SFNO_MIX = "sfno.spectral_mix"
+SCOPE_SFNO_MLP = "sfno.mlp"
+SCOPE_SFNO_DECODER = "sfno.decoder"
+SFNO_SCOPES = (SCOPE_SFNO_ENCODER, SCOPE_SFNO_SHT, SCOPE_SFNO_MIX,
+               SCOPE_SFNO_MLP, SCOPE_SFNO_DECODER)
 SCOPE_NOISE = "engine.noise"
 SCOPE_PRODUCTS = "engine.products"
-SCOPES = (SCOPE_ENCODER, SCOPE_LOCAL_CONV, SCOPE_SPECTRAL_CONV, SCOPE_MLP,
-          SCOPE_DECODER, SCOPE_NOISE, SCOPE_PRODUCTS)
+ENGINE_SCOPES = (SCOPE_NOISE, SCOPE_PRODUCTS)
+SCOPES = FCN3_SCOPES + SFNO_SCOPES + ENGINE_SCOPES
 
 #: prefix of the host spans the serving stack also writes into a
 #: ``jax.profiler`` trace (``jax.profiler.TraceAnnotation``), so they lie

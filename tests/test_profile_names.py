@@ -59,8 +59,9 @@ def smoke():
 @pytest.mark.parametrize("batch", [None, 2])
 def test_chunk_program_ops_carry_scopes(smoke, batch):
     """Serial and coalesced chunk programs, scored with spectra: every
-    scope is there, every dot and convolution sits under one, and the
-    unscoped rest stays under ``UNSCOPED_SHARE``."""
+    FCN3 and engine scope is there and no other family's, every dot and
+    convolution sits under one, and the unscoped rest stays under
+    ``UNSCOPED_SHARE``."""
     model, params, buffers = smoke
     eng = ForecastEngine(model, EngineConfig(members=2, lead_chunk=1,
                                              spectra=True))
@@ -76,7 +77,8 @@ def test_chunk_program_ops_carry_scopes(smoke, batch):
             unscoped.append(m.group("name"))
             assert m.group("op") not in ("dot", "convolution"), m.group(0)
         found.add(scope)
-    assert set(telemetry.SCOPES) <= found
+    assert set(telemetry.FCN3_SCOPES + telemetry.ENGINE_SCOPES) <= found
+    assert not found & set(telemetry.SFNO_SCOPES)
     assert len(unscoped) < UNSCOPED_SHARE * total, (len(unscoped), total)
 
 

@@ -195,3 +195,40 @@ def test_fcn3_step_kernel_calls_carry_scopes(one_chip, chip_dft,
         assert scope in KERNEL_SCOPES[kernel], (kernel, stack)
         seen[kernel].add(scope)
     assert seen == KERNEL_SCOPES
+
+
+def test_sfno_step_kernel_calls_carry_scopes(one_chip, chip_dft,
+                                             monkeypatch):
+    """The smoke-size SFNO step compiled with the Pallas Legendre kernel
+    for a v5e: each of its transforms (two per block, three in the
+    first and last blocks, which resample) makes two kernel calls (real
+    and imaginary parts), and every call sits under ``sfno.sht``."""
+    import re
+
+    from repro import telemetry
+    from repro.configs import registry
+    from repro.kernels import config as kconfig
+    monkeypatch.setattr(kconfig, "compiled_backend", lambda: True)
+    model = registry.build_model("sfno_smoke")
+    cfg = model.cfg
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    text = jax.jit(model.apply).lower(
+        on_chip(jax.eval_shape(model.init, jax.random.PRNGKey(0))),
+        on_chip(jax.eval_shape(model.make_buffers)),
+        jax.ShapeDtypeStruct((cfg.n_state, cfg.nlat, cfg.nlon),
+                             jnp.float32, sharding=one_chip),
+    ).compile().as_text()
+    scopes = []
+    for line in text.splitlines():
+        if "tpu_custom_call" not in line or " = " not in line:
+            continue
+        stack = re.search(r'op_name="([^"]*)"', line).group(1)
+        scopes.append(next((p for p in (q.rstrip(")").rsplit("(", 1)[-1]
+                                        for q in reversed(stack.split("/")))
+                            if p in telemetry.SCOPES), None))
+    transforms = 2 * cfg.n_blocks + 2
+    assert scopes == [telemetry.SCOPE_SFNO_SHT] * 2 * transforms
