@@ -57,16 +57,18 @@ def _rdft_mats(n: int) -> tuple[np.ndarray, np.ndarray]:
 
 
 @functools.lru_cache(maxsize=16)
-def _irdft_mats(n: int) -> tuple[np.ndarray, np.ndarray]:
-    """Inverse: irfft(c, n)[w] = Re(c) @ a + Im(c) @ b."""
-    nf = n // 2 + 1
+def _irdft_mats(n: int, nf: int) -> tuple[np.ndarray, np.ndarray]:
+    """Inverse: irfft(c, n)[w] = Re(c) @ a + Im(c) @ b for c of nf orders.
+
+    Row f weighs order f twice (its -f twin), except order 0 and the
+    Nyquist order f = n/2, which exists only for even n and is the last
+    row only when nf = n//2+1; with nf = n//2+1 these are the full
+    inverse matrices.
+    """
     f = np.arange(nf)[:, None]
     w = np.arange(n)[None, :]
     ang = 2.0 * np.pi * f * w / n
-    mult = np.full((nf, 1), 2.0)
-    mult[0] = 1.0
-    if n % 2 == 0:
-        mult[-1] = 1.0
+    mult = np.where((f == 0) | (2 * f == n), 1.0, 2.0)
     a = (mult * np.cos(ang) / n).astype(np.float32)
     b = (-mult * np.sin(ang) / n).astype(np.float32)
     return a, b
@@ -88,10 +90,17 @@ def rfft(x: jax.Array, axis: int = -1) -> jax.Array:
 
 
 def irfft(c: jax.Array, n: int, axis: int = -1) -> jax.Array:
-    """Inverse real FFT along the last axis; c must have n//2+1 entries."""
+    """Inverse real FFT along the last axis.
+
+    c holds orders 0..k-1 for any k <= n//2+1; the orders it leaves out
+    are zero.  The DFT matrices take k rows, so a short spectrum costs k
+    rows of work, not n//2+1.  The Nyquist order n/2 (even n only) is
+    the last of n//2+1 and counts once, as order 0 does; every other
+    order counts twice, for its Hermitian twin.
+    """
     assert axis in (-1, c.ndim - 1)
+    assert 0 < c.shape[-1] <= n // 2 + 1, (c.shape, n)
     if get_mode() == "fft":
         return jnp.fft.irfft(c, n=n, axis=-1)
-    assert c.shape[-1] == n // 2 + 1, (c.shape, n)
-    a, b = _irdft_mats(n)
+    a, b = _irdft_mats(n, c.shape[-1])
     return (jnp.real(c) @ jnp.asarray(a) + jnp.imag(c) @ jnp.asarray(b))

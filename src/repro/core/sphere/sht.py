@@ -73,13 +73,16 @@ def sht_inverse(c: jax.Array, pct: jax.Array, nlon: int) -> jax.Array:
 
 
 def synthesize(spec: jax.Array, nlon: int) -> jax.Array:
-    """(..., H, M) Legendre-synthesized orders -> (..., H, nlon) field."""
+    """(..., H, M) Legendre-synthesized orders -> (..., H, nlon) field.
+
+    M may be fewer than nlon//2+1 orders: the orders above M are zero
+    and cost nothing, as ``fourier.irfft`` contracts only the M it is
+    given.  Only M = nlon//2+1 on an even grid holds the Nyquist order
+    nlon/2, which counts once.
+    """
     m = spec.shape[-1]
-    pad = nlon // 2 + 1 - m
-    if pad < 0:
+    if m > nlon // 2 + 1:
         raise ValueError(f"mmax={m} too large for nlon={nlon}")
-    if pad:
-        spec = jnp.pad(spec, [(0, 0)] * (spec.ndim - 1) + [(0, pad)])
     # irfft contributes 1/nlon and the Hermitian double-count of m>0 modes.
     return fourier.irfft(spec, n=nlon, axis=-1) * nlon
 

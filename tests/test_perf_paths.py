@@ -60,6 +60,81 @@ class TestFourierModes:
         np.testing.assert_allclose(np.asarray(xa), np.asarray(x), atol=1e-5)
 
 
+def _full_irdft_mats(n):
+    """The n//2+1-row inverse DFT matrices written out directly: order 0
+    and, for even n, the Nyquist order (the last row) once, every other
+    order twice."""
+    nf = n // 2 + 1
+    ang = 2.0 * np.pi * np.arange(nf)[:, None] * np.arange(n)[None, :] / n
+    mult = np.full((nf, 1), 2.0)
+    mult[0] = 1.0
+    if n % 2 == 0:
+        mult[-1] = 1.0
+    return ((mult * np.cos(ang) / n).astype(np.float32),
+            (-mult * np.sin(ang) / n).astype(np.float32))
+
+
+@pytest.mark.parametrize("mode", ["fft", "matmul"])
+@pytest.mark.parametrize("n,k", [(16, 1), (16, 5), (16, 9),
+                                 (15, 1), (15, 5), (15, 8)])
+def test_irfft_short_spectrum_is_the_zero_padded_one(mode, n, k):
+    """irfft of k <= n//2+1 orders is irfft of those orders zero-padded
+    to n//2+1; at k = n//2+1 on an even n the last row is the Nyquist
+    order and weighs once (numpy's irfft is the oracle), and the
+    matrices are the full ones bit for bit."""
+    key = jax.random.PRNGKey(n * 100 + k)
+    c = jax.lax.complex(*jax.random.normal(key, (2, 3, n // 2 + 1)))[..., :k]
+    padded = jnp.pad(c, ((0, 0), (0, n // 2 + 1 - k)))
+    fourier.set_mode(mode)
+    try:
+        got = np.asarray(fourier.irfft(c, n))
+        full = np.asarray(fourier.irfft(padded, n))
+    finally:
+        fourier.set_mode("fft")
+    assert got.shape == (3, n)
+    want = np.fft.irfft(np.asarray(padded, np.complex128), n=n)
+    np.testing.assert_allclose(got, full, atol=1e-6)
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    a, b = fourier._irdft_mats(n, k)
+    fa, fb = _full_irdft_mats(n)
+    assert a.shape == b.shape == (k, n)
+    if k == n // 2 + 1:
+        np.testing.assert_array_equal(a, fa)
+        np.testing.assert_array_equal(b, fb)
+    else:
+        np.testing.assert_array_equal(a, fa[:k])
+        np.testing.assert_array_equal(b, fb[:k])
+
+
+@pytest.mark.parametrize("nlat,nlon,kind,m", [(31, 64, "equiangular", 10),
+                                              (24, 48, "gauss", 8)])
+def test_sht_roundtrip_keeping_fewer_orders_in_matmul_mode(nlat, nlon,
+                                                           kind, m):
+    """An SHT that keeps m < nlon//2+1 orders synthesizes from m DFT rows:
+    the round trip (inverse, then forward) equals the FFT path's, which
+    zero-pads the spectrum, and on the Gauss grid, whose quadrature is
+    exact, it gives band-limited coefficients back."""
+    t = sht.SHT.create(grids.make_grid(nlat, nlon, kind), lmax=m, mmax=m)
+    assert t.mmax < nlon // 2 + 1
+    re, im = jax.random.normal(jax.random.PRNGKey(m), (2, 2, m, m))
+    c = jax.lax.complex(re, im.at[..., 0].set(0.0))
+    c = c * jnp.asarray(sht.mode_mask(m, m))
+    trips = {}
+    for mode in ("fft", "matmul"):
+        fourier.set_mode(mode)
+        try:
+            x = t.inverse(c)
+            trips[mode] = (np.asarray(x), np.asarray(t.forward(x)))
+        finally:
+            fourier.set_mode("fft")
+    x, back = trips["matmul"]
+    assert x.shape == (2, nlat, nlon)
+    np.testing.assert_allclose(x, trips["fft"][0], atol=1e-5)
+    np.testing.assert_allclose(back, trips["fft"][1], atol=1e-5)
+    if kind == "gauss":
+        np.testing.assert_allclose(back, np.asarray(c), atol=1e-4)
+
+
 class TestAffineBandGather:
     @pytest.mark.parametrize("gi,go", [
         ((64, 128, "equiangular"), (32, 64, "gauss")),

@@ -127,6 +127,34 @@ def test_legendre_latent_slab(one_chip, chip_dft, direction):
     _compile(fn, one_chip, x, (360, 360, 360), dtypes=(dtype, jnp.float32))
 
 
+#: inverse transforms whose spectra keep fewer orders than the grid's
+#: nlon//2+1, through the Pallas wrapper: SFNO's input-grid inverse (384
+#: channels, 240 orders onto 721x1440) and the latent inverse of
+#: ``LEGENDRE_CASES`` (360 of 361 orders onto 360x720).  Each names the
+#: pattern of shapes that only a pad of the orders back to nlon//2+1, or
+#: a DFT contraction over that many rows, would give.
+SHORT_INVERSES = {
+    "sfno_input_grid": (
+        lambda c, t: sht_inverse_pallas(c, t, 1440, interpret=False),
+        (384, 240, 240), (240, 240, 721),
+        r"\[384,721,721\]|\[721,1440\]"),
+    "fcn3_latent": (LEGENDRE_CASES["inverse"][0], LEGENDRE_CASES["inverse"][1],
+                    (360, 360, 360), r"[\[,]361[\],]"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHORT_INVERSES))
+def test_inverse_synthesizes_only_the_orders_kept(one_chip, chip_dft, case):
+    """The inverse DFT contracts the orders the transform keeps: no
+    instruction of the compiled program has the zero-padded spectrum's
+    or the full DFT matrix's shape."""
+    import re
+    fn, c, table, padded = SHORT_INVERSES[case]
+    text = _compile(fn, one_chip, c, table,
+                    dtypes=(jnp.complex64, jnp.float32)).as_text()
+    assert re.search(padded, text) is None, re.search(padded, text).group()
+
+
 @pytest.mark.parametrize("plan,run", DISCO_CALLS)
 def test_disco_band_full_width_plan(one_chip, plan, run):
     x, psi, mix, stride, affine = _run_call(plan, run)
